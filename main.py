@@ -30,14 +30,8 @@ import os
 
 
 def main(argv=None) -> dict:
-    from relora_tpu.utils.logging import honor_platform_request
+    from relora_tpu.utils.logging import enable_compile_cache
 
-    honor_platform_request()
-    from relora_tpu.utils.logging import enable_compile_cache, enable_xla_overlap_flags
-
-    # before the first jax import below: XLA reads XLA_FLAGS exactly once at
-    # backend init, and the tp/fsdp step wants its collectives overlapped
-    enable_xla_overlap_flags()
     enable_compile_cache()
     from relora_tpu.config.training import parse_train_args
     from relora_tpu.utils.logging import get_logger

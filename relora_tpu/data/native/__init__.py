@@ -2,14 +2,18 @@
 
 Parity with the reference's runtime ``make`` hook
 (megatron_dataset/data_utils.py:470-482, Makefile): the shared object is
-compiled on first use with g++ and cached next to the source; if compilation
-fails (no compiler on some hosts) callers fall back to the NumPy
-implementations automatically.
+compiled on first use with g++ and cached next to the source, stamped with
+the sha256 of the ``helpers.cpp`` it was built from — a copied checkout can
+carry a stale or foreign ``.so`` whose mtime says nothing, so a library whose
+stamp does not match the source is rebuilt.  If compilation fails (no compiler
+on some hosts) callers fall back to the NumPy implementations.  Either way one
+INFO line says which builder this process runs.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -24,18 +28,24 @@ logger = get_logger(__name__)
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "helpers.cpp")
 _SO = os.path.join(_DIR, "_helpers.so")
+_STAMP = _SO + ".sha256"  # digest of the helpers.cpp that _SO was built from
 _LOCK = threading.Lock()
 _LIB: Optional[ctypes.CDLL] = None
 _TRIED = False
 
 
 def _compile() -> bool:
-    cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", _SO]
+    # build beside the target and rename: several processes (test workers)
+    # may find the library stale at once, and a half-written .so must never
+    # be what another one loads
+    tmp = f"{_SO}.tmp{os.getpid()}"
+    cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", tmp]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+        os.replace(tmp, _SO)
         return True
-    except Exception as e:
-        logger.warning(f"native helpers build failed ({e}); using NumPy fallbacks")
+    except (OSError, subprocess.SubprocessError) as e:
+        logger.warning(f"index builder: NumPy (native helpers build failed: {e})")
         return False
 
 
@@ -46,10 +56,22 @@ def load() -> Optional[ctypes.CDLL]:
         if _LIB is not None or _TRIED:
             return _LIB
         _TRIED = True
-        src_mtime = os.path.getmtime(_SRC)
-        if not os.path.exists(_SO) or os.path.getmtime(_SO) < src_mtime:
+        with open(_SRC, "rb") as f:
+            digest = hashlib.sha256(f.read()).hexdigest()
+        stamp = ""
+        if os.path.exists(_SO) and os.path.exists(_STAMP):
+            with open(_STAMP) as f:
+                stamp = f.read().strip()
+        rebuilt = stamp != digest
+        if rebuilt:
             if not _compile():
                 return None
+            with open(_STAMP, "w") as f:
+                f.write(digest)
+        logger.info(
+            f"index builder: native ({'built now' if rebuilt else 'already built'} "
+            f"from helpers.cpp sha256 {digest[:12]})"
+        )
         lib = ctypes.CDLL(_SO)
 
         i32p = np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS")

@@ -200,15 +200,21 @@ class LoRALinear(nn.Module):
     def _dispatched(self, x: jax.Array, in_features: int, quantize: Optional[str]) -> jax.Array:
         """The y = x@W + ((x@A)@B)*scale composite via ops/lora_dispatch.
 
-        ``fused=True`` pins the fused Pallas kernel (untileable shapes fall
-        back to the ordered reference inside the dispatcher); ``"auto"``
-        lets the roofline cost model pick per shape.  The frozen base gets
+        ``fused=True`` pins the fused Pallas kernel wherever this call's
+        (rows, features) has a block plan — the module sees its shapes, and
+        the dispatcher raises when a forced arm cannot tile — and leaves the
+        rest to ``"auto"``, which lets the roofline cost model pick per
+        shape.  The frozen base gets
         ``stop_gradient`` so every arm agrees its cotangent is zero — the
         optimizer mask already never applies base updates, this just keeps
         grads arm-independent.
         """
-        from relora_tpu.ops.lora_dispatch import lora_matmul
+        from relora_tpu.ops.lora_dispatch import lora_matmul, plan_blocks
 
+        rows = 1
+        for d in x.shape[:-1]:
+            rows *= d
+        pin_fused = self.lora.fused is True and plan_blocks(rows, self.features) is not None
         if quantize == "int8":
             kernel_q, kernel_scale = self._int8_params(in_features)
             base = (kernel_q, kernel_scale)
@@ -223,7 +229,7 @@ class LoRALinear(nn.Module):
             lora_a.astype(self.dtype),
             lora_b.astype(self.dtype),
             scale,
-            arm="fused" if self.lora.fused is True else "auto",
+            arm="fused" if pin_fused else "auto",
             dtype=self.dtype,
             weights_static=self.lora.weights_static,
         )

@@ -27,6 +27,7 @@ metrics cadence (the trainer's flush), never per step.
 from __future__ import annotations
 
 import math
+import re
 from typing import Any, Dict, Mapping, Optional
 
 __all__ = [
@@ -37,6 +38,8 @@ __all__ = [
     "live_memory_stats",
     "hbm_peak_gb",
     "reconcile",
+    "placement",
+    "collective_counts",
     "MemoryPoller",
 ]
 
@@ -200,6 +203,43 @@ def reconcile(plan_total_bytes: Optional[int], live: Optional[Dict[str, Any]] = 
     if plan_total_bytes and peak:
         out["live_vs_plan"] = round(peak / plan_total_bytes, 4)
     return out
+
+
+def placement(params: Any, batch: Any) -> Dict[str, Any]:
+    """Which devices really hold the parameters and the batch, as aligned
+    lists over the local devices (lists, not dicts: a metrics event keeps
+    them as they are): ``device_ids``; ``param_bytes`` of parameter shards on
+    each (a replicated leaf counts in full on every device that holds it);
+    each allocator's ``bytes_in_use``; and ``batch_devices``, the ids the
+    batch is laid out over.  A mesh that was meant to spread the model and
+    put everything on the first device shows here."""
+    import jax
+
+    devices = jax.local_devices()
+    by_device = {d.id: 0 for d in devices}
+    for leaf in jax.tree_util.tree_leaves(params):
+        for shard in getattr(leaf, "addressable_shards", ()):
+            by_device[shard.device.id] += shard.data.nbytes  # host metadata, no device read
+    return {
+        "device_ids": [d.id for d in devices],
+        "param_bytes": [by_device[d.id] for d in devices],
+        "bytes_in_use": [live_memory_stats(d)["bytes_in_use"] for d in devices],
+        "batch_devices": sorted(d.id for d in batch.sharding.device_set),
+    }
+
+
+_COLLECTIVE_RE = re.compile(
+    r"\b(all-gather|all-reduce|reduce-scatter|all-to-all|collective-permute)(?:-start)?\("
+)
+
+
+def collective_counts(hlo_text: str) -> list:
+    """Collective instructions in a compiled program's text as sorted
+    ``[kind, count]`` pairs (an async pair counts once, at its ``-start``)."""
+    counts: Dict[str, int] = {}
+    for kind in _COLLECTIVE_RE.findall(hlo_text):
+        counts[kind] = counts.get(kind, 0) + 1
+    return [[kind, n] for kind, n in sorted(counts.items())]
 
 
 class MemoryPoller:

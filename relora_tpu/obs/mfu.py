@@ -12,10 +12,10 @@ Two MFU paths share this module:
   a dense-transformer estimate, so the two can legitimately differ by tens
   of percent under remat.
 
-Peak-FLOPs resolution order: ``RELORA_TPU_PEAK_FLOPS`` env override, then a
-``device_kind`` substring match against :data:`PEAK_FLOPS_BY_KIND`, then the
-v5e default (keeps historical bench numbers comparable when detection
-fails, e.g. on the CPU backend).
+Peak-FLOPs resolution: on platform ``tpu`` a ``device_kind`` substring match
+against :data:`PEAK_FLOPS_BY_KIND` and nothing else — a TPU the table has
+never heard of is an error, not a default.  Elsewhere (the CPU backend in
+tests, GPUs): ``RELORA_TPU_PEAK_FLOPS``, then the table, then the v5e default.
 """
 
 from __future__ import annotations
@@ -33,11 +33,12 @@ __all__ = [
 #: bf16 peak FLOPs/s of one chip, keyed by a lowercase substring of
 #: ``jax.devices()[0].device_kind``.  Order matters: first match wins, so
 #: longer / more specific kinds come before their prefixes (v5e before v5,
-#: v6e before v6).
+#: v6e before v6).  A v5e chip reports ``"TPU v5 lite"``.
 PEAK_FLOPS_BY_KIND = (
     ("v6e", 918e12),        # Trillium
     ("v5p", 459e12),
-    ("v5e", 197e12),        # aka v5 lite
+    ("v5 lite", 197e12),    # what a v5e chip reports (Google Cloud "TPU v5e")
+    ("v5e", 197e12),
     ("v5litepod", 197e12),
     ("v4", 275e12),
     ("v3", 123e12),
@@ -46,48 +47,47 @@ PEAK_FLOPS_BY_KIND = (
     ("a100", 312e12),
 )
 
-#: historical default (one TPU v5e chip) — used when the device kind is
-#: unrecognized, e.g. the CPU backend in tests
+#: one TPU v5e chip — used off-TPU when the device kind is unrecognized,
+#: e.g. the CPU backend in tests
 PEAK_FLOPS_DEFAULT = 197e12
 
 
 def peak_flops(device: Optional[Any] = None) -> float:
     """Peak bf16 FLOPs/s for ``device`` (default: ``jax.devices()[0]``).
 
-    ``RELORA_TPU_PEAK_FLOPS`` overrides everything — the escape hatch for
-    hardware this table has not met.
+    A TPU must be in the table: an unknown TPU kind raises.  Off-TPU,
+    ``RELORA_TPU_PEAK_FLOPS`` overrides the table and an unknown kind gets
+    :data:`PEAK_FLOPS_DEFAULT`.
     """
-    env = os.environ.get("RELORA_TPU_PEAK_FLOPS")
-    if env:
-        return float(env)
     if device is None:
-        try:
-            import jax
+        import jax
 
-            device = jax.devices()[0]
-        except Exception:
-            return PEAK_FLOPS_DEFAULT
+        device = jax.devices()[0]
     kind = str(getattr(device, "device_kind", "")).lower()
+    on_tpu = getattr(device, "platform", "") == "tpu"
+    env = os.environ.get("RELORA_TPU_PEAK_FLOPS")
+    if env and not on_tpu:
+        return float(env)
     for needle, flops in PEAK_FLOPS_BY_KIND:
         if needle in kind:
             return flops
+    if on_tpu:
+        raise ValueError(
+            f"no peak FLOP/s on record for TPU device_kind {device.device_kind!r}; "
+            "add it to relora_tpu.obs.mfu.PEAK_FLOPS_BY_KIND with its source"
+        )
     return PEAK_FLOPS_DEFAULT
 
 
 def step_flops_from_cost_analysis(cost: Any) -> Optional[float]:
-    """Extract total FLOPs from a jax cost-analysis result.
-
-    Handles both shapes jax returns across versions: ``lowered.cost_analysis()``
-    gives a dict, ``compiled.cost_analysis()`` gives a list of per-computation
-    dicts.  Returns None when no positive 'flops' entry exists (e.g. some
+    """Total FLOPs from ``lowered.cost_analysis()`` (a dict on the installed
+    JAX).  Returns None when there is no positive 'flops' entry (some
     backends report nothing), signalling the caller to fall back to 6ND.
     """
-    if cost is None:
+    if not isinstance(cost, dict):
         return None
-    if isinstance(cost, dict):
-        cost = [cost]
     try:
-        total = sum(float(c.get("flops", 0.0)) for c in cost if isinstance(c, dict))
+        total = float(cost.get("flops", 0.0))
     except (TypeError, ValueError):
         return None
     return total if total > 0 else None

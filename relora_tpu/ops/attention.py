@@ -26,6 +26,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from relora_tpu.utils.logging import get_logger, info_once
+
+logger = get_logger(__name__)
+
 
 def _expand_grouped_kv(q, k, v):
     """Materialize grouped K/V up to the full query head count (for impls
@@ -116,7 +120,52 @@ def _pallas_attention(q, k, v, *, causal: bool, scale: float) -> jax.Array:
         )
         return out.swapaxes(1, 2)
 
-    return _grouped_equal_heads_call(q, k, v, equal_heads)
+    def local(qq, kk, vv):
+        return _grouped_equal_heads_call(qq, kk, vv, equal_heads)
+
+    mesh = _multi_device_mesh()
+    if mesh is None:
+        return local(q, k, v)
+    # GSPMD cannot partition a Mosaic kernel ("wrap the call in a shard_map"):
+    # run it per shard over the layout the activations already have — batch
+    # over data x fsdp, heads over tensor
+    if not flash_partitionable(q.shape[0], q.shape[2], k.shape[2]):
+        raise ValueError(
+            f"pallas attention under mesh {dict(mesh.shape)} needs batch "
+            f"{q.shape[0]} divisible by data*fsdp and heads {q.shape[2]}/"
+            f"{k.shape[2]} by tensor"
+        )
+    from relora_tpu.parallel.mesh import DATA_AXIS, FSDP_AXIS, TENSOR_AXIS
+
+    spec = jax.sharding.PartitionSpec((DATA_AXIS, FSDP_AXIS), None, TENSOR_AXIS, None)
+    return jax.shard_map(
+        local, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False
+    )(q, k, v)
+
+
+def _multi_device_mesh():
+    """The current mesh when it spans more than one device, else None."""
+    from relora_tpu.parallel.mesh import current_mesh
+
+    mesh = current_mesh()
+    return mesh if mesh is not None and mesh.size > 1 else None
+
+
+def flash_partitionable(batch: int, heads: int, kv_heads: int) -> bool:
+    """Whether the pallas flash arm can run under the current mesh: always on
+    one device; on a mesh only where the per-shard split of
+    :func:`_pallas_attention` is exact (e.g. not the batch-1 init trace)."""
+    mesh = _multi_device_mesh()
+    if mesh is None:
+        return True
+    n_batch = mesh.shape["data"] * mesh.shape["fsdp"]
+    n_t = mesh.shape["tensor"]
+    return (
+        mesh.shape["sequence"] == 1
+        and batch % n_batch == 0
+        and heads % n_t == 0
+        and kv_heads % n_t == 0
+    )
 
 
 def cached_attention(
@@ -249,8 +298,8 @@ def _paged_decode_kernel(
     q_ref,  # (1, N*S, H) this row's queries, head-major (row = head*S + s)
     k_ref,  # (1, ps, n_kv, H) pool page selected by bt[b, w]
     v_ref,  # (1, ps, n_kv, H)
-    ks_ref,  # (1, n_kv) f32 page scales (ones when unquantized)
-    vs_ref,  # (1, n_kv)
+    ks_ref,  # (1, 1, n_kv) f32 page scales (ones when unquantized)
+    vs_ref,  # (1, 1, n_kv)
     # VMEM output
     o_ref,  # (1, N*S, H)
     # VMEM scratch, carried across the W grid steps of one row
@@ -295,8 +344,8 @@ def _paged_decode_kernel(
         kj = k_ref[0, :, j, :].astype(jnp.float32)  # (ps, H)
         vj = v_ref[0, :, j, :].astype(jnp.float32)
         if quantized:
-            kj = kj * ks_ref[0, j]
-            vj = vj * vs_ref[0, j]
+            kj = kj * ks_ref[0, 0, j]
+            vj = vj * vs_ref[0, 0, j]
         qj = q_ref[0, j * gS : (j + 1) * gS, :].astype(jnp.float32)  # (gS, H)
         s = (
             jax.lax.dot_general(
@@ -383,12 +432,15 @@ def paged_decode_attention(
     quantized = k_scale is not None
     if quantized != (v_scale is not None):
         raise ValueError("k_scale and v_scale must be given together")
+    # scales ride as (num_pages, 1, n_kv): the TPU lowering wants a block's
+    # last two dims to equal the array's (or tile by 8x128), which a
+    # (1, n_kv) block of a (num_pages, n_kv) array does not
     if quantized:
-        ks = k_scale.astype(jnp.float32)
-        vs = v_scale.astype(jnp.float32)
+        ks = k_scale.astype(jnp.float32).reshape(num_pages, 1, n_kv)
+        vs = v_scale.astype(jnp.float32).reshape(num_pages, 1, n_kv)
     else:
         # constant-folded away; keeps one kernel signature for both flavors
-        ks = jnp.ones((num_pages, n_kv), jnp.float32)
+        ks = jnp.ones((num_pages, 1, n_kv), jnp.float32)
         vs = ks
 
     # head-major rows: (B, S, N, H) -> (B, N, S, H) -> (B, N*S, H); row
@@ -420,8 +472,8 @@ def paged_decode_attention(
             pl.BlockSpec(
                 (1, page_size, n_kv, H), lambda b, w, bt, pos: (bt[b, w], 0, 0, 0)
             ),
-            pl.BlockSpec((1, n_kv), lambda b, w, bt, pos: (bt[b, w], 0)),
-            pl.BlockSpec((1, n_kv), lambda b, w, bt, pos: (bt[b, w], 0)),
+            pl.BlockSpec((1, 1, n_kv), lambda b, w, bt, pos: (bt[b, w], 0, 0)),
+            pl.BlockSpec((1, 1, n_kv), lambda b, w, bt, pos: (bt[b, w], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, N * T, H), lambda b, w, bt, pos: (b, 0, 0)),
         scratch_shapes=[
@@ -453,8 +505,8 @@ def _packed_paged_kernel(
     q_ref,  # (1, N, H) this packed token's query, head-major
     k_ref,  # (1, ps, n_kv, H) pool page selected by bt[rm[t], w]
     v_ref,  # (1, ps, n_kv, H)
-    ks_ref,  # (1, n_kv) f32 page scales (ones when unquantized)
-    vs_ref,  # (1, n_kv)
+    ks_ref,  # (1, 1, n_kv) f32 page scales (ones when unquantized)
+    vs_ref,  # (1, 1, n_kv)
     # VMEM output
     o_ref,  # (1, N, H)
     # VMEM scratch, carried across the W grid steps of one token
@@ -490,8 +542,8 @@ def _packed_paged_kernel(
         kj = k_ref[0, :, j, :].astype(jnp.float32)  # (ps, H)
         vj = v_ref[0, :, j, :].astype(jnp.float32)
         if quantized:
-            kj = kj * ks_ref[0, j]
-            vj = vj * vs_ref[0, j]
+            kj = kj * ks_ref[0, 0, j]
+            vj = vj * vs_ref[0, 0, j]
         qj = q_ref[0, j * g : (j + 1) * g, :].astype(jnp.float32)  # (g, H)
         s = (
             jax.lax.dot_general(
@@ -577,10 +629,10 @@ def packed_paged_attention(
     if quantized != (v_scale is not None):
         raise ValueError("k_scale and v_scale must be given together")
     if quantized:
-        ks = k_scale.astype(jnp.float32)
-        vs = v_scale.astype(jnp.float32)
+        ks = k_scale.astype(jnp.float32).reshape(num_pages, 1, n_kv)
+        vs = v_scale.astype(jnp.float32).reshape(num_pages, 1, n_kv)
     else:
-        ks = jnp.ones((num_pages, n_kv), jnp.float32)
+        ks = jnp.ones((num_pages, 1, n_kv), jnp.float32)
         vs = ks
 
     # token-major rows: (1, T, N, H) -> (T, N, H); within a token the N axis
@@ -610,8 +662,12 @@ def packed_paged_attention(
                 (1, page_size, n_kv, H),
                 lambda t, w, rm, bt, pos: (bt[rm[t], w], 0, 0, 0),
             ),
-            pl.BlockSpec((1, n_kv), lambda t, w, rm, bt, pos: (bt[rm[t], w], 0)),
-            pl.BlockSpec((1, n_kv), lambda t, w, rm, bt, pos: (bt[rm[t], w], 0)),
+            pl.BlockSpec(
+                (1, 1, n_kv), lambda t, w, rm, bt, pos: (bt[rm[t], w], 0, 0)
+            ),
+            pl.BlockSpec(
+                (1, 1, n_kv), lambda t, w, rm, bt, pos: (bt[rm[t], w], 0, 0)
+            ),
         ],
         out_specs=pl.BlockSpec((1, N, H), lambda t, w, rm, bt, pos: (t, 0, 0)),
         scratch_shapes=[
@@ -663,9 +719,12 @@ def dot_product_attention(
                 k.shape[2],
                 q.shape[3],
                 act_bytes=jnp.dtype(q.dtype).itemsize,
-                fused_available=jax.default_backend() == "tpu",
+                fused_available=jax.default_backend() == "tpu"
+                and flash_partitionable(q.shape[0], q.shape[2], k.shape[2]),
             )
             impl = "pallas" if arm == "flash" else arm
+            # trace time only: a run's log says which attention its step compiled
+            info_once(logger, f"dot_product_attention traced: auto -> {impl} q_shape={q.shape}")
     if impl == "xla":
         return jax.nn.dot_product_attention(q, k, v, scale=scale, is_causal=causal)
     if impl == "pallas":

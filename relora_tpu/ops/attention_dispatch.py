@@ -60,6 +60,9 @@ from relora_tpu.ops.lora_dispatch import (
     LAUNCH_OVERHEAD_S,
     PEAK_FLOPS,
 )
+from relora_tpu.utils.logging import get_logger, info_once
+
+logger = get_logger(__name__)
 
 __all__ = [
     "ARMS",
@@ -293,6 +296,18 @@ def choose_training_arm(
     return min(candidates, key=lambda arm: times[arm])
 
 
+def _note_traced_arm(entry: str, arm: str, q_shape, kv_dtype, interpret: bool) -> None:
+    """Say once which arm a serving program was traced with (trace time only,
+    not in the compiled step), so an operator — and chip_smoke.py — can read
+    from the server's log whether decode runs the compiled Pallas kernel, the
+    interpreter or the naive arm."""
+    info_once(
+        logger,
+        f"{entry} traced: arm={arm} q_shape={tuple(q_shape)} "
+        f"kv_dtype={jnp.dtype(kv_dtype)} interpret={interpret}",
+    )
+
+
 def paged_attention(
     q: jax.Array,
     pool_k: jax.Array,
@@ -333,10 +348,12 @@ def paged_attention(
     if arm == "paged_decode":
         if interpret is None:
             interpret = jax.default_backend() != "tpu"
+        _note_traced_arm("paged_attention", arm, q.shape, pool_k.dtype, interpret)
         return paged_decode_attention(
             q, pool_k, pool_v, block_tables, positions,
             k_scale=k_scale, v_scale=v_scale, scale=scale, interpret=interpret,
         )
+    _note_traced_arm("paged_attention", arm, q.shape, pool_k.dtype, False)
     return paged_cached_attention(
         q, pool_k, pool_v, block_tables, positions,
         k_scale=k_scale, v_scale=v_scale, scale=scale,
@@ -389,10 +406,12 @@ def packed_attention(
     if arm == "packed":
         if interpret is None:
             interpret = jax.default_backend() != "tpu"
+        _note_traced_arm("packed_attention", arm, q.shape, pool_k.dtype, interpret)
         return packed_paged_attention(
             q, pool_k, pool_v, block_tables, rm, pos,
             k_scale=k_scale, v_scale=v_scale, scale=scale, interpret=interpret,
         )
+    _note_traced_arm("packed_attention", arm, q.shape, pool_k.dtype, False)
     # naive: tokens become batch rows, each with its own table — (T, 1, N, H)
     # queries against (T, W) per-token tables, then back to token-major
     token_tables = jnp.take(block_tables, rm.astype(jnp.int32), axis=0)

@@ -313,8 +313,12 @@ def lora_matmul(
 
     if arm == "auto":
         # The Pallas interpreter is a correctness tool, not a fast path:
-        # never auto-select fused off-TPU.
-        fused_ok = jax.default_backend() == "tpu"
+        # never auto-select fused off-TPU.  On the TPU the fused arm is a
+        # candidate only for forward-only calls (weights_static, serving):
+        # its backward kernels hold whole-N blocks in VMEM and the chip's
+        # compiler refuses them at FFN width (tests/test_tpu_compile.py,
+        # ROADMAP A4), and a call cannot see whether it will be differentiated.
+        fused_ok = jax.default_backend() == "tpu" and weights_static
         arm = choose_arm(
             M, K, N, r, _dtype_bytes(dtype), base_bytes,
             fused_available=fused_ok, weights_static=weights_static,
@@ -325,19 +329,24 @@ def lora_matmul(
             interpret = jax.default_backend() != "tpu"
         planned = plan_blocks(M, N)
         if planned is None:
-            arm = "ordered"  # untileable shape: quietly take the reference path
-        else:
-            bm, bn = planned
-            kwargs = dict(block_m=bm, block_n=bn, interpret=interpret, out_dtype=dtype)
-            if quantized:
-                return fused_lora_matmul_int8(
-                    x.astype(dtype), q, qscale, a.astype(dtype), b.astype(dtype),
-                    scale, **kwargs,
-                )
-            return fused_lora_matmul(
-                x.astype(dtype), base.astype(dtype), a.astype(dtype),
-                b.astype(dtype), scale, **kwargs,
+            # choose_arm strikes untileable shapes, so only a forced arm
+            # gets here: say so instead of quietly running another arm
+            raise ValueError(
+                f"arm='fused' was forced but (M={M}, N={N}) has no block plan "
+                f"(M must divide by one of {BLOCK_M_CANDIDATES}, N by one of "
+                f"{BLOCK_N_CANDIDATES})"
             )
+        bm, bn = planned
+        kwargs = dict(block_m=bm, block_n=bn, interpret=interpret, out_dtype=dtype)
+        if quantized:
+            return fused_lora_matmul_int8(
+                x.astype(dtype), q, qscale, a.astype(dtype), b.astype(dtype),
+                scale, **kwargs,
+            )
+        return fused_lora_matmul(
+            x.astype(dtype), base.astype(dtype), a.astype(dtype),
+            b.astype(dtype), scale, **kwargs,
+        )
 
     w = dequantize_int8(q, qscale, dtype) if quantized else base.astype(dtype)
     xd = x.astype(dtype)
@@ -394,10 +403,13 @@ def lora_matmul_grouped(
         num_adapters = min(S, M)
 
     if arm == "auto":
-        grouped_ok = jax.default_backend() == "tpu" and not quantized
+        # the grouped kernel puts one activation row in a program — a (1, K)
+        # block the chip's compiler refuses (tests/test_tpu_compile.py,
+        # ROADMAP A4) — so no backend has it as a candidate until it is
+        # rebuilt; forcing arm="grouped" on a TPU raises the compiler's error
         arm = choose_grouped_arm(
             M, K, N, r, num_adapters, _dtype_bytes(dtype), base_bytes,
-            grouped_available=grouped_ok,
+            grouped_available=False,
         )
 
     if arm in ("grouped", "looped") and not quantized:

@@ -32,8 +32,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from relora_tpu.parallel._compat import axis_size, shard_map
-
 from relora_tpu.parallel.mesh import DATA_AXIS, FSDP_AXIS, SEQUENCE_AXIS
 
 _NEG_INF = -1e30  # finite sentinel: keeps exp()/where math NaN-free
@@ -111,7 +109,7 @@ def _ring_attention_local(
 ) -> jax.Array:
     """Per-device body (runs under shard_map).  q: (B, S_local, N, H);
     k/v: (B, S_local, n_kv, H) with n_kv | N."""
-    ring = axis_size(axis_name)
+    ring = jax.lax.axis_size(axis_name)
     me = jax.lax.axis_index(axis_name)
     B, S, N, H = q.shape
     n_kv = k.shape[2]
@@ -163,7 +161,7 @@ def ring_attention(
     if scale is None:
         scale = q.shape[-1] ** -0.5
     spec = P((DATA_AXIS, FSDP_AXIS), seq_axis, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(
             _ring_attention_local,
             axis_name=seq_axis,
@@ -232,7 +230,7 @@ def _zz_positions(block: jax.Array, ring: int, C: int):
 def _ring_attention_zigzag_local(q, k, v, *, axis_name: str, scale: float, tile: int):
     """Per-device body for zigzag layout.  q: (B, 2C, N, H) local;
     k/v: (B, 2C, n_kv, H) grouped."""
-    ring = axis_size(axis_name)
+    ring = jax.lax.axis_size(axis_name)
     me = jax.lax.axis_index(axis_name)
     B, S2, N, H = q.shape
     C = S2 // 2
@@ -318,7 +316,7 @@ def ring_attention_zigzag(
         inv = jnp.asarray(zigzag_inverse(S, ring))
         q, k, v = (x[:, perm] for x in (q, k, v))
 
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(
             _ring_attention_zigzag_local, axis_name=seq_axis, scale=scale, tile=tile
         ),

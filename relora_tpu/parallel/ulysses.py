@@ -23,8 +23,6 @@ from typing import Optional
 import jax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from relora_tpu.parallel._compat import shard_map
-
 from relora_tpu.ops.attention import dot_product_attention
 from relora_tpu.parallel.mesh import DATA_AXIS, FSDP_AXIS, SEQUENCE_AXIS
 
@@ -68,7 +66,7 @@ def ulysses_attention(
     if scale is None:
         scale = q.shape[-1] ** -0.5
     spec = P((DATA_AXIS, FSDP_AXIS), seq_axis, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(
             _ulysses_local,
             axis_name=seq_axis,

@@ -52,12 +52,31 @@ def _zigzag_inputs(tokens: jax.Array, ring: int):
     return tokens[:, perm], labels[:, perm], perm[None, :]
 
 
+def _model_inputs(tokens: jax.Array, zigzag_ring: Optional[int], next_token_window: bool):
+    """``(tokens_in, labels, positions)`` for one batch of rows.
+
+    ``next_token_window``: each row is a ``seq_length + 1``-token window (what
+    the Megatron pipeline packs) — the model reads the first ``seq_length``
+    tokens and is scored on the last ``seq_length``.  For a causal model
+    these are the very predictions that reading the whole window and dropping
+    the last position's logits gives, but the model runs at ``seq_length``
+    (2048: tile-aligned, flash attention applies) instead of ``seq_length + 1``
+    (2049: every activation padded, and a shape the TPU compiler fails on at
+    one row per chip).  Otherwise the loss shifts inside (``labels=None``)."""
+    if zigzag_ring:
+        return _zigzag_inputs(tokens, zigzag_ring)
+    if next_token_window:
+        return tokens[:, :-1], tokens[:, 1:], None
+    return tokens, None, None
+
+
 def _make_loss_fn(
     model,
     *,
     loss_impl: str = "dense",
     vocab_chunk: int = 8192,
     zigzag_ring: Optional[int] = None,
+    next_token_window: bool = False,
 ) -> Callable:
     """``loss_fn(trainable, frozen, tokens, rng) -> loss`` shared by the
     train step and the watch-histogram pass (one definition of the
@@ -68,10 +87,7 @@ def _make_loss_fn(
 
     def loss_fn(trainable: PyTree, frozen: PyTree, tokens: jax.Array, rng) -> jax.Array:
         params = combine(trainable, frozen)
-        if zigzag_ring:
-            tokens_in, labels, positions = _zigzag_inputs(tokens, zigzag_ring)
-        else:
-            tokens_in, labels, positions = tokens, None, None
+        tokens_in, labels, positions = _model_inputs(tokens, zigzag_ring, next_token_window)
         if loss_impl == "chunked":
             from relora_tpu.train.losses import chunked_softmax_ce
 
@@ -114,6 +130,7 @@ def make_train_step(
     schedule: Optional[Callable] = None,
     grad_breakdown: bool = False,
     zigzag_ring: Optional[int] = None,
+    next_token_window: bool = False,
     loss_impl: str = "dense",  # dense | chunked (streamed vocab CE)
     vocab_chunk: int = 8192,
     log_per_layer_scaling: bool = False,
@@ -121,7 +138,8 @@ def make_train_step(
 ) -> Callable[[TrainState, jax.Array, jax.Array], Tuple[TrainState, dict]]:
     """Build ``train_step(state, batch, rng) -> (state, metrics)``.
 
-    ``batch``: int32 token ids shaped ``(grad_accum, microbatch, seq)``.
+    ``batch``: int32 token ids shaped ``(grad_accum, microbatch, seq)`` —
+    ``seq + 1`` under ``next_token_window`` (:func:`_model_inputs`).
     With ``zigzag_ring`` set, the model runs in the zigzag sequence layout
     (attention impl 'ring_zigzag'): tokens/positions/labels are permuted
     consistently inside the step.  The returned function is pure; jit it
@@ -136,7 +154,11 @@ def make_train_step(
     """
 
     loss_fn = _make_loss_fn(
-        model, loss_impl=loss_impl, vocab_chunk=vocab_chunk, zigzag_ring=zigzag_ring
+        model,
+        loss_impl=loss_impl,
+        vocab_chunk=vocab_chunk,
+        zigzag_ring=zigzag_ring,
+        next_token_window=next_token_window,
     )
     grad_fn = jax.value_and_grad(loss_fn)
 
@@ -251,6 +273,7 @@ def make_eval_step(
     zigzag_ring: Optional[int] = None,
     loss_impl: str = "dense",
     vocab_chunk: int = 8192,
+    next_token_window: bool = False,
 ) -> Callable[[PyTree, jax.Array], dict]:
     """``eval_step(params, tokens) -> {loss_sum_weighted, n_tokens}``.
 
@@ -261,10 +284,7 @@ def make_eval_step(
     """
 
     def eval_step(params: PyTree, tokens: jax.Array) -> dict:
-        if zigzag_ring:
-            tokens_in, labels, positions = _zigzag_inputs(tokens, zigzag_ring)
-        else:
-            tokens_in, labels, positions = tokens, None, None
+        tokens_in, labels, positions = _model_inputs(tokens, zigzag_ring, next_token_window)
         if loss_impl == "chunked":
             from relora_tpu.train.losses import chunked_softmax_ce
 
@@ -301,6 +321,7 @@ def make_watch_histograms(
     loss_impl: str = "dense",
     vocab_chunk: int = 8192,
     zigzag_ring: Optional[int] = None,
+    next_token_window: bool = False,
 ):
     """Parameter + gradient histograms per top-level subtree — the
     observability ``wandb.watch(model)`` provided in the reference
@@ -320,7 +341,11 @@ def make_watch_histograms(
     edges and the counts summed — no concatenated f32 copy of the whole
     subtree (that transient would double the frozen base's footprint)."""
     loss_fn = _make_loss_fn(
-        model, loss_impl=loss_impl, vocab_chunk=vocab_chunk, zigzag_ring=zigzag_ring
+        model,
+        loss_impl=loss_impl,
+        vocab_chunk=vocab_chunk,
+        zigzag_ring=zigzag_ring,
+        next_token_window=next_token_window,
     )
 
     def hist_tree(tree: PyTree, prefix: str) -> dict:

@@ -107,10 +107,6 @@ def read_split(path: str):
 def main(argv=None):
     args = parse_args(argv)
 
-    from relora_tpu.utils.logging import honor_platform_request
-
-    honor_platform_request()
-
     import numpy as np
 
     from relora_tpu.config.model import load_model_config

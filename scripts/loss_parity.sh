@@ -1,5 +1,5 @@
 #!/usr/bin/env bash
-# Loss-parity experiment (BASELINE.md quality target): ReLoRA vs full-rank
+# Loss-parity experiment (BASELINE.json quality target): ReLoRA vs full-rank
 # at matched tokens, llama_35m on a ~100M-token local corpus.
 #
 # Mirrors the reference recipe structure (README.md:69-89): a shared

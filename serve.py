@@ -254,17 +254,11 @@ def _decode_tokens(tokens, tokenizer) -> str:
 
 
 def main(argv=None) -> int:
-    from relora_tpu.utils.logging import (
-        enable_xla_overlap_flags,
-        get_logger,
-        honor_platform_request,
-    )
+    from relora_tpu.utils.logging import enable_compile_cache, get_logger
 
-    honor_platform_request()
-    # before the first jax import: a tensor-sharded serving engine overlaps
-    # its attention/mlp collectives the same way the train step does
-    enable_xla_overlap_flags()
     args = parse_args(argv)
+    # a restarted server loads prefill/decode/warm-up programs from disk
+    enable_compile_cache()
     logger = get_logger("relora_tpu.serve")
 
     from relora_tpu.utils import faults

@@ -521,8 +521,13 @@ def test_router_never_routes_to_warming_replica():
     try:
         with harness as router:
             harness.wait_healthy(1)
-            assert router.replicas["cold"].healthy is False
+            # the prober reaches the replicas one after another: wait until
+            # it has been to the cold one instead of assuming it was first
+            deadline = time.monotonic() + 10.0
+            while router.replicas["cold"].status != "warming" and time.monotonic() < deadline:
+                time.sleep(0.01)
             assert router.replicas["cold"].status == "warming"
+            assert router.replicas["cold"].healthy is False
             for _ in range(6):
                 status, headers, _ = router_http(
                     router.port, "POST", "/v1/generate",

@@ -1,6 +1,8 @@
 """The shared bench measurement core (relora_tpu/utils/benchlib.py) and the
 attention-impl fallbacks the benches rely on."""
 
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -98,21 +100,23 @@ def test_remat_policy_unknown_raises():
 
 
 def test_enable_compile_cache_env_control(monkeypatch):
-    """RELORA_TPU_COMPILE_CACHE=0 leaves the config untouched; a path value
-    selects the directory; default picks the shared tmp dir."""
-    from relora_tpu.utils.logging import enable_compile_cache
+    """JAX_COMPILATION_CACHE_DIR set -> JAX reads it itself and the code sets
+    no directory; unset -> the one fixed path inside the checkout."""
+    from relora_tpu.utils import logging as rlog
 
     before = jax.config.jax_compilation_cache_dir
     try:
-        monkeypatch.setenv("RELORA_TPU_COMPILE_CACHE", "0")
-        enable_compile_cache()
-        assert jax.config.jax_compilation_cache_dir == before
+        jax.config.update("jax_compilation_cache_dir", "sentinel")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/outside")
+        assert rlog.enable_compile_cache() == "/somewhere/outside"
+        assert jax.config.jax_compilation_cache_dir == "sentinel"
 
-        monkeypatch.setenv("RELORA_TPU_COMPILE_CACHE", "/tmp/cache_test_dir")
-        enable_compile_cache()
-        assert jax.config.jax_compilation_cache_dir == "/tmp/cache_test_dir"
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        fixed = os.path.join(repo, ".jax_compile_cache")
+        assert rlog.enable_compile_cache() == fixed == rlog.COMPILE_CACHE_DIR
+        assert jax.config.jax_compilation_cache_dir == fixed
     finally:
-        # restore the conftest's cache config for later tests
         jax.config.update("jax_compilation_cache_dir", before)
 
 
@@ -148,3 +152,27 @@ def test_pallas_block_size_selection():
     assert flash_block_size(8, 8) is None
     assert flash_block_size(200, 200) is None
     assert flash_block_size(1024, 96) is None
+
+
+@pytest.mark.usefixtures("devices")
+def test_flash_partitionable_follows_the_current_mesh():
+    """GSPMD cannot partition a Mosaic kernel, so under a multi-device mesh
+    the flash arm runs per shard: a candidate only where batch and heads
+    split exactly (not the batch-1 init trace)."""
+    from relora_tpu.ops.attention import flash_partitionable
+    from relora_tpu.parallel.mesh import MeshSpec, current_mesh, make_mesh, set_current_mesh
+
+    before = current_mesh()
+    try:
+        set_current_mesh(None)
+        assert flash_partitionable(1, 8, 8)
+        set_current_mesh(make_mesh(MeshSpec(data=1, fsdp=1), devices=jax.devices()[:1]))
+        assert flash_partitionable(1, 8, 8)
+        set_current_mesh(make_mesh(MeshSpec(data=1, fsdp=4, tensor=2)))
+        assert flash_partitionable(4, 8, 8)
+        assert not flash_partitionable(1, 8, 8)  # batch does not split over fsdp
+        assert not flash_partitionable(4, 8, 1)  # kv heads do not split over tensor
+        set_current_mesh(make_mesh(MeshSpec(data=1, fsdp=2, sequence=2), devices=jax.devices()[:4]))
+        assert not flash_partitionable(4, 8, 8)  # sequence-sharded: ring/ulysses, not flash
+    finally:
+        set_current_mesh(before)

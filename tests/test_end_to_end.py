@@ -206,7 +206,7 @@ def test_warm_start_from_full_rank(tmp_path):
 def test_relora_quality_tracks_full_rank(tmp_path):
     """The paper's quality claim at toy scale: ReLoRA (warmup -> LoRA cycles
     with merges) reaches an eval loss close to full-rank training on the same
-    total step budget (BASELINE.md: 'loss within 1% of full-rank' at scale;
+    total step budget (BASELINE.json: 'loss within 1% of full-rank' at scale;
     here we allow a loose factor since the model/data are tiny)."""
     from relora_tpu.train.trainer import Trainer
 

@@ -128,9 +128,6 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 # stderr and exits instead of hanging the suite to the phase deadline.
 faulthandler.dump_traceback_later(360, exit=True)
 import jax
-jax.config.update("jax_platforms", "cpu")
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_test_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 coordinator, pid, workdir, steps = sys.argv[1], int(sys.argv[2]), sys.argv[3], sys.argv[4]
 jax.distributed.initialize(coordinator_address=coordinator, num_processes=2, process_id=pid)
 assert jax.process_count() == 2 and len(jax.devices()) == 4

@@ -317,29 +317,32 @@ def test_dump_on_fault_env_dir_and_empty_buffer(tmp_path, monkeypatch):
 
 
 class _FakeDevice:
-    def __init__(self, kind):
+    def __init__(self, kind, platform="tpu"):
         self.device_kind = kind
+        self.platform = platform
 
 
 def test_peak_flops_table_and_env_override(monkeypatch):
     monkeypatch.delenv("RELORA_TPU_PEAK_FLOPS", raising=False)
-    assert peak_flops(_FakeDevice("TPU v5e")) == 197e12
+    # "TPU v5 lite" is what a v5e chip reports as its device_kind
+    assert peak_flops(_FakeDevice("TPU v5 lite")) == 197e12
     assert peak_flops(_FakeDevice("TPU v5p chip")) == 459e12
     assert peak_flops(_FakeDevice("TPU v6e")) == 918e12
     assert peak_flops(_FakeDevice("TPU v4")) == 275e12
-    assert peak_flops(_FakeDevice("NVIDIA H100 80GB")) == 989e12
-    assert peak_flops(_FakeDevice("cpu")) == PEAK_FLOPS_DEFAULT
+    assert peak_flops(_FakeDevice("NVIDIA H100 80GB", "gpu")) == 989e12
+    assert peak_flops(_FakeDevice("cpu", "cpu")) == PEAK_FLOPS_DEFAULT
     monkeypatch.setenv("RELORA_TPU_PEAK_FLOPS", "123e12")
-    assert peak_flops(_FakeDevice("TPU v5e")) == 123e12  # override wins
+    assert peak_flops(_FakeDevice("NVIDIA H100 80GB", "gpu")) == 123e12  # off-TPU override wins
+    assert peak_flops(_FakeDevice("TPU v5 lite")) == 197e12  # a TPU reads the table only
 
 
 def test_step_flops_from_cost_analysis_shapes():
     assert step_flops_from_cost_analysis({"flops": 5.0}) == 5.0
-    assert step_flops_from_cost_analysis([{"flops": 2.0}, {"flops": 3.0}]) == 5.0
+    assert step_flops_from_cost_analysis({"flops": 5, "bytes accessed": 9.0}) == 5.0
     assert step_flops_from_cost_analysis(None) is None
     assert step_flops_from_cost_analysis({}) is None
-    assert step_flops_from_cost_analysis([{"flops": 0.0}]) is None
-    assert step_flops_from_cost_analysis([{"bytes": 1}, "junk"]) is None
+    assert step_flops_from_cost_analysis({"flops": 0.0}) is None
+    assert step_flops_from_cost_analysis({"bytes": 1}) is None
 
 
 def test_benchlib_peak_flops_alias():
@@ -398,28 +401,33 @@ def test_trace_report_reads_jsonl_stream(tmp_path):
 
 def test_peak_flops_device_without_kind_and_env_precedence(monkeypatch):
     monkeypatch.delenv("RELORA_TPU_PEAK_FLOPS", raising=False)
-    # a device object with no device_kind attribute at all -> default
+    # off-TPU: no device_kind attribute at all, or an unknown kind -> default
     assert peak_flops(object()) == PEAK_FLOPS_DEFAULT
-    assert peak_flops(_FakeDevice("")) == PEAK_FLOPS_DEFAULT
-    assert peak_flops(_FakeDevice("made-up accelerator 9000")) == PEAK_FLOPS_DEFAULT
-    # the env override wins over everything, including unknown kinds
+    assert peak_flops(_FakeDevice("", "cpu")) == PEAK_FLOPS_DEFAULT
+    assert peak_flops(_FakeDevice("made-up accelerator 9000", "gpu")) == PEAK_FLOPS_DEFAULT
+    # a TPU the table has never heard of is an error, not a default ...
+    with pytest.raises(ValueError, match="TPU v9 mega"):
+        peak_flops(_FakeDevice("TPU v9 mega"))
+    # ... and the env override is no rescue on that path
     monkeypatch.setenv("RELORA_TPU_PEAK_FLOPS", "42e12")
+    with pytest.raises(ValueError, match="TPU v9 mega"):
+        peak_flops(_FakeDevice("TPU v9 mega"))
     assert peak_flops(object()) == 42e12
-    assert peak_flops(None) == 42e12
+    assert peak_flops(None) == 42e12  # the suite's jax.devices()[0] is a CPU
 
 
 def test_step_flops_from_cost_analysis_hostile_inputs():
-    # non-iterable / wrong-typed cost objects must signal fallback, not raise
+    # wrong-typed cost objects must signal fallback, not raise
     assert step_flops_from_cost_analysis(42) is None
     assert step_flops_from_cost_analysis("flops") is None
-    assert step_flops_from_cost_analysis([{"flops": "NaN-ish"}]) is None
-    assert step_flops_from_cost_analysis([None, {"flops": 7.0}]) == 7.0
+    assert step_flops_from_cost_analysis({"flops": "NaN-ish"}) is None
+    assert step_flops_from_cost_analysis([{"flops": 7.0}]) is None
 
 
-def test_trainer_measure_step_flops_falls_back_to_6nd_when_lower_raises():
-    """When lowering/cost_analysis blows up, _measure_step_flops returns None
-    (the live-MFU gauge then uses the 6ND analytic estimate) instead of
-    failing the run."""
+def test_trainer_measure_step_flops_raises_when_lower_raises():
+    """A train step that cannot be lowered is not hidden behind the 6ND
+    estimate: _measure_step_flops lets the error through (the step itself
+    would fail at its first call anyway, with a worse message)."""
     import jax
     import numpy as np
     from jax.sharding import Mesh
@@ -434,7 +442,8 @@ def test_trainer_measure_step_flops_falls_back_to_6nd_when_lower_raises():
     tr.mesh = Mesh(np.array(jax.devices()).reshape(-1), ("dp",))
     tr._train_step = BadStep()
     tr.state = {"params": np.ones((2,), np.float32)}
-    assert tr._measure_step_flops(np.zeros((1, 2, 4), np.int32), jax.random.PRNGKey(0)) is None
+    with pytest.raises(RuntimeError, match="backend exploded"):
+        tr._measure_step_flops(np.zeros((1, 2, 4), np.int32), jax.random.PRNGKey(0))
 
 
 def test_trainer_measure_step_flops_honors_live_mfu_kill_switch(monkeypatch):
@@ -443,3 +452,43 @@ def test_trainer_measure_step_flops_honors_live_mfu_kill_switch(monkeypatch):
     monkeypatch.setenv("RELORA_TPU_LIVE_MFU", "0")
     tr = Trainer.__new__(Trainer)  # the kill switch returns before any field use
     assert tr._measure_step_flops(None, None) is None
+
+
+# ---------------------------------------------------------------------------
+# placement + collectives: what chip_smoke.py --multichip reads from a run
+
+
+def test_placement_counts_shard_bytes_per_device():
+    """A leaf sharded over four devices counts a quarter on each, a replicated
+    leaf in full on each; devices outside the mesh hold nothing."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from relora_tpu.obs.memory import placement
+
+    mesh = Mesh(np.array(jax.devices()[:4]), ("fsdp",))
+    sharded = jax.device_put(jnp.zeros((8, 16), jnp.float32), NamedSharding(mesh, P("fsdp")))
+    replicated = jax.device_put(jnp.zeros((4,), jnp.float32), NamedSharding(mesh, P()))
+    batch = jax.device_put(jnp.zeros((1, 4, 8), jnp.int32), NamedSharding(mesh, P(None, "fsdp")))
+    out = placement({"w": sharded, "b": replicated}, batch)
+    assert out["device_ids"] == [d.id for d in jax.local_devices()]
+    assert out["param_bytes"][:4] == [8 * 16 * 4 // 4 + 16] * 4
+    assert out["param_bytes"][4:] == [0] * (len(jax.local_devices()) - 4)
+    assert out["batch_devices"] == [d.id for d in jax.devices()[:4]]
+    assert out["bytes_in_use"] == [None] * len(jax.local_devices())  # CPU keeps no stats
+
+
+def test_collective_counts_reads_compiled_text():
+    from relora_tpu.obs.memory import collective_counts
+
+    text = """
+      %ag = f32[8]{0} all-gather(f32[2]{0} %p), dimensions={0}
+      %ars = f32[8]{0} all-reduce-start(f32[8]{0} %x), to_apply=%add
+      %ard = f32[8]{0} all-reduce-done(f32[8]{0} %ars)
+      %ar2 = f32[8]{0} all-reduce(f32[8]{0} %y), to_apply=%add
+      ROOT %t = f32[8]{0} add(%ard, %ar2), metadata={op_name="not an all-gather"}
+    """
+    assert collective_counts(text) == [["all-gather", 1], ["all-reduce", 2]]
+    assert collective_counts("ROOT %t = f32[8]{0} add(%a, %b)") == []

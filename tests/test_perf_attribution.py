@@ -362,11 +362,18 @@ def test_bench_gate_passes_on_committed_files():
 
 
 def test_bench_gate_fails_on_synthetic_regression(tmp_path):
-    base = json.loads((REPO / "BENCH_r05.json").read_text())
-    # the committed r05 is an outage replay (detail.stale); the gate rightly
-    # ignores those, so build the synthetic trajectory from fresh rounds
-    base["parsed"] = dict(base["parsed"], detail=dict(base["parsed"]["detail"]))
-    base["parsed"]["detail"].pop("stale", None)
+    # a driver round as bench.py's default run prints it on a chip
+    base = {
+        "n": 5,
+        "rc": 0,
+        "parsed": {
+            "metric": "llama_1b ReLoRA r=128 seq1024 bf16 training throughput",
+            "value": 7000.0,
+            "unit": "tokens/sec/chip",
+            "vs_baseline": 0.54,
+            "detail": {"mfu": 0.27, "step_time_s": 1.17, "device": "TPU v5 lite0"},
+        },
+    }
     (tmp_path / "BENCH_r05.json").write_text(json.dumps(base))
     worse = dict(base, n=6)
     worse["parsed"] = dict(base["parsed"], value=round(base["parsed"]["value"] * 0.8, 1))

@@ -29,7 +29,7 @@ def test_headline_config_fits_v5e():
 
 def test_no_remat_matches_measured_oom():
     """Without remat the dense S^2 f32 attention residuals dominate — the
-    on-chip compile fails allocating 51.5GB (BASELINE.md round-2 finding 2);
+    on-chip compile failed allocating 51.5GB (llama_1b, a record removed in PR 22);
     the estimate must land in the same does-not-fit regime."""
     out = run_plan(
         "--model", "llama_1b", "--rank", "128", "--micro-batch", "8",

@@ -1,0 +1,180 @@
+"""Compiles for a described TPU v5e: what the chip's compiler accepts.
+
+The Pallas interpreter accepts kernels that Mosaic refuses (a block not
+aligned to the 8x128 tiling, more VMEM than a kernel may use).  libtpu is
+installed here, and it compiles for a chip that is described and not attached
+— nothing runs, so these say nothing about numbers or times (chip_smoke.py
+does, on the chip); they guard every later PR against a kernel of the main
+path that no longer lowers, at no chip time.  All at ``pythia_1b`` widths:
+8 heads of 256, hidden 2048, FFN 8192, page 16.
+
+The one file with such compiles, and the topology is described inside a
+fixture (on-chip-measurement guide, section 2): only one process may load the
+TPU's library, so nothing here touches it at import or collection, and only
+the worker that is given this file ever does.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+N_HEADS, HEAD_DIM, HIDDEN, FFN = 8, 256, 2048, 8192
+PAGE, N_PAGES, TABLE_W, BATCH = 16, 512, 128, 8
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """ShapeDtypeStruct factory on the first described chip; the persistent
+    cache stays off around the compiles (an entry written for a described
+    chip cannot be read back without one)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    sharding = SingleDeviceSharding(topo.devices[0])
+    yield lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def compiled_text(fn, *args) -> str:
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _pool(S, dtype):
+    return S((N_PAGES, PAGE, N_HEADS, HEAD_DIM), dtype)
+
+
+@pytest.mark.parametrize("q_len, kv_dtype", [(1, "bfloat16"), (5, "bfloat16"), (1, "int8")])
+def test_paged_decode_attention_compiles(one_chip, q_len, kv_dtype):
+    """Decode (S=1), the speculative verify window (S=5) and the int8 pool."""
+    from relora_tpu.ops.attention import paged_decode_attention
+
+    S = one_chip
+    args = [
+        S((BATCH, q_len, N_HEADS, HEAD_DIM), jnp.bfloat16),
+        _pool(S, kv_dtype),
+        _pool(S, kv_dtype),
+        S((BATCH, TABLE_W), jnp.int32),
+        S((BATCH, q_len), jnp.int32),
+    ]
+    if kv_dtype == "int8":
+        args += [S((N_PAGES, N_HEADS), jnp.float32)] * 2
+
+    def fn(q, k, v, bt, pos, *scales):
+        kw = dict(k_scale=scales[0], v_scale=scales[1]) if scales else {}
+        return paged_decode_attention(q, k, v, bt, pos, **kw)
+
+    assert "tpu_custom_call" in compiled_text(fn, *args)
+
+
+@pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
+def test_packed_paged_attention_compiles(one_chip, kv_dtype):
+    from relora_tpu.ops.attention import packed_paged_attention
+
+    S, T = one_chip, 64
+    args = [
+        S((1, T, N_HEADS, HEAD_DIM), jnp.bfloat16),
+        _pool(S, kv_dtype),
+        _pool(S, kv_dtype),
+        S((BATCH + 1, TABLE_W), jnp.int32),
+        S((T,), jnp.int32),
+        S((T,), jnp.int32),
+    ]
+    if kv_dtype == "int8":
+        args += [S((N_PAGES, N_HEADS), jnp.float32)] * 2
+
+    def fn(q, k, v, bt, rm, pos, *scales):
+        kw = dict(k_scale=scales[0], v_scale=scales[1]) if scales else {}
+        return packed_paged_attention(q, k, v, bt, rm, pos, **kw)
+
+    assert "tpu_custom_call" in compiled_text(fn, *args)
+
+
+def test_flash_attention_forward_backward_compiles(one_chip):
+    """The trainer's attention at seq 2048, head 256, block 512."""
+    from relora_tpu.ops.attention import _pallas_attention
+
+    def fn(q, k, v):
+        def loss(q, k, v):
+            out = _pallas_attention(q, k, v, causal=True, scale=HEAD_DIM**-0.5)
+            return out.astype(jnp.float32).sum()
+
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    qkv = [one_chip((2, 2048, N_HEADS, HEAD_DIM), jnp.bfloat16)] * 3
+    assert "tpu_custom_call" in compiled_text(fn, *qkv)
+
+
+def _lora_args(S, M, K, N, r=128):
+    bf = jnp.bfloat16
+    return S((M, K), bf), S((K, N), bf), S((K, r), bf), S((r, N), bf)
+
+
+def test_fused_lora_matmul_forward_compiles_at_decode_m(one_chip):
+    """x @ W_qkv + LoRA at decode M=8 (hidden 2048 -> 3 x 2048)."""
+    from relora_tpu.ops.lora_dispatch import lora_matmul
+
+    fn = functools.partial(lora_matmul, scale=0.25, arm="fused", interpret=False)
+    args = _lora_args(one_chip, BATCH, HIDDEN, 3 * HIDDEN)
+    assert "tpu_custom_call" in compiled_text(lambda x, w, a, b: fn(x, w, a, b), *args)
+
+
+@pytest.mark.xfail(
+    strict=True,
+    reason="Mosaic: 'Ran out of memory in memory space vmem ... Scoped allocation "
+    "with size 16.25M and limit 16.00M' — _backward_dx holds whole-N blocks of g "
+    "and W (ops/pallas_lora_matmul.py); ROADMAP A4",
+)
+def test_fused_lora_matmul_backward_compiles_at_ffn_width(one_chip):
+    """The training shape: 4096 tokens through h_to_4h (2048 -> 8192)."""
+    from relora_tpu.ops.lora_dispatch import lora_matmul
+
+    def fn(x, w, a, b):
+        def loss(x, a, b):
+            y = lora_matmul(x, w, a, b, 0.25, arm="fused", interpret=False)
+            return y.astype(jnp.float32).sum()
+
+        return jax.grad(loss, argnums=(0, 1, 2))(x, a, b)
+
+    assert "tpu_custom_call" in compiled_text(fn, *_lora_args(one_chip, 4096, HIDDEN, FFN))
+
+
+@pytest.mark.xfail(
+    strict=True,
+    reason="Pallas TPU lowering: 'the last two dimensions of your block shape are "
+    "divisible by 8 and 128' — one activation row per program, block (1, K) over "
+    "(T, K) (ops/pallas_lora_matmul.py _grouped_forward); ROADMAP A4",
+)
+def test_grouped_lora_matmul_compiles(one_chip):
+    """The multi-tenant decode composite: 8 rows over 4 adapter slots."""
+    from relora_tpu.ops.lora_dispatch import lora_matmul_grouped
+
+    S, bf, slots, r = one_chip, jnp.bfloat16, 4, 128
+    args = (
+        S((BATCH, HIDDEN), bf),
+        S((HIDDEN, 3 * HIDDEN), bf),
+        S((slots, HIDDEN, r), bf),
+        S((slots, r, 3 * HIDDEN), bf),
+        S((slots,), jnp.float32),
+        S((BATCH,), jnp.int32),
+    )
+
+    def fn(x, w, a, b, s, idx):
+        return lora_matmul_grouped(x, w, a, b, s, idx, arm="grouped", interpret=False)
+
+    assert "tpu_custom_call" in compiled_text(fn, *args)

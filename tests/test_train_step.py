@@ -189,6 +189,30 @@ def test_eval_step_returns_weighted_sums():
     assert np.isfinite(float(out["loss_sum"]))
 
 
+@pytest.mark.parametrize("loss_impl", ["dense", "chunked"])
+def test_next_token_window_gives_the_same_loss_at_the_aligned_length(loss_impl):
+    """A seq+1-token window (the Megatron pipeline's rows) read as seq inputs
+    + seq labels scores the very predictions that reading all seq+1 tokens
+    and dropping the last position's logits does — the model just runs at
+    seq (tile-aligned) instead of seq+1."""
+    model, state, _ = build()
+    tokens = jax.random.randint(jax.random.PRNGKey(2), (4, 17), 0, 128)
+    kw = dict(loss_impl=loss_impl, vocab_chunk=64)
+    whole = jax.jit(make_eval_step(model, **kw))(state.params, tokens)
+    window = jax.jit(make_eval_step(model, next_token_window=True, **kw))(state.params, tokens)
+    assert float(window["n_tokens"]) == float(whole["n_tokens"]) == 4 * 16
+    np.testing.assert_allclose(float(window["loss_sum"]), float(whole["loss_sum"]), rtol=1e-5)
+
+    # and the train step takes such windows: one update, finite loss
+    tx = build_optimizer(schedule=lambda s: 1e-2)
+    mask = trainable_param_mask(state.params)
+    step = jax.jit(
+        make_train_step(model, tx, mask, schedule=lambda s: 1e-2, next_token_window=True, **kw)
+    )
+    _, metrics = step(state, tokens[None], jax.random.PRNGKey(0))
+    assert np.isfinite(float(metrics["loss"]))
+
+
 @pytest.mark.usefixtures("devices")
 def test_sharded_train_step_on_mesh():
     """FSDP×TP×DP sharded step on 8 virtual devices: params sharded by the

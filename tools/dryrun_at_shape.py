@@ -266,9 +266,6 @@ def main() -> None:
     if "collective" not in flags:
         flags += COLLECTIVE_FLAGS
     os.environ["XLA_FLAGS"] = flags.strip()
-    from relora_tpu.utils.logging import honor_platform_request
-
-    honor_platform_request()
 
     out = run_at_shape(
         model=args.model,

@@ -296,12 +296,8 @@ def cmd_lr(argv) -> None:
     p.add_argument("--out", default="lr.png")
     args = p.parse_args(argv)
 
-    # analysis-only tool: always CPU (the sandbox env force-selects the TPU
-    # backend; evaluating a schedule needs no chip)
+    # analysis-only tool: always CPU (evaluating a schedule needs no chip)
     os.environ["JAX_PLATFORMS"] = "cpu"
-    from relora_tpu.utils.logging import honor_platform_request
-
-    honor_platform_request()
     from relora_tpu.core.schedules import make_schedule
 
     sched = make_schedule(
