@@ -42,9 +42,7 @@ from relora_tpu.obs.tracer import (
     Span,
     Tracer,
     chrome_trace_events,
-    default_tracer,
     new_trace_id,
-    set_default_tracer,
 )
 
 __all__ = [
@@ -85,7 +83,5 @@ __all__ = [
     "Span",
     "Tracer",
     "chrome_trace_events",
-    "default_tracer",
     "new_trace_id",
-    "set_default_tracer",
 ]

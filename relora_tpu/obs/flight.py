@@ -8,6 +8,14 @@ bounded memory, no I/O); ``train/resilience.PreemptionGuard`` and the
 trainer's crash/rollback paths call :func:`dump_on_fault` to write the
 buffer to disk as JSON that ``tools/trace_report.py`` renders.
 
+Beside the ring the recorder keeps **the capture**: the spans of the last
+``jax.profiler`` session, which the tracer marks ``profiled`` (a session was
+live at both their ends).  The ring turns over in seconds under serving
+traffic (a span per streamed token); the capture is only cleared by the next
+session's first span, so it is the host half of a captured profile — the
+trainer's ``--profile`` as much as a benchmark's traced run — and what
+:meth:`FlightRecorder.capture` hands a reader after the run.
+
 Dump location, first match wins: ``RELORA_TPU_FLIGHT_DIR`` env, the dir set
 via :func:`configure` (the trainer points this at ``save_dir``), the
 current directory.  Dumps are written atomically (tmp + rename) because the
@@ -34,22 +42,49 @@ __all__ = [
 #: of train steps at <1 MB resident; sized for forensics, not archival
 SPAN_CAPACITY = 2048
 EVENT_CAPACITY = 512
+#: the capture's bound: a profiler session is seconds long (a serving round
+#: leaves about ten context-managed spans, a train update four)
+CAPTURE_CAPACITY = 4096
 
 
 class FlightRecorder:
     """Thread-safe ring buffer of span/event dicts with atomic JSON dumps."""
 
-    def __init__(self, span_capacity: int = SPAN_CAPACITY, event_capacity: int = EVENT_CAPACITY):
+    def __init__(
+        self,
+        span_capacity: int = SPAN_CAPACITY,
+        event_capacity: int = EVENT_CAPACITY,
+        capture_capacity: int = CAPTURE_CAPACITY,
+    ):
         self._lock = threading.Lock()
         self._spans: "collections.deque[Dict[str, Any]]" = collections.deque(maxlen=span_capacity)
         self._events: "collections.deque[Dict[str, Any]]" = collections.deque(maxlen=event_capacity)
         self.dropped_spans = 0  # total appends beyond capacity
+        self._capture: List[Dict[str, Any]] = []
+        self._capture_capacity = capture_capacity
+        self._capture_open = False  # a profiled span came since the last unprofiled end
+        self.dropped_profiled = 0  # profiled spans of this session beyond the bound
 
     def add_span(self, span: Dict[str, Any]) -> None:
+        profiled = span.get("profiled")
         with self._lock:
             if len(self._spans) == self._spans.maxlen:
                 self.dropped_spans += 1
             self._spans.append(span)
+            if profiled:
+                if not self._capture_open:
+                    # the first span of a new session: the last one's go
+                    self._capture_open = True
+                    self._capture.clear()
+                    self.dropped_profiled = 0
+                if len(self._capture) < self._capture_capacity:
+                    self._capture.append(span)
+                else:
+                    self.dropped_profiled += 1
+            elif profiled is False:
+                # a span ended with no session live: whatever is profiled
+                # next belongs to another session
+                self._capture_open = False
 
     def add_event(self, event: Dict[str, Any]) -> None:
         with self._lock:
@@ -63,11 +98,20 @@ class FlightRecorder:
         with self._lock:
             return list(self._events)
 
+    def capture(self) -> List[Dict[str, Any]]:
+        """The ``profiled`` spans of the last profiler session, in the order
+        they ended (at most the capture's bound: ``dropped_profiled``)."""
+        with self._lock:
+            return list(self._capture)
+
     def clear(self) -> None:
         with self._lock:
             self._spans.clear()
             self._events.clear()
             self.dropped_spans = 0
+            self._capture.clear()
+            self._capture_open = False
+            self.dropped_profiled = 0
 
     def dump(self, path: str, reason: str = "manual") -> str:
         """Write the buffer as JSON (atomic rename).  Returns the path."""
@@ -79,6 +123,8 @@ class FlightRecorder:
                 "dropped_spans": self.dropped_spans,
                 "spans": list(self._spans),
                 "events": list(self._events),
+                "dropped_profiled": self.dropped_profiled,
+                "profiled_spans": list(self._capture),
             }
         d = os.path.dirname(os.path.abspath(path))
         os.makedirs(d, exist_ok=True)

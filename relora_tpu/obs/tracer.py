@@ -10,15 +10,19 @@ phases nest into a tree), and free-form attributes.
 
 Design constraints, in priority order:
 
-1. **Hot-loop safe.**  ``Tracer.span`` is called once or a handful of times
-   per decode step / train update; its cost is two ``time.monotonic()``
-   calls, a few dict stores, and one lock-guarded deque append — single-digit
-   microseconds against multi-millisecond steps (measured: ``bench.py --mode
-   obs_overhead``, budget <1% of step time).  No I/O on the hot path unless a
-   JSONL sink is explicitly configured.
+1. **Hot-loop safe.**  ``Tracer.span`` is called a dozen times per serving
+   round and four times per train update; its cost is two ``time.monotonic()``
+   calls, a few dict stores, one lock-guarded deque append and, once jax is
+   loaded, two ``is_enabled()`` checks (a profiler annotation only while a
+   session is live) — microseconds against rounds and updates
+   of hundreds of milliseconds (on the chip: PERF.md §6, "PR 26", gives what
+   the serving and the training cell read with a profiler session on and
+   off).  No I/O on the hot path unless a JSONL sink is explicitly configured.
 2. **Stdlib-only and jax-free**, like serve/admission and analysis/: the
    tracer must import fast and run in the asyncio front-end, the model
-   thread, and the signal handler that dumps the flight recorder.
+   thread, and the signal handler that dumps the flight recorder.  It never
+   imports jax; where the process has already loaded it, ``Tracer.span``
+   takes ``jax.profiler.TraceAnnotation`` from ``sys.modules`` (below).
 3. **Thread-safe with cross-thread spans.**  Nesting uses a *per-thread*
    stack (the trainer's single-threaded loop gets parent/child links for
    free); spans that start on one thread and end on another (a request's
@@ -27,9 +31,19 @@ Design constraints, in priority order:
 
 Finished spans land in a :class:`~relora_tpu.obs.flight.FlightRecorder`
 ring buffer (crash forensics) and, when configured, a JSONL stream.  Both
-export to Chrome/Perfetto trace-event JSON (``chrome_trace_events``) so
-spans overlay with the XLA timelines ``StepProfiler`` already writes —
-``chrome://tracing`` or https://ui.perfetto.dev open either.
+export to Chrome/Perfetto trace-event JSON (``chrome_trace_events``).
+
+**The profiler's clock.**  A span's own stamps are ``time.monotonic()``; an
+``.xplane.pb`` counts from its session's start, so the two do not line up.
+A context-managed span therefore also enters a
+``jax.profiler.TraceAnnotation`` of its name, with its scalar attributes and
+its ``span_id``: while a ``jax.profiler`` session is live that puts the span
+on the ``/host:CPU`` plane of the session's file, on the device planes'
+clock by construction (``tools/trace_report.py --xplane`` reads both from
+the one file).  Manual cross-thread spans get no annotation: a TraceMe scope
+begins and ends on one thread.  A span inside which the session was live at
+both ends is marked ``profiled: True`` and kept by the flight recorder's
+capture (:meth:`FlightRecorder.capture`) until the next session.
 """
 
 from __future__ import annotations
@@ -38,6 +52,7 @@ import contextlib
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 import uuid
@@ -49,8 +64,6 @@ __all__ = [
     "NoopTracer",
     "new_trace_id",
     "chrome_trace_events",
-    "default_tracer",
-    "set_default_tracer",
 ]
 
 
@@ -65,7 +78,7 @@ class Span:
 
     __slots__ = (
         "name", "trace_id", "span_id", "parent_id", "t_start", "t_end",
-        "attrs", "thread", "_tracer",
+        "attrs", "thread", "profiled", "_tracer", "_annotation",
     )
 
     def __init__(
@@ -86,11 +99,24 @@ class Span:
         self.t_end: Optional[float] = None
         self.attrs = attrs
         self.thread = threading.current_thread().name
+        # True: a profiler session was live at both ends; False: none was
+        # live at the end; None: not observed (manual span, no jax, or the
+        # session started inside the span)
+        self.profiled: Optional[bool] = None
         self._tracer = tracer
+        self._annotation = None  # the live TraceAnnotation of a span() block
 
     def set(self, **attrs: Any) -> "Span":
         self.attrs.update(attrs)
+        if self._annotation is not None:
+            self._annotation.set_metadata(**_scalars(attrs))
         return self
+
+    def drop(self) -> None:
+        """Close the span without recording it (a scheduler round that
+        turned out to have nothing to dispatch)."""
+        if self.t_end is None:
+            self.t_end = self._tracer.clock()
 
     @property
     def duration_s(self) -> Optional[float]:
@@ -107,7 +133,7 @@ class Span:
         return self.t_end - self.t_start
 
     def to_dict(self) -> Dict[str, Any]:
-        return {
+        d = {
             "name": self.name,
             "trace_id": self.trace_id,
             "span_id": self.span_id,
@@ -122,6 +148,14 @@ class Span:
             "service": self._tracer.service,
             "attrs": self.attrs,
         }
+        if self.profiled is not None:
+            d["profiled"] = self.profiled
+        return d
+
+
+def _scalars(attrs: Dict[str, Any]) -> Dict[str, Any]:
+    """The attributes a profiler annotation can carry as event stats."""
+    return {k: v for k, v in attrs.items() if isinstance(v, (bool, int, float, str))}
 
 
 class Tracer:
@@ -155,6 +189,9 @@ class Tracer:
         self.recorder = recorder
         self._ids = itertools.count(1)  # next() is atomic in CPython
         self._local = threading.local()
+        # jax.profiler.TraceAnnotation, taken from sys.modules the first time
+        # a span opens after the process has loaded jax; never imported here
+        self._annotation_cls = None
         self._jsonl_lock = threading.Lock()
         self._jsonl_path = jsonl_path
         self._jsonl_fh = None
@@ -172,6 +209,14 @@ class Tracer:
 
     def _next_id(self) -> str:
         return f"s{next(self._ids):06x}"
+
+    def _trace_annotation(self):
+        cls = self._annotation_cls
+        if cls is None:
+            # a half-imported jax has no ``profiler`` yet: look again next time
+            profiler = getattr(sys.modules.get("jax"), "profiler", None)
+            cls = self._annotation_cls = getattr(profiler, "TraceAnnotation", None)
+        return cls
 
     def _record(self, span: Span) -> None:
         d = span.to_dict()
@@ -194,11 +239,13 @@ class Tracer:
     ) -> Span:
         """Manual span (cross-thread capable): caller must call ``end()``.
         Does not join the per-thread nesting stack, but *reads* it: with no
-        explicit parent/trace, the calling thread's current span becomes the
-        parent."""
+        explicit parent, the calling thread's current span becomes the
+        parent — unless an explicit ``trace_id`` names another trace (a
+        request's ``decode`` span opened inside a batch-level ``round`` is
+        the request's, not the round's)."""
         stack = self._stack()
         top = stack[-1] if stack else None
-        if parent is None:
+        if parent is None and (trace_id is None or top is None or top.trace_id == trace_id):
             parent = top
         if trace_id is None:
             trace_id = parent.trace_id if parent is not None else self.default_trace_id
@@ -222,10 +269,20 @@ class Tracer:
         **attrs: Any,
     ):
         """Context-managed span with automatic nesting: children opened in
-        the same thread inside this block parent to it."""
+        the same thread inside this block parent to it.  Where jax is loaded
+        the block is also a ``jax.profiler.TraceAnnotation`` (module
+        docstring, "The profiler's clock")."""
         sp = self.start_span(name, trace_id=trace_id, parent=parent, **attrs)
         stack = self._stack()
         stack.append(sp)
+        annotation_cls = self._trace_annotation()
+        # with no session live the annotation would record nothing (nor would
+        # a session that starts inside the span take it up): two is_enabled()
+        # checks are all a span costs the profiler's way then
+        live = annotation_cls is not None and annotation_cls.is_enabled()
+        if live:
+            sp._annotation = annotation_cls(name, span_id=sp.span_id, **_scalars(attrs))
+            sp._annotation.__enter__()
         try:
             yield sp
         finally:
@@ -235,6 +292,16 @@ class Tracer:
                 stack.pop()
             elif sp in stack:
                 stack.remove(sp)
+            if live:
+                annotation, sp._annotation = sp._annotation, None
+                annotation.__exit__(None, None, None)
+            if annotation_cls is not None:
+                # both ends: the span inside which a session starts or stops
+                # is left out of the capture
+                if annotation_cls.is_enabled():
+                    sp.profiled = True if live else None
+                else:
+                    sp.profiled = False
             sp.end()
 
     def current_span(self) -> Optional[Span]:
@@ -283,6 +350,9 @@ class _NoopSpan:
     def set(self, **attrs: Any) -> "_NoopSpan":
         return self
 
+    def drop(self) -> None:
+        return None
+
     def end(self) -> float:
         return 0.0
 
@@ -308,7 +378,7 @@ _NOOP_CTX = _NoopCtx()
 
 class NoopTracer:
     """API-compatible tracer that records nothing — the control arm of the
-    overhead bench and the disabled state (``RELORA_TPU_TRACE=0``)."""
+    overhead bench and what a scheduler built without a tracer holds."""
 
     enabled = False
     service = "noop"
@@ -339,9 +409,13 @@ def chrome_trace_events(
     pid: Optional[int] = None,
 ) -> List[Dict[str, Any]]:
     """Convert recorded span/event dicts to Chrome trace-event JSON objects
-    (the ``traceEvents`` list).  Timestamps are monotonic microseconds — the
-    same clock family the XLA profiler emits, so loading both into Perfetto
-    lines the host phases up against device activity."""
+    (the ``traceEvents`` list).  Timestamps are ``time.monotonic()``
+    microseconds (``tools/trace_report.py`` shifts them onto wall time to
+    join a router's spans with its replicas').  That is not the XLA
+    profiler's clock: an ``.xplane.pb`` counts from its session's start.  To
+    see host spans against device activity, read the annotations
+    ``Tracer.span`` leaves in the profile itself
+    (``tools/trace_report.py --xplane``)."""
     pid = os.getpid() if pid is None else pid
     out: List[Dict[str, Any]] = []
     tids: Dict[str, int] = {}
@@ -395,27 +469,3 @@ def chrome_trace_events(
             }
         )
     return out
-
-
-# -- process default ---------------------------------------------------------
-
-_DEFAULT: Optional[Tracer] = None
-_DEFAULT_LOCK = threading.Lock()
-
-
-def default_tracer() -> Tracer:
-    """Lazy process-wide tracer (service "app").  Subsystems that care about
-    their service label (Trainer, GenerateServer) build their own; library
-    code that just wants to emit a span uses this."""
-    global _DEFAULT
-    with _DEFAULT_LOCK:
-        if _DEFAULT is None:
-            _DEFAULT = Tracer(service="app")
-        return _DEFAULT
-
-
-def set_default_tracer(tracer: Tracer) -> Optional[Tracer]:
-    global _DEFAULT
-    with _DEFAULT_LOCK:
-        prev, _DEFAULT = _DEFAULT, tracer
-        return prev

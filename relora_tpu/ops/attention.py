@@ -487,6 +487,7 @@ def paged_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, N * T, H), q.dtype),
         interpret=interpret,
+        name="paged_decode_attention",
     )(bt, pos, q3, pool_k, pool_v, ks, vs)
     return out.reshape(B, N, T, H).transpose(0, 2, 1, 3)
 
@@ -681,6 +682,7 @@ def packed_paged_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, N, H), q.dtype),
         interpret=interpret,
+        name="packed_paged_attention",
     )(rm, bt, pos, q3, pool_k, pool_v, ks, vs)
     return out.reshape(1, T, N, H)
 

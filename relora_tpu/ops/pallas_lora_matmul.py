@@ -130,6 +130,7 @@ def _forward(bm, bn, interpret, out_dtype, x2, base_operands, a, b, s):
             jax.ShapeDtypeStruct((M, r), _F32),
         ],
         interpret=interpret,
+        name="lora_matmul_fwd",
     )(x2, *base_operands, a, b, s)
     return y, z
 
@@ -182,6 +183,7 @@ def _grouped_forward(bn, interpret, out_dtype, idx, x2, w, a_stack, b_stack, s_s
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         interpret=interpret,
+        name="grouped_lora_matmul",
     )(idx, x2, w, a_stack, b_stack, s_stack)
 
 
@@ -337,6 +339,7 @@ def _backward_dx(bm, interpret, g, base_operands, a, b, s, x_dtype):
         out_specs=pl.BlockSpec((bm, bk), lambda i, k: (i, k)),
         out_shape=jax.ShapeDtypeStruct((M, K), x_dtype),
         interpret=interpret,
+        name="lora_matmul_bwd_dx",
     )(g, *base_operands, a, b, s)
 
 
@@ -363,6 +366,7 @@ def _backward_dab(bm, interpret, g, x2, z, b, s):
             jax.ShapeDtypeStruct((r, N), _F32),
         ],
         interpret=interpret,
+        name="lora_matmul_bwd_dab",
     )(g, x2, z, b, s)
     return da, db
 

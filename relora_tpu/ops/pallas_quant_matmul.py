@@ -57,6 +57,7 @@ def _pallas_forward(bm, bn, interpret, out_dtype, x2, q, scale):
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         interpret=interpret,
+        name="quant_matmul",
     )(x2, q, scale)
 
 

@@ -515,7 +515,10 @@ class ContinuousBatchingScheduler:
         self._observe("insert_seconds", time.monotonic() - t1)
         return cache, first_id
 
-    def _sample_rows(self, logits, slots) -> np.ndarray:
+    def _sample_rows(self, logits, slots) -> jax.Array:
+        """Every row's draw, enqueued and not waited for: the caller's read
+        of it is the round's one bulk pull (the paged rounds time the two
+        apart, as ``dispatch`` and ``pull``)."""
         temps = np.zeros(self.max_batch, np.float32)
         top_ps = np.ones(self.max_batch, np.float32)
         keys = []
@@ -526,14 +529,13 @@ class ContinuousBatchingScheduler:
             temps[slot_idx] = slot.request.temperature
             top_ps[slot_idx] = slot.request.top_p
             keys.append(self._request_key(slot.request, len(slot.tokens)))
-        drawn = self.engine._sample(
+        return self.engine._sample(
             logits,
             jnp.stack(keys),
             temperature=jnp.asarray(temps),
             top_k=self.top_k,
             top_p=jnp.asarray(top_ps),
         )
-        return np.asarray(drawn)
 
     def _emit_token(self, uid: int, token: int, index: int) -> None:
         callback = self._on_token.get(uid)
@@ -985,8 +987,11 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
         tid = self._trace_ids.get(req.uid)
         first_id = None
         t0 = time.monotonic()
+        # the request's trace, the round's child: one chunk of one request's
+        # prompt, inside the batch-level round that ran it
         with self.tracer.span(
-            "prefill_chunk", trace_id=tid, uid=req.uid, start=start, chunk=chunk
+            "prefill_chunk", trace_id=tid, parent=self.tracer.current_span(),
+            uid=req.uid, start=start, chunk=chunk, real=n_real,
         ):
             logits, self._pool = self.engine.prefill_chunk(
                 jnp.asarray(ids), start, self._ensure_pool(), table,
@@ -1011,7 +1016,8 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
                     top_k=self.top_k,
                     top_p=req.top_p,
                 )
-                first_id = int(np.asarray(first)[0])
+                with self.tracer.span("pull"):
+                    first_id = int(np.asarray(first)[0])
         self._observe("prefill_seconds", time.monotonic() - t0)
         if first_id is None:
             return  # more chunks to go; decode proceeds this round regardless
@@ -1396,16 +1402,16 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
             i: [int(t) for t in stacked[i, : int(ks[i])]] for i in eligible
         }
 
-    def _verify_round(self, drafts: Dict[int, List[int]], finished: List[Completion]) -> None:
-        """One ``(batch, spec_k+1)`` verify forward over every decoding row,
-        then the host-side accept walk.  Window row 0 carries the pending
-        token; rows ``1..k`` carry the drafts at consecutive positions.
-        Padding rows (free / prefilling / short drafts) write through the
-        trailing null column of the ``W+1``-wide tables at ``pos >=
-        cache_size``, so no live page is ever touched.  The walk commits the
-        longest accepted draft prefix plus one corrective token — greedy
-        rows by argmax match, sampled rows by rejection sampling — through
-        the same emit/finish flow as the plain path, stopping at EOS."""
+    def _verify_dispatch(self, drafts: Dict[int, List[int]]) -> tuple:
+        """Enqueue one ``(batch, spec_k+1)`` verify forward over every
+        decoding row and the accept draws over its logits; returns
+        ``(accept, alt, draft_mat, k_eff)``, the first two still on the
+        device, for the round's pull and its accept walk
+        (``_commit_spec_walk``).  Window row 0 carries the pending token;
+        rows ``1..k`` carry the drafts at consecutive positions.  Padding
+        rows (free / prefilling / short drafts) write through the trailing
+        null column of the ``W+1``-wide tables at ``pos >= cache_size``, so
+        no live page is ever touched."""
         spec_k = self.engine.spec_k
         S = spec_k + 1
         B = self.max_batch
@@ -1452,11 +1458,7 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
             top_k=self.top_k,
             top_p=jnp.asarray(top_ps),
         )
-        self._commit_spec_walk(
-            np.asarray(accept), np.asarray(alt), draft_mat, k_eff,
-            set(i for i, s in enumerate(self._slots) if s is not None and s.decoding),
-            finished,
-        )
+        return accept, alt, draft_mat, k_eff
 
     def _commit_spec_walk(
         self,
@@ -1466,14 +1468,15 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
         k_eff: np.ndarray,
         eligible: set,
         finished: List[Completion],
-    ) -> None:
+    ) -> int:
         """The host-side accept walk shared by the sequential verify round
         and the packed step: for each eligible row commit the longest
         accepted draft prefix plus one corrective token through the normal
         emit/finish flow, stopping at EOS.  ``eligible`` is the set of slot
         indices that actually rode the verify window (the packed step must
-        exclude slots it armed for decode *after* the dispatch)."""
-        drafted = accepted = 0
+        exclude slots it armed for decode *after* the dispatch).  Returns
+        the number of tokens committed."""
+        drafted = accepted = committed = 0
         for slot_idx in sorted(eligible):
             slot = self._slots[slot_idx]
             if slot is None or not slot.decoding:
@@ -1493,6 +1496,7 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
                 self._tokens[slot_idx] = tok
                 self._positions[slot_idx] = slot.pos
                 self._emit_token(req.uid, tok, len(slot.tokens) - 1)
+                committed += 1
                 self._finish_if_done(slot_idx, finished)
                 if self._slots[slot_idx] is None:
                     break  # EOS / budget inside the window: drop the rest
@@ -1501,6 +1505,7 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
         if self.obs_registry is not None and drafted:
             self.obs_registry.inc("spec_drafted_total", by=drafted)
             self.obs_registry.inc("spec_accepted_total", by=accepted)
+        return committed
 
     # -- the budgeted round ----------------------------------------------------
 
@@ -1509,80 +1514,143 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
         only), at most one prefill chunk, then one paged decode over every
         decoding slot.  Returns the requests that finished during it.
         ``packed=True`` replaces the whole round body with the single-
-        dispatch packed step (``_step_packed``)."""
+        dispatch packed step (``_step_packed``).
+
+        The round is split into spans where the device waits
+        (docs/observability.md, "The serving round"): ``round`` holds
+        ``admit``, ``prefill_chunk``, ``decode_step`` (``dispatch`` up to
+        the enqueue, with the rows' draws as ``sample`` inside it, ``pull``
+        for the blocking read), ``commit`` and ``round_metrics``; a step
+        that dispatched nothing leaves none."""
         if self._packed:
             return self._step_packed()
         finished: List[Completion] = []
         t_step = time.monotonic()
         d0 = self._dispatch_total
-        self._expire_deadlines(finished)
-        self._admit_pass(finished)
-        self._prefill_pass(finished)
-        admit_s = time.monotonic() - t_step
-        decoding = [
-            s is not None and s.decoding for s in self._slots
-        ]
-        n_decoding = sum(decoding)
-        if n_decoding == 0:
-            if self._dispatch_total > d0:
-                self._count_round()  # pure-prefill round still dispatched
-                self._admit_time_s += admit_s  # a 100%-stall round
-            elif any(s is not None and s.migrating for s in self._slots):
-                time.sleep(0.001)  # only parked handoffs: don't hot-spin
-            return finished  # pure-prefill round (or idle)
+        with self.tracer.span("round", round=self._round_total) as sp_round:
+            self._admit_round(finished)
+            self._prefill_pass(finished)
+            admit_s = time.monotonic() - t_step
+            n_decoding = sum(s is not None and s.decoding for s in self._slots)
+            if n_decoding == 0:
+                if self._dispatch_total > d0:
+                    self._count_round()  # pure-prefill round still dispatched
+                    self._admit_time_s += admit_s  # a 100%-stall round
+                    self._close_round(sp_round, 0, d0)
+                else:
+                    sp_round.drop()
+                    if any(s is not None and s.migrating for s in self._slots):
+                        time.sleep(0.001)  # only parked handoffs: don't hot-spin
+                return finished  # pure-prefill round (or idle)
 
-        t_decode = time.monotonic()
-        if self._spec == "ngram":
-            drafts = self._draft_pass()
-        elif self._spec == "model":
-            drafts = self._model_draft_pass()
-        else:
-            drafts = {}
-        n_drafted = sum(len(d) for d in drafts.values())
-        with self.tracer.span(
-            "decode_step",
-            step=self._step_count,
-            active_slots=n_decoding,
-            spec_drafted=n_drafted,
-        ):
-            if drafts:
-                # draft→verify→accept: the walk commits straight into the
-                # slots, so there is no next_tokens loop for this branch
-                self._verify_round(drafts, finished)
-                self._step_count += 1
-                next_tokens = None
+            t_decode = time.monotonic()
+            if self._spec == "ngram":
+                drafts = self._draft_pass()
+            elif self._spec == "model":
+                drafts = self._model_draft_pass()
             else:
-                # no row drafted (spec off, or nothing to look up): the
-                # plain warmed (batch, 1) decode shape
-                logits, self._pool = self.engine.decode_paged(
-                    self._ensure_pool(),
-                    jnp.asarray(self._tokens)[:, None],
-                    jnp.asarray(self._positions)[:, None],
-                    self._tables,
-                    adapter_idx=self._adapter_row,
-                )
-                self._count_dispatch(self.max_batch, n_decoding)
-                self._step_count += 1
-                masked = [
-                    s if (s is not None and s.decoding) else None for s in self._slots
-                ]
-                next_tokens = self._sample_rows(logits, masked).tolist()
-        decode_s = time.monotonic() - t_decode
-        self._observe("decode_step_seconds", decode_s)
-        self._count_round()
-        if next_tokens is not None:
-            for slot_idx, slot in enumerate(self._slots):
-                if slot is None or not slot.decoding:
-                    continue
-                tok = next_tokens[slot_idx]
-                slot.tokens.append(tok)
-                slot.pos += 1
-                self._tokens[slot_idx] = tok
-                self._positions[slot_idx] = slot.pos
-                self._emit_token(slot.request.uid, tok, len(slot.tokens) - 1)
-                self._finish_if_done(slot_idx, finished)
-        self._round_metrics(admit_s, decode_s, n_decoding)
+                drafts = {}
+            n_drafted = sum(len(d) for d in drafts.values())
+            rode = set(
+                i for i, s in enumerate(self._slots) if s is not None and s.decoding
+            )
+            with self.tracer.span(
+                "decode_step",
+                step=self._step_count,
+                active_slots=n_decoding,
+                spec_drafted=n_drafted,
+                kv_bytes=self._decode_kv_bytes(),
+            ):
+                with self.tracer.span("dispatch"):
+                    if drafts:
+                        # draft→verify→accept: one verify window per row
+                        accept, alt, draft_mat, k_eff = self._verify_dispatch(drafts)
+                    else:
+                        # no row drafted (spec off, or nothing to look up):
+                        # the plain warmed (batch, 1) decode shape
+                        logits, self._pool = self.engine.decode_paged(
+                            self._ensure_pool(),
+                            jnp.asarray(self._tokens)[:, None],
+                            jnp.asarray(self._positions)[:, None],
+                            self._tables,
+                            adapter_idx=self._adapter_row,
+                        )
+                        self._count_dispatch(self.max_batch, n_decoding)
+                        masked = [
+                            s if (s is not None and s.decoding) else None
+                            for s in self._slots
+                        ]
+                        with self.tracer.span("sample"):
+                            drawn = self._sample_rows(logits, masked)
+                    self._step_count += 1
+                with self.tracer.span("pull"):
+                    if drafts:
+                        accept, alt = np.asarray(accept), np.asarray(alt)
+                    else:
+                        next_tokens = np.asarray(drawn).tolist()
+            decode_s = time.monotonic() - t_decode
+            self._observe("decode_step_seconds", decode_s)
+            self._count_round()
+            with self.tracer.span("commit") as sp_commit:
+                if drafts:
+                    committed = self._commit_spec_walk(
+                        accept, alt, draft_mat, k_eff, rode, finished
+                    )
+                else:
+                    committed = self._commit_tokens(next_tokens, sorted(rode), finished)
+                sp_commit.set(tokens=committed)
+            with self.tracer.span("round_metrics"):
+                self._round_metrics(admit_s, decode_s, n_decoding)
+            self._close_round(sp_round, n_decoding, d0)
         return finished
+
+    # -- the round's pieces, shared by the sequential and the packed step ------
+
+    def _admit_round(self, finished: List[Completion]) -> None:
+        """The ``admit`` span: deadlines, then admission (host work only)."""
+        with self.tracer.span("admit") as sp:
+            seq0 = self._admit_seq
+            self._expire_deadlines(finished)
+            self._admit_pass(finished)
+            sp.set(admitted=self._admit_seq - seq0)
+            if not any(s is not None and not s.migrating for s in self._slots):
+                sp.drop()  # nothing to run: the round will leave no span either
+
+    def _decode_kv_bytes(self) -> float:
+        """The K/V bytes a decode over the live tokens must read, whatever
+        kernel reads them: every decoding row attends positions ``0..pos``."""
+        live = sum(s.pos + 1 for s in self._slots if s is not None and s.decoding)
+        return live * self._kv_bytes_per_token
+
+    def _commit_tokens(
+        self, next_tokens: List[int], rows: List[int], finished: List[Completion]
+    ) -> int:
+        """Append each decoded row's token, stream it, retire what is done;
+        returns the number of tokens committed."""
+        committed = 0
+        for slot_idx in rows:
+            slot = self._slots[slot_idx]
+            if slot is None:
+                continue  # retired by another row's token callback
+            committed += 1
+            tok = next_tokens[slot_idx]
+            slot.tokens.append(tok)
+            slot.pos += 1
+            self._tokens[slot_idx] = tok
+            self._positions[slot_idx] = slot.pos
+            self._emit_token(slot.request.uid, tok, len(slot.tokens) - 1)
+            self._finish_if_done(slot_idx, finished)
+        return committed
+
+    def _close_round(self, sp_round, n_decoding: int, d0: int) -> None:
+        sp_round.set(
+            decoding=n_decoding,
+            prefilling=sum(
+                s is not None and not s.decoding and not s.migrating
+                for s in self._slots
+            ),
+            dispatches=self._dispatch_total - d0,
+        )
 
     # -- dispatch accounting ----------------------------------------------------
 
@@ -1699,12 +1767,20 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
         keys, same scalar-vs-stacked key structure — so the drain is
         token-identical to the unpacked scheduler."""
         finished: List[Completion] = []
+        with self.tracer.span("round", round=self._round_total) as sp_round:
+            if not self._packed_round(sp_round, finished):
+                sp_round.drop()
+        return finished
+
+    def _packed_round(self, sp_round, finished: List[Completion]) -> bool:
+        """The packed round's body under its ``round`` span (the same span
+        names as the sequential ``step``); False when nothing was dispatched."""
         t_step = time.monotonic()
-        self._expire_deadlines(finished)
-        self._admit_pass(finished)
+        d0 = self._dispatch_total
+        self._admit_round(finished)
         admit_s = time.monotonic() - t_step
         if not any(s is not None for s in self._slots):
-            return finished
+            return False
 
         t_decode = time.monotonic()
         engine = self.engine
@@ -1724,7 +1800,7 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
 
         # decode/verify windows first — the budget never throttles decode
         # (ctor floor check); k_eff=0 rows ride the full window in spec mode,
-        # mirroring _verify_round
+        # mirroring _verify_dispatch
         draft_mat = np.zeros((B, max(spec_k, 1)), np.int32)
         k_eff = np.zeros(B, np.int32)
         uids = np.zeros(B, np.int32)
@@ -1779,7 +1855,7 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
         if n_real == 0:
             if any(s is not None and s.migrating for s in self._slots):
                 time.sleep(0.001)  # only parked handoffs: don't hot-spin
-            return finished  # nothing decodable and nothing left to prefill
+            return False  # nothing decodable and nothing left to prefill
         bucket = next(b for b in engine.packed_buckets() if b >= n_real)
         pad = bucket - n_real
         ids.extend([0] * pad)
@@ -1789,36 +1865,43 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
         self._pad_tokens += pad
         self._prefill_tokens += sum(n for _, _, n, _ in prefill_spans)
 
+        # slots whose prompt ends inside this dispatch: their first token is
+        # drawn with the same per-slot scalar call and (uid, 0) key as the
+        # sequential chunk path, so first tokens match exactly
+        ending = [
+            (slot_idx, off + n - 1)
+            for slot_idx, start, n, off in prefill_spans
+            if start + n >= len(self._slots[slot_idx].request.prompt)
+        ]
         with self.tracer.span(
             "decode_step",
             step=self._step_count,
             active_slots=n_decoding,
             spec_drafted=int(k_eff.sum()),
             packed_tokens=bucket,
+            kv_bytes=self._decode_kv_bytes(),
         ):
-            logits, self._pool = engine.step_paged(
-                self._ensure_pool(),
-                np.asarray(ids, np.int32)[None, :],
-                np.asarray(poss, np.int32)[None, :],
-                self._ptables,
-                np.asarray(rows, np.int32),
-                adapter_idx=np.asarray(adap, np.int32),
-            )
-            self._step_count += 1
-
-            # decode rows first (before any slot armed this round joins the
-            # decoding set): gather each window's logits from its packed
-            # offsets and reuse the sequential sampling calls unchanged
-            if n_decoding:
-                flat = logits[0]
-                if spec_mode:
+            with self.tracer.span("dispatch"):
+                logits, self._pool = engine.step_paged(
+                    self._ensure_pool(),
+                    np.asarray(ids, np.int32)[None, :],
+                    np.asarray(poss, np.int32)[None, :],
+                    self._ptables,
+                    np.asarray(rows, np.int32),
+                    adapter_idx=np.asarray(adap, np.int32),
+                )
+                self._step_count += 1
+                # decode rows: gather each window's logits from its packed
+                # offsets and reuse the sequential sampling calls unchanged
+                drawn = None
+                if n_decoding and spec_mode:
                     win_idx = np.zeros(B * S, np.int32)
                     for slot_idx, off in slot_off.items():
                         win_idx[slot_idx * S : (slot_idx + 1) * S] = off + np.arange(S)
-                    win = jnp.take(flat, jnp.asarray(win_idx), axis=0).reshape(
-                        B, S, flat.shape[-1]
+                    win = jnp.take(logits[0], jnp.asarray(win_idx), axis=0).reshape(
+                        B, S, logits.shape[-1]
                     )
-                    accept, alt = self._spec_sample(
+                    drawn = self._spec_sample(
                         win,
                         jnp.asarray(draft_mat),
                         self.key,
@@ -1829,76 +1912,81 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
                         top_k=self.top_k,
                         top_p=jnp.asarray(top_ps),
                     )
-                    self._commit_spec_walk(
-                        np.asarray(accept), np.asarray(alt), draft_mat, k_eff,
-                        set(slot_off), finished,
-                    )
-                else:
+                elif n_decoding:
                     sample_idx = np.zeros(B, np.int32)
                     for slot_idx, off in slot_off.items():
                         sample_idx[slot_idx] = off
-                    gathered = jnp.take(flat, jnp.asarray(sample_idx), axis=0)
+                    gathered = jnp.take(logits[0], jnp.asarray(sample_idx), axis=0)
                     masked = [
                         s if i in slot_off else None
                         for i, s in enumerate(self._slots)
                     ]
-                    next_tokens = self._sample_rows(gathered, masked).tolist()
-                    for slot_idx in sorted(slot_off):
-                        slot = self._slots[slot_idx]
-                        if slot is None:
-                            continue  # retired mid-walk (cannot happen here)
-                        tok = next_tokens[slot_idx]
-                        slot.tokens.append(tok)
-                        slot.pos += 1
-                        self._tokens[slot_idx] = tok
-                        self._positions[slot_idx] = slot.pos
-                        self._emit_token(slot.request.uid, tok, len(slot.tokens) - 1)
-                        self._finish_if_done(slot_idx, finished)
+                    with self.tracer.span("sample"):
+                        drawn = self._sample_rows(gathered, masked)
+                firsts = [
+                    engine._sample(
+                        logits[:, at, :],
+                        self._request_key(self._slots[slot_idx].request, 0),
+                        temperature=self._slots[slot_idx].request.temperature,
+                        top_k=self.top_k,
+                        top_p=self._slots[slot_idx].request.top_p,
+                    )
+                    for slot_idx, at in ending
+                ]
+            with self.tracer.span("pull"):
+                if drawn is not None and spec_mode:
+                    accept, alt = np.asarray(drawn[0]), np.asarray(drawn[1])
+                elif drawn is not None:
+                    next_tokens = np.asarray(drawn).tolist()
+                first_ids = [int(np.asarray(first)[0]) for first in firsts]
+        decode_s = time.monotonic() - t_decode
+        self._observe("decode_step_seconds", decode_s)
+        # dispatch and round tick together: a concurrent /healthz read
+        # between the engine call and here must never see the packed
+        # invariant (dispatches == rounds) transiently violated
+        self._count_dispatch(bucket, n_real)
+        self._count_round()
 
-            # prefill completions: the same per-slot scalar sample call and
-            # (uid, 0) key as the sequential chunk path, so first tokens
-            # match exactly; the slot joins the decode set next round
-            for slot_idx, start, n, off in prefill_spans:
+        with self.tracer.span("commit") as sp_commit:
+            # decode rows first, before any slot armed this round joins the
+            # decoding set
+            committed = 0
+            if drawn is not None and spec_mode:
+                committed = self._commit_spec_walk(
+                    accept, alt, draft_mat, k_eff, set(slot_off), finished
+                )
+            elif drawn is not None:
+                committed = self._commit_tokens(next_tokens, sorted(slot_off), finished)
+            for slot_idx, start, n, _ in prefill_spans:
+                if self._slots[slot_idx] is not None:
+                    self._slots[slot_idx].prefill_progress = start + n
+            # prefill completions: the slot joins the decode set next round
+            for (slot_idx, _), first_id in zip(ending, first_ids):
                 slot = self._slots[slot_idx]
                 if slot is None:
                     continue
                 req = slot.request
-                slot.prefill_progress = start + n
-                L = len(req.prompt)
-                if slot.prefill_progress < L:
-                    continue
-                first = engine._sample(
-                    logits[:, off + n - 1, :],
-                    self._request_key(req, 0),
-                    temperature=req.temperature,
-                    top_k=self.top_k,
-                    top_p=req.top_p,
-                )
-                first_id = int(np.asarray(first)[0])
                 if self.prefix_cache is not None:
                     self.prefix_cache.register(list(req.prompt), slot.pages)
                 slot.decoding = True
                 slot.tokens = [first_id]
-                slot.pos = L
+                slot.pos = len(req.prompt)
                 slot.t_first = time.monotonic()
                 slot.span = self.tracer.start_span(
                     "decode", trace_id=self._trace_ids.get(req.uid), uid=req.uid
                 )
                 self._tokens[slot_idx] = first_id
-                self._positions[slot_idx] = L
+                self._positions[slot_idx] = slot.pos
                 self._tables[slot_idx, : len(slot.pages)] = slot.pages
                 self._emit_token(req.uid, first_id, 0)
+                committed += 1
                 self._finish_if_done(slot_idx, finished)
                 self._maybe_migrate(slot_idx)
-        decode_s = time.monotonic() - t_decode
-        self._observe("decode_step_seconds", decode_s)
-        # dispatch and round tick together at round end: a concurrent
-        # /healthz read between the engine call and here must never see the
-        # packed invariant (dispatches == rounds) transiently violated
-        self._count_dispatch(bucket, n_real)
-        self._count_round()
-        self._round_metrics(admit_s, decode_s, n_decoding)
-        return finished
+            sp_commit.set(tokens=committed)
+        with self.tracer.span("round_metrics"):
+            self._round_metrics(admit_s, decode_s, n_decoding)
+        self._close_round(sp_round, n_decoding, d0)
+        return True
 
     # -- retirement (page bookkeeping) ----------------------------------------
 

@@ -396,6 +396,236 @@ def test_trace_report_reads_jsonl_stream(tmp_path):
 
 
 # ---------------------------------------------------------------------------
+# spans on the profiler's clock: annotations, the ``profiled`` mark, the capture
+
+
+def _load_trace_report():
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "trace_report", os.path.join(REPO, "tools", "trace_report.py")
+    )
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def _xplane_under(trace_dir):
+    import glob
+
+    [path] = glob.glob(os.path.join(str(trace_dir), "plugins", "profile", "*", "*.xplane.pb"))
+    return path
+
+
+def test_span_lands_on_the_host_plane_of_the_profile(tmp_path):
+    """A context-managed span inside a jax.profiler session is an event of
+    the written xplane's /host:CPU plane, with its name and attributes —
+    the ones given at open and the ones ``set`` later; a manual span is not."""
+    import jax
+
+    tr = Tracer(service="t", recorder=FlightRecorder())
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("round", round=7, note="x", skipped=[1, 2]) as sp:
+            with tr.span("pull"):
+                pass
+            sp.set(dispatches=2)
+        tr.start_span("sse_flush").end()
+    finally:
+        jax.profiler.stop_trace()
+    _, spans = _load_trace_report().load_xplane(_xplane_under(tmp_path))
+    by_name = {s["name"]: s for s in spans}
+    assert set(by_name) == {"round", "pull"}
+    rnd, pull = by_name["round"], by_name["pull"]
+    assert rnd["attrs"] == {"span_id": sp.span_id, "round": 7, "note": "x", "dispatches": 2}
+    # one clock: the child's event lies inside its parent's
+    assert rnd["start_ns"] <= pull["start_ns"]
+    assert pull["start_ns"] + pull["dur_ns"] <= rnd["start_ns"] + rnd["dur_ns"]
+
+
+def test_profiled_mark_needs_a_session_at_both_ends(tmp_path):
+    """The span inside which a session starts or stops is left out of the
+    capture; the spans wholly inside it are kept, and survive the ring."""
+    import jax
+
+    rec = FlightRecorder(span_capacity=2)
+    tr = Tracer(service="t", recorder=rec)
+    with tr.span("before"):
+        pass
+    with tr.span("straddles_start"):
+        jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("inside", n=1):
+            with tr.span("child"):
+                pass
+        manual = tr.start_span("manual")
+        manual.end()
+        with tr.span("straddles_stop"):
+            jax.profiler.stop_trace()
+    finally:
+        if tr._trace_annotation().is_enabled():
+            jax.profiler.stop_trace()
+    for _ in range(3):
+        with tr.span("after"):
+            pass
+    marks = {s["name"]: s.get("profiled") for s in [*rec.capture(), *rec.spans()]}
+    assert [s["name"] for s in rec.capture()] == ["child", "inside"]
+    assert marks["child"] is True and marks["inside"] is True and marks["after"] is False
+    # the ring of two has long turned over; the capture has not
+    assert [s["name"] for s in rec.spans()] == ["after", "after"]
+    assert "profiled" not in manual.to_dict()
+
+
+def test_capture_is_bounded_counts_drops_and_a_new_session_clears_it(tmp_path):
+    rec = FlightRecorder(capture_capacity=3)
+    span = lambda name, profiled: {"name": name, "profiled": profiled}  # noqa: E731
+    for i in range(5):
+        rec.add_span(span(f"a{i}", True))
+    rec.add_span({"name": "manual"})  # no mark: says nothing about the session
+    assert [s["name"] for s in rec.capture()] == ["a0", "a1", "a2"]
+    assert rec.dropped_profiled == 2
+    rec.add_span(span("late", None))  # started before the session did
+    rec.add_span(span("a5", True))  # still the same session
+    assert rec.dropped_profiled == 3
+    rec.add_span(span("between", False))  # ended with no session live
+    assert [s["name"] for s in rec.capture()] == ["a0", "a1", "a2"]
+    rec.add_span(span("b0", True))  # the next session's first span
+    assert [s["name"] for s in rec.capture()] == ["b0"] and rec.dropped_profiled == 0
+    path = rec.dump(str(tmp_path / "dump.json"))
+    with open(path) as fh:
+        payload = json.load(fh)
+    assert [s["name"] for s in payload["profiled_spans"]] == ["b0"]
+    assert payload["dropped_profiled"] == 0
+    rec.clear()
+    assert rec.capture() == []
+
+
+def test_tracer_never_imports_jax():
+    """In a process that never loads jax the tracer records spans exactly as
+    before, and jax is still not loaded afterwards."""
+    code = (
+        "import sys\n"
+        "from relora_tpu.obs.flight import FlightRecorder\n"
+        "from relora_tpu.obs.tracer import Tracer\n"
+        "rec = FlightRecorder()\n"
+        "tr = Tracer(service='router', recorder=rec)\n"
+        "with tr.span('outer', a=1) as sp:\n"
+        "    with tr.span('inner'):\n"
+        "        pass\n"
+        "    sp.set(b=2)\n"
+        "spans = rec.spans()\n"
+        "assert [s['name'] for s in spans] == ['inner', 'outer'], spans\n"
+        "assert spans[1]['attrs'] == {'a': 1, 'b': 2} and 'profiled' not in spans[1]\n"
+        "assert rec.capture() == []\n"
+        "assert 'jax' not in sys.modules and 'jaxlib' not in sys.modules\n"
+        "print('ok')\n"
+    )
+    out = subprocess.run(
+        [sys.executable, "-c", code], cwd=REPO, capture_output=True, text=True, timeout=60
+    )
+    assert out.returncode == 0 and out.stdout.strip() == "ok", out.stderr[-2000:]
+
+
+def test_a_span_of_another_trace_is_not_adopted_by_the_ambient_span():
+    """A request's manual span opened inside a batch-level round keeps its
+    own trace and gets no parent from the round; with no trace id of its own
+    (or the round's) the ambient span is its parent as before."""
+    tr = Tracer(service="t", recorder=FlightRecorder())
+    with tr.span("round") as rnd:
+        theirs = tr.start_span("decode", trace_id="request-1")
+        ours = tr.start_span("insert")
+        same = tr.start_span("insert", trace_id=rnd.trace_id)
+        with tr.span("prefill_chunk", trace_id="request-1", parent=tr.current_span()) as chunk:
+            pass
+    assert theirs.parent_id is None and theirs.trace_id == "request-1"
+    assert ours.parent_id == rnd.span_id and same.parent_id == rnd.span_id
+    assert chunk.parent_id == rnd.span_id and chunk.trace_id == "request-1"
+
+
+def test_dropped_span_is_not_recorded():
+    rec = FlightRecorder()
+    tr = Tracer(service="t", recorder=rec)
+    with tr.span("round") as sp:
+        with tr.span("admit"):
+            pass
+        sp.drop()
+    assert [s["name"] for s in rec.spans()] == ["admit"]
+    NoopTracer().span("round").__enter__().drop()  # same surface, nothing to drop
+
+
+def test_trace_report_puts_idle_gaps_down_to_host_spans():
+    """--xplane's reduction on hand-written events: gaps between the union
+    of device operations, the innermost span at a gap's midpoint, and a
+    round's host-only time."""
+    tr = _load_trace_report()
+    ms = 1e6
+    # busy 0-10 (a loop 0-10 holding its body 2-4), 30-40, 40.5-50, 52-60
+    ops = [(0, 10 * ms), (2 * ms, 2 * ms), (30 * ms, 10 * ms), (40.5 * ms, 9.5 * ms), (52 * ms, 8 * ms)]
+    assert tr.idle_gaps(ops) == [(10 * ms, 20 * ms), (50 * ms, 2 * ms)]  # the 0.5 ms gap is under the floor
+    assert sum(d for _, d in tr.idle_gaps(ops, 0.0)) == 22.5 * ms
+
+    def span(name, start, end, thread="model"):
+        return {"name": name, "start_ns": start * ms, "dur_ns": (end - start) * ms, "thread": thread, "attrs": {}}
+
+    spans = sorted(
+        [
+            span("round", 5, 58), span("admit", 5, 12), span("decode_step", 12, 45),
+            span("dispatch", 12, 14), span("pull", 14, 45), span("commit", 45, 55),
+            span("round_metrics", 55, 58), span("pull", 46, 47, thread="other"),
+        ],
+        key=lambda s: s["start_ns"],
+    )
+    assert tr.innermost_span(spans, 20 * ms)["name"] == "pull"
+    assert tr.innermost_span(spans, 51 * ms)["name"] == "commit"
+    assert tr.innermost_span(spans, 59 * ms) is None
+    [(rnd, inside, host_ns)] = tr.round_host_only(spans)
+    # 53 ms of round less dispatch start (12) .. last pull end on its thread (45)
+    assert rnd["name"] == "round" and host_ns == (53 - 33) * ms
+    assert [s["name"] for s in inside] == ["admit", "decode_step", "dispatch", "pull", "commit", "round_metrics"]
+
+
+# ---------------------------------------------------------------------------
+# every Pallas kernel carries a name a device trace can be searched for
+
+
+def _pallas_call_sites():
+    import ast
+
+    ops_dir = os.path.join(REPO, "relora_tpu", "ops")
+    sites = []
+    for fname in sorted(os.listdir(ops_dir)):
+        if not fname.endswith(".py"):
+            continue
+        with open(os.path.join(ops_dir, fname)) as fh:
+            tree = ast.parse(fh.read())
+        for node in ast.walk(tree):
+            if (
+                isinstance(node, ast.Call)
+                and isinstance(node.func, ast.Attribute)
+                and node.func.attr == "pallas_call"
+            ):
+                sites.append(pytest.param(node, id=f"{fname}:{node.lineno}"))
+    return sites
+
+
+@pytest.mark.parametrize("call", _pallas_call_sites())
+def test_every_pallas_call_is_named(call):
+    """The name reaches the HLO instruction (``%paged_decode_attention.N``),
+    which is how the benchmark's trace reduction finds a kernel's time."""
+    import ast
+
+    names = [kw.value for kw in call.keywords if kw.arg == "name"]
+    assert len(names) == 1, "pallas_call without name="
+    assert isinstance(names[0], ast.Constant) and isinstance(names[0].value, str)
+    assert names[0].value.isidentifier() and not names[0].value.startswith("_")
+
+
+def test_pallas_call_names_are_distinct():
+    names = [kw.value.value for p in _pallas_call_sites() for kw in p.values[0].keywords if kw.arg == "name"]
+    assert len(names) == 7 and len(set(names)) == 7, names
+
+
+# ---------------------------------------------------------------------------
 # MFU edge cases: the 6ND fallback path and peak-FLOPs resolution corners
 
 

@@ -294,3 +294,33 @@ def test_packed_warmup_no_steady_state_retrace():
             sched.cancel(1)
     sched.run([])
     assert engine.compile_watcher.steady_state_retraces == 0
+
+
+# -- the round's spans: the same names as the sequential step -----------------
+
+
+def test_packed_round_has_the_sequential_rounds_span_names():
+    """S1 will move the serving cell to the packed step: its rounds must
+    feed the same metrics, so they carry the same span names and attributes
+    (no ``prefill_chunk``: the one dispatch carries the prompts' tokens)."""
+    from tests.test_paging import check_round_tree, traced_drain
+
+    engine, _ = make_engine(TINY_LLAMA)
+    reqs = mixed_requests(TINY_LLAMA.vocab_size)
+    sched, rounds, children, completions, calls = traced_drain(
+        engine, reqs, spy="step_paged", packed=True
+    )
+    decode_steps = check_round_tree(rounds, children)
+    assert len(rounds) == len(decode_steps) == len(calls) == sched._round_total
+    for r in rounds:
+        names = [k["name"] for k in children[r["span_id"]]]
+        assert names == ["admit", "decode_step", "commit", "round_metrics"]
+        assert r["attrs"]["dispatches"] == 1
+    per_token = engine.kv_bytes_per_token()
+    for step, (_pool, _ids, poss, *_rest) in zip(decode_steps, calls):
+        # the decoding rows' one-token windows come first in the packed window
+        n = step["attrs"]["active_slots"]
+        want = float((np.asarray(poss)[0, :n] + 1).sum()) * per_token
+        assert step["attrs"]["kv_bytes"] == pytest.approx(want)
+    commits = [k for r in rounds for k in children[r["span_id"]] if k["name"] == "commit"]
+    assert sum(c["attrs"]["tokens"] for c in commits) == sum(len(c.tokens) for c in completions.values())

@@ -648,3 +648,116 @@ def test_paged_metrics_kv_bytes_gauges(tmp_path):
         assert sched.allocator.used_bytes == 0
         values[name] = step["serve/kv_cache_bytes"]
     assert values["int8"] < 0.55 * values["stored"]
+
+
+# -- the round's spans (docs/observability.md, "The serving round") -----------
+
+ROUND_CHILDREN = {"admit", "prefill_chunk", "decode_step", "commit", "round_metrics"}
+
+
+def traced_drain(engine, reqs, *, spy: str, **kwargs):
+    """Drain ``reqs`` under a real Tracer; returns the scheduler, the span
+    tree as ``(rounds, children by parent id)``, the completions and the
+    positional arguments of every call of the engine method ``spy``."""
+    from relora_tpu.obs.flight import FlightRecorder
+    from relora_tpu.obs.tracer import Tracer
+
+    rec = FlightRecorder(span_capacity=1 << 16)
+    calls = []
+    real = getattr(engine, spy)
+
+    def spied(*args, **kw):
+        # the scheduler's tables are live numpy state: keep them as they were
+        calls.append(tuple(a.copy() if isinstance(a, np.ndarray) else a for a in args))
+        return real(*args, **kw)
+
+    setattr(engine, spy, spied)
+    try:
+        sched = PagedContinuousBatchingScheduler(
+            engine, max_batch=2, eos_id=9, key=jax.random.PRNGKey(42),
+            tracer=Tracer(service="serve", recorder=rec), **kwargs,
+        )
+        # as the server submits: each request under its own trace id, so its
+        # ``decode`` span is the request's and not a child of the round
+        completions = {}
+        for req in reqs:
+            sched.submit(req, trace_id=f"request-{req.uid}")
+        while sched.has_work():
+            completions.update({c.uid: c for c in sched.step()})
+    finally:
+        delattr(engine, spy)  # the instance attribute; the method is the class's
+    assert rec.dropped_spans == 0
+    children = {}
+    for s in rec.spans():
+        children.setdefault(s["parent_id"], []).append(s)
+    rounds = [s for s in rec.spans() if s["name"] == "round"]
+    return sched, rounds, children, completions, calls
+
+
+def check_round_tree(rounds, children):
+    """Every round holds the children of the span table, each inside its
+    parent; returns the decode_step spans in round order."""
+    decode_steps = []
+    assert [r["attrs"]["round"] for r in rounds] == list(range(len(rounds)))
+    for r in rounds:
+        kids = children[r["span_id"]]
+        names = [k["name"] for k in kids]
+        assert set(names) <= ROUND_CHILDREN and names[0] == "admit", names
+        assert r["attrs"]["dispatches"] == names.count("prefill_chunk") + names.count("decode_step") > 0
+        if r["attrs"]["decoding"]:
+            assert names[-3:] == ["decode_step", "commit", "round_metrics"], names
+        for k in kids:
+            assert r["t_start"] <= k["t_start"] <= k["t_end"] <= r["t_end"]
+            grandkids = children.get(k["span_id"], [])
+            if k["name"] == "decode_step":
+                assert [g["name"] for g in grandkids] == ["dispatch", "pull"]
+                # the rows' draws, enqueued inside the dispatch (a packed
+                # round of prompt tokens only has no row to draw for)
+                samples = children.get(grandkids[0]["span_id"], [])
+                assert [g["name"] for g in samples] == ["sample"] * bool(k["attrs"]["active_slots"])
+                decode_steps.append(k)
+            elif k["name"] == "prefill_chunk":
+                assert [g["name"] for g in grandkids] in ([], ["pull"])
+                assert k["trace_id"].startswith("request-")  # the request's trace, the round's child
+                assert 0 < k["attrs"]["real"] <= k["attrs"]["chunk"]
+            else:
+                assert grandkids == []
+            for g in grandkids:
+                assert k["t_start"] <= g["t_start"] <= g["t_end"] <= k["t_end"]
+    return decode_steps
+
+
+def test_round_spans_split_the_round_where_the_device_waits():
+    """A drain under a real Tracer yields per ``round`` the children of the
+    span table, children inside the parent, ``kv_bytes`` equal to the sum of
+    (position + 1) over the decoding rows times ``kv_bytes_per_token``, and
+    token counts that add up to what was served."""
+    _, paged = make_engines(TINY_LLAMA)
+    reqs = mixed_requests(TINY_LLAMA.vocab_size)
+    sched, rounds, children, completions, calls = traced_drain(paged, reqs, spy="decode_paged")
+    decode_steps = check_round_tree(rounds, children)
+    assert len(rounds) == sched._round_total and len(decode_steps) == len(calls) > 4
+    per_token = paged.kv_bytes_per_token()
+    for step, (_pool, _tokens, positions, tables) in zip(decode_steps, calls):
+        live = np.asarray(tables).any(axis=1)  # a decoding row's table is not all null
+        want = float((np.asarray(positions)[:, 0] + 1)[live].sum()) * per_token
+        assert step["attrs"]["kv_bytes"] == pytest.approx(want) and want > 0
+        assert step["attrs"]["active_slots"] == int(live.sum())
+    served = sum(len(c.tokens) for c in completions.values())
+    commits = [k for r in rounds for k in children[r["span_id"]] if k["name"] == "commit"]
+    first_tokens = len(reqs)  # sampled inside the prompt's last prefill_chunk
+    assert sum(c["attrs"]["tokens"] for c in commits) + first_tokens == served
+    admits = [k for r in rounds for k in children[r["span_id"]] if k["name"] == "admit"]
+    assert sum(a["attrs"]["admitted"] for a in admits) == len(reqs)
+
+
+def test_idle_step_leaves_no_span():
+    from relora_tpu.obs.flight import FlightRecorder
+    from relora_tpu.obs.tracer import Tracer
+
+    _, paged = make_engines(TINY_LLAMA)
+    rec = FlightRecorder()
+    sched = PagedContinuousBatchingScheduler(
+        paged, max_batch=2, tracer=Tracer(service="serve", recorder=rec)
+    )
+    assert sched.step() == [] and rec.spans() == []

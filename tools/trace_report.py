@@ -15,9 +15,18 @@ dict per line — the trainer's ``RELORA_TPU_TRACE_DIR`` sink).  Prints:
 JSON — open in chrome://tracing or https://ui.perfetto.dev, where it overlays
 with the XLA timelines StepProfiler writes.
 
+``--xplane FILE.xplane.pb`` reads a ``jax.profiler`` profile instead: the
+device planes' operations and, from the ``/host:CPU`` plane of the same file
+and so on the same clock, the annotations ``Tracer.span`` left there.  It
+prints each device idle gap over a millisecond with the innermost host span
+open at its midpoint, the gap seconds per span name and per scheduler round,
+and each round's host-only time (its duration less the interval from its
+first ``dispatch`` or ``prefill_chunk`` start to its last ``pull`` end).
+
     python tools/trace_report.py ckpts/flight_sigterm_1234.json
     python tools/trace_report.py traces/train_spans.jsonl --trace a1b2c3
     python tools/trace_report.py dump.json --chrome /tmp/trace.json
+    python tools/trace_report.py --xplane .bench_work/<cell>/trace/plugins/profile/<t>/<host>.xplane.pb
 """
 
 from __future__ import annotations
@@ -206,10 +215,163 @@ def phase_summary(spans: List[Dict[str, Any]], out=sys.stdout) -> None:
         )
 
 
+# -- a jax.profiler profile: device idle gaps put down to host spans ------------
+
+#: the device planes' per-operation line, and the shortest gap worth a line
+XLA_OPS_LINE = "XLA Ops"
+MIN_GAP_NS = 1e6
+NO_SPAN = "(no span open)"
+
+
+def load_xplane(path: str) -> Tuple[Dict[str, List[Tuple[float, float]]], List[Dict[str, Any]]]:
+    """``({device plane: [(start_ns, duration_ns)]}, host spans)`` of one
+    ``.xplane.pb``.  A host span is an event of the ``/host:CPU`` plane that
+    carries a ``span_id`` stat, which only ``Tracer.span``'s annotations do:
+    ``{"name", "start_ns", "dur_ns", "thread", "attrs"}``."""
+    from jax.profiler import ProfileData
+
+    device_ops: Dict[str, List[Tuple[float, float]]] = {}
+    host_spans: List[Dict[str, Any]] = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/device:"):
+            for line in plane.lines:
+                if line.name == XLA_OPS_LINE:
+                    device_ops[plane.name] = [
+                        (float(e.start_ns), float(e.duration_ns)) for e in line.events
+                    ]
+        elif plane.name == "/host:CPU":
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("$"):
+                        continue  # the Python tracer's own function events
+                    stats = dict(e.stats)
+                    if "span_id" in stats:
+                        host_spans.append(
+                            {
+                                "name": e.name,
+                                "start_ns": float(e.start_ns),
+                                "dur_ns": float(e.duration_ns),
+                                "thread": line.name,
+                                "attrs": stats,
+                            }
+                        )
+    host_spans.sort(key=lambda s: s["start_ns"])
+    return device_ops, host_spans
+
+
+def idle_gaps(ops: List[Tuple[float, float]], min_ns: float = MIN_GAP_NS) -> List[Tuple[float, float]]:
+    """``(start_ns, duration_ns)`` of the gaps of at least ``min_ns`` between
+    the device's operations (their union: a loop's body lies inside it)."""
+    gaps, end = [], None
+    for start, dur in sorted(ops):
+        if end is not None and start - end >= min_ns:
+            gaps.append((end, start - end))
+        end = start + dur if end is None else max(end, start + dur)
+    return gaps
+
+
+def innermost_span(spans: List[Dict[str, Any]], t_ns: float) -> Optional[Dict[str, Any]]:
+    """The span open at ``t_ns`` that started last (``spans`` sorted by start)."""
+    best = None
+    for s in spans:
+        if s["start_ns"] > t_ns:
+            break
+        if t_ns < s["start_ns"] + s["dur_ns"]:
+            best = s
+    return best
+
+
+def round_host_only(spans: List[Dict[str, Any]]) -> List[Tuple[Dict[str, Any], List[Dict[str, Any]], float]]:
+    """Per ``round`` span: the spans of its thread that lie inside it, and its
+    host-only nanoseconds — its duration less the interval from its first
+    ``dispatch`` or ``prefill_chunk`` start to its last ``pull`` end."""
+    out = []
+    for r in (s for s in spans if s["name"] == "round"):
+        lo, hi = r["start_ns"], r["start_ns"] + r["dur_ns"]
+        inside = [
+            s for s in spans
+            if s is not r and s["thread"] == r["thread"]
+            and lo <= s["start_ns"] and s["start_ns"] + s["dur_ns"] <= hi
+        ]
+        starts = [s["start_ns"] for s in inside if s["name"] in ("dispatch", "prefill_chunk")]
+        ends = [s["start_ns"] + s["dur_ns"] for s in inside if s["name"] == "pull"]
+        if starts and ends:
+            out.append((r, inside, r["dur_ns"] - (max(ends) - min(starts))))
+    return out
+
+
+def xplane_report(path: str, out=sys.stdout, max_gaps: int = 40) -> int:
+    device_ops, spans = load_xplane(path)
+    if not device_ops:
+        out.write(f"{path}: no device plane with an {XLA_OPS_LINE!r} line\n")
+        return 1
+    rounds = round_host_only(spans)
+    # what "per round" divides by: serving rounds, else the trainer's updates
+    frame = "round" if rounds else "update_step"
+    n_frames = len(rounds) or sum(s["name"] == frame for s in spans)
+    out.write(
+        f"{path}\n{len(device_ops)} device plane(s), {len(spans)} host spans, "
+        f"{n_frames} whole {frame} spans\n"
+    )
+    for plane, ops in sorted(device_ops.items()):
+        gaps = idle_gaps(ops)
+        t0 = min(start for start, _ in ops)
+        t1 = max(start + dur for start, dur in ops)
+        all_idle = sum(d for _, d in idle_gaps(ops, 0.0))
+        by_name: Dict[str, List[float]] = {}
+        rows = []
+        for start, dur in gaps:
+            sp = innermost_span(spans, start + dur / 2)
+            name = sp["name"] if sp is not None else NO_SPAN
+            by_name.setdefault(name, []).append(dur)
+            rows.append((start, dur, name))
+        total = sum(d for _, d in gaps)
+        out.write(
+            f"\n{plane}: {(t1 - t0) / 1e9:.3f} s from first to last operation, idle "
+            f"{all_idle / 1e9:.3f} s, of it {total / 1e9:.3f} s in {len(gaps)} gaps of "
+            f"{MIN_GAP_NS / 1e6:g} ms or more\n"
+        )
+        out.write(f"  (i) gaps, longest first ({min(len(rows), max_gaps)} of {len(rows)})\n")
+        out.write(f"  {'at_ms':>10} {'gap_ms':>9}  innermost host span at the midpoint\n")
+        for start, dur, name in sorted(rows, key=lambda r: -r[1])[:max_gaps]:
+            out.write(f"  {(start - t0) / 1e6:>10.2f} {dur / 1e6:>9.3f}  {name}\n")
+        out.write(f"  (ii) gap seconds by span, and milliseconds per {frame} ({n_frames} whole ones)\n")
+        out.write(f"  {'span':<20} {'gaps':>5} {'total_s':>9} {'share':>7} {'ms/' + frame:>15}\n")
+        for name, durs in sorted(by_name.items(), key=lambda kv: -sum(kv[1])):
+            out.write(
+                f"  {name:<20} {len(durs):>5} {sum(durs) / 1e9:>9.4f} "
+                f"{100.0 * sum(durs) / max(total, 1.0):>6.1f}% {sum(durs) / 1e6 / max(n_frames, 1):>15.2f}\n"
+            )
+        named = sum(sum(v) for k, v in by_name.items() if k != NO_SPAN)
+        out.write(
+            f"  put down to a named span: {100.0 * named / max(total, 1.0):.1f}%; "
+            f"to {NO_SPAN}: {100.0 * (total - named) / max(total, 1.0):.1f}%\n"
+        )
+    if rounds:
+        host = [ns for _, _, ns in rounds]
+        out.write(
+            f"\nrounds: mean {sum(r['dur_ns'] for r, _, _ in rounds) / len(rounds) / 1e6:.2f} ms, "
+            f"host-only mean {sum(host) / len(host) / 1e6:.2f} ms "
+            f"(min {min(host) / 1e6:.2f}, max {max(host) / 1e6:.2f})\n"
+        )
+        per_child: Dict[str, float] = {}
+        for _, inside, _ in rounds:
+            for s in inside:
+                per_child[s["name"]] = per_child.get(s["name"], 0.0) + s["dur_ns"]
+        out.write(f"  {'span in a round':<20} {'ms/round':>9}\n")
+        for name, ns in sorted(per_child.items(), key=lambda kv: -kv[1]):
+            out.write(f"  {name:<20} {ns / 1e6 / len(rounds):>9.2f}\n")
+    return 0
+
+
 def main(argv: Optional[List[str]] = None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument(
-        "paths", nargs="+", metavar="path",
+        "--xplane", metavar="FILE.xplane.pb",
+        help="report a jax.profiler profile: device idle gaps by host span",
+    )
+    ap.add_argument(
+        "paths", nargs="*", metavar="path",
         help="flight_*.json dumps and/or *.jsonl span streams; several paths "
         "are merged into one timeline joined on shared trace ids "
         "(e.g. router_spans_*.jsonl + serve_spans_*.jsonl)",
@@ -221,6 +383,10 @@ def main(argv: Optional[List[str]] = None) -> int:
     )
     ap.add_argument("--chrome", help="also export Chrome trace-event JSON here")
     args = ap.parse_args(argv)
+    if args.xplane:
+        return xplane_report(args.xplane)
+    if not args.paths:
+        ap.error("give span files, or --xplane FILE.xplane.pb")
 
     if len(args.paths) == 1:
         spans, events, header = load(args.paths[0])
