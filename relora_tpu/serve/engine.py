@@ -57,7 +57,7 @@ from relora_tpu.obs import memory as obs_memory
 from relora_tpu.obs.compile import CompileWatcher
 from relora_tpu.parallel.mesh import DATA_AXIS, FSDP_AXIS, TENSOR_AXIS, param_shardings
 from relora_tpu.serve.paging import NULL_PAGE
-from relora_tpu.serve.sampling import SamplingParams, sample
+from relora_tpu.serve.sampling import SamplingParams, sample, sample_rows
 
 PyTree = Any
 
@@ -76,6 +76,13 @@ def bucket_length(n: int, minimum: int = 16) -> int:
     if n < 1:
         raise ValueError(f"prompt length must be >= 1, got {n}")
     return max(minimum, 1 << (n - 1).bit_length())
+
+
+def _chunk_positions(start: int, rows: int, length: int) -> np.ndarray:
+    """Absolute positions ``start .. start+length-1`` for every row of a
+    prefill chunk, built on the host: a transfer, where ``jnp`` arithmetic on
+    concrete values is an XLA program per operation."""
+    return np.broadcast_to(np.arange(start, start + length, dtype=np.int32), (rows, length))
 
 
 # multi-tenant slot writes: stacked lora_a/lora_b leaves are
@@ -383,6 +390,9 @@ class InferenceEngine:
         self._decode = cw.wrap("decode", jax.jit(decode_fn, donate_argnums=(1,)))
         self._insert = cw.wrap("insert", jax.jit(insert_fn, donate_argnums=(0,)))
         self._sample = jax.jit(sample, static_argnames=("top_k",))
+        # the schedulers' sampler: per-row (uid, token index) keys are derived
+        # inside it, so a round's draws are one dispatch over numpy inputs
+        self._sample_rows = jax.jit(sample_rows, static_argnames=("top_k",))
 
         if adapter_slots:
             # slot writes donate the param tree and trace slot/scale: every
@@ -758,9 +768,7 @@ class InferenceEngine:
         self._require_paged()
         self._require_draft()
         B, T = ids.shape
-        positions = jnp.asarray(start, jnp.int32) + jnp.broadcast_to(
-            jnp.arange(T, dtype=jnp.int32)[None, :], (B, T)
-        )
+        positions = _chunk_positions(start, B, T)
         return self._prefill_chunk(
             self.draft_params,
             jnp.asarray(ids),
@@ -788,11 +796,11 @@ class InferenceEngine:
             self._row_idx(None, token.shape[0]),
         )
 
-    def _row_idx(self, adapter_idx, rows: int) -> jax.Array:
+    def _row_idx(self, adapter_idx, rows: int):
         """Normalize an optional per-row adapter index to a concrete (rows,)
         int32 array (None -> all slot 0, the identity adapter)."""
         if adapter_idx is None:
-            return jnp.zeros((rows,), jnp.int32)
+            return np.zeros((rows,), np.int32)  # a transfer, not a program
         idx = jnp.asarray(adapter_idx, jnp.int32)
         if idx.shape != (rows,):
             raise ValueError(f"adapter_idx must have shape ({rows},), got {idx.shape}")
@@ -915,9 +923,7 @@ class InferenceEngine:
         the decode loop's critical path for more than one chunk."""
         self._require_paged()
         B, T = ids.shape
-        positions = jnp.asarray(start, jnp.int32) + jnp.broadcast_to(
-            jnp.arange(T, dtype=jnp.int32)[None, :], (B, T)
-        )
+        positions = _chunk_positions(start, B, T)
         return self._prefill_chunk(
             self.params,
             jnp.asarray(ids),

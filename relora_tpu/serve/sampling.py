@@ -16,6 +16,11 @@ nucleus filter, then temperature-scaled categorical.  Everything traces under
   sample stream independent of which other requests happen to share its
   batch — fold in the request id, not the slot index.
 
+``sample_rows`` is what the schedulers call: ``sample`` over per-row keys it
+derives itself, inside the jitted program, from the scheduler's base key and
+two integer vectors — each row's request id and the index of the token being
+drawn (``request_key``).  The host hands over numpy and dispatches once.
+
 ``spec_verify_draws`` is the speculative-decoding verify sampler: one jitted
 pass over the verify window's ``(B, S, V)`` logits that produces everything
 the scheduler's host-side accept/rollback walk needs — greedy accept bits,
@@ -103,6 +108,32 @@ def sample(
     return jnp.where(temp <= 0.0, greedy, drawn)
 
 
+def request_key(base_key: jax.Array, uid, token_index) -> jax.Array:
+    """The key of one draw: a request's sample stream is keyed by (uid, token
+    index), never by the slot it landed in or what shares its batch.
+    ``fold_in`` takes its data as uint32, so any integer dtype that holds the
+    uid's low 32 bits gives the key the host would build from the Python int."""
+    return jax.random.fold_in(jax.random.fold_in(base_key, uid), token_index)
+
+
+def sample_rows(
+    logits: jax.Array,
+    base_key: jax.Array,
+    uids: jax.Array,
+    token_index: jax.Array,
+    *,
+    temperature,
+    top_k: int = 0,
+    top_p=1.0,
+) -> jax.Array:
+    """:func:`sample` with each row's :func:`request_key` built here, inside
+    the program: ``uids`` and ``token_index`` are ``(B,)`` integers the host
+    fills in numpy, so a round's draws cost one dispatch whatever ``B`` is
+    (a key per row built on the host is several tiny programs per row)."""
+    keys = jax.vmap(request_key, in_axes=(None, 0, 0))(base_key, uids, token_index)
+    return sample(logits, keys, temperature=temperature, top_k=top_k, top_p=top_p)
+
+
 #: fold_in constants separating the verify round's PRNG draws per
 #: (uid, token_index): 1 = acceptance uniform, 2 = residual/bonus sample.
 #: Each (uid, token_index, kind) is consumed at most once over a request's
@@ -167,14 +198,11 @@ def spec_verify_draws(
     probs = jax.nn.softmax(scaled, axis=-1)  # (B, S, V) the target p
 
     # per-(row, window-slot) keys: the SAME (uid, token_index) stream the
-    # plain decode path folds, built in-device to avoid B*S host fold_ins
-    def row_keys(uid, start):
-        def one(i):
-            return jax.random.fold_in(jax.random.fold_in(base_key, uid), start + i)
-
-        return jax.vmap(one)(jnp.arange(S, dtype=jnp.int32))
-
-    keys = jax.vmap(row_keys)(uids.astype(jnp.int32), start_index.astype(jnp.int32))
+    # plain decode path folds (sample_rows), built in-device like there
+    window = start_index.astype(jnp.int32)[:, None] + jnp.arange(S, dtype=jnp.int32)
+    keys = jax.vmap(jax.vmap(request_key, in_axes=(None, None, 0)), in_axes=(None, 0, 0))(
+        base_key, uids.astype(jnp.int32), window
+    )
 
     accept_keys = jax.vmap(jax.vmap(lambda k: jax.random.fold_in(k, _SPEC_ACCEPT)))(
         keys

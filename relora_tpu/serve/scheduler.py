@@ -162,11 +162,6 @@ class ContinuousBatchingScheduler:
         self._on_finish: Dict[int, FinishCallback] = {}
         self._trace_ids: Dict[int, str] = {}  # uid -> request trace id
 
-    def _request_key(self, req: Request, token_index: int) -> jax.Array:
-        # keyed by (uid, token index): a request's sample stream does not
-        # depend on which slot it landed in or what shares its batch
-        return jax.random.fold_in(jax.random.fold_in(self.key, req.uid), token_index)
-
     # -- incremental API ------------------------------------------------------
 
     def validate_request(self, req: Request) -> None:
@@ -315,8 +310,8 @@ class ContinuousBatchingScheduler:
         ):
             logits, self._cache = self.engine.decode(
                 self._cache,
-                jnp.asarray(self._tokens)[:, None],
-                jnp.asarray(self._positions)[:, None],
+                self._tokens[:, None],
+                self._positions[:, None],
                 adapter_idx=self._adapter_row,
             )
             self._step_count += 1
@@ -500,13 +495,7 @@ class ContinuousBatchingScheduler:
                 jnp.asarray(ids),
                 adapter_idx=np.array([adapter_slot], np.int32),
             )
-            first = self.engine._sample(
-                logits[:, L - 1, :],
-                self._request_key(req, 0),
-                temperature=req.temperature,
-                top_k=self.top_k,
-                top_p=req.top_p,
-            )
+            first = self._sample_first(logits[:, L - 1, :], req)
             first_id = int(np.asarray(first)[0])
         t1 = time.monotonic()
         self._observe("prefill_seconds", t1 - t0)
@@ -518,23 +507,45 @@ class ContinuousBatchingScheduler:
     def _sample_rows(self, logits, slots) -> jax.Array:
         """Every row's draw, enqueued and not waited for: the caller's read
         of it is the round's one bulk pull (the paged rounds time the two
-        apart, as ``dispatch`` and ``pull``)."""
-        temps = np.zeros(self.max_batch, np.float32)
-        top_ps = np.ones(self.max_batch, np.float32)
-        keys = []
-        for slot_idx, slot in enumerate(slots):
-            if slot is None:
-                keys.append(self.key)  # unused row; any key works
+        apart, as ``dispatch`` and ``pull``).  A row with no slot is drawn
+        greedily and its token discarded."""
+        return self._draw(
+            logits, [None if s is None else (s.request, len(s.tokens)) for s in slots]
+        )
+
+    def _sample_first(self, logits, req: Request) -> jax.Array:
+        """A request's first token from the ``(1, V)`` logits of its prompt's
+        last position: the same sampler, one row, token index 0."""
+        return self._draw(logits, [(req, 0)])
+
+    def _draw(self, logits, rows) -> jax.Array:
+        """One dispatch for ``rows`` of ``(request, token index)``.  A draw is
+        keyed by (uid, token index), so a request's sample stream does not
+        depend on which slot it landed in or what shares its batch; the host
+        only fills numpy vectors, and the keys are built inside the sampler's
+        program (``sampling.sample_rows``).  uids are uint32, the type
+        ``fold_in`` gives its data."""
+        B = len(rows)
+        uids = np.zeros(B, np.uint32)
+        token_index = np.zeros(B, np.int32)
+        temps = np.zeros(B, np.float32)
+        top_ps = np.ones(B, np.float32)
+        for i, row in enumerate(rows):
+            if row is None:
                 continue
-            temps[slot_idx] = slot.request.temperature
-            top_ps[slot_idx] = slot.request.top_p
-            keys.append(self._request_key(slot.request, len(slot.tokens)))
-        return self.engine._sample(
+            req, index = row
+            uids[i] = req.uid
+            token_index[i] = index
+            temps[i] = req.temperature
+            top_ps[i] = req.top_p
+        return self.engine._sample_rows(
             logits,
-            jnp.stack(keys),
-            temperature=jnp.asarray(temps),
+            self.key,
+            uids,
+            token_index,
+            temperature=temps,
             top_k=self.top_k,
-            top_p=jnp.asarray(top_ps),
+            top_p=top_ps,
         )
 
     def _emit_token(self, uid: int, token: int, index: int) -> None:
@@ -995,7 +1006,7 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
         ):
             logits, self._pool = self.engine.prefill_chunk(
                 jnp.asarray(ids), start, self._ensure_pool(), table,
-                adapter_idx=[slot.adapter_slot],
+                adapter_idx=np.full(1, slot.adapter_slot, np.int32),
             )
             self._count_dispatch(chunk, n_real)
             if self._spec == "model":
@@ -1009,13 +1020,7 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
                 self._count_dispatch(chunk, n_real)
             slot.prefill_progress = start + n_real
             if slot.prefill_progress >= L:
-                first = self.engine._sample(
-                    logits[:, L - 1 - start, :],
-                    self._request_key(req, 0),
-                    temperature=req.temperature,
-                    top_k=self.top_k,
-                    top_p=req.top_p,
-                )
+                first = self._sample_first(logits[:, L - 1 - start, :], req)
                 with self.tracer.span("pull"):
                     first_id = int(np.asarray(first)[0])
         self._observe("prefill_seconds", time.monotonic() - t0)
@@ -1385,7 +1390,7 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
         if not eligible:
             return {}
         k_max = int(ks.max())
-        cur = jnp.asarray(self._tokens)[:, None]
+        cur = self._tokens[:, None]
         proposals = []
         for step in range(k_max):
             live = ks > step
@@ -1570,8 +1575,8 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
                         # the plain warmed (batch, 1) decode shape
                         logits, self._pool = self.engine.decode_paged(
                             self._ensure_pool(),
-                            jnp.asarray(self._tokens)[:, None],
-                            jnp.asarray(self._positions)[:, None],
+                            self._tokens[:, None],
+                            self._positions[:, None],
                             self._tables,
                             adapter_idx=self._adapter_row,
                         )
@@ -1924,13 +1929,7 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
                     with self.tracer.span("sample"):
                         drawn = self._sample_rows(gathered, masked)
                 firsts = [
-                    engine._sample(
-                        logits[:, at, :],
-                        self._request_key(self._slots[slot_idx].request, 0),
-                        temperature=self._slots[slot_idx].request.temperature,
-                        top_k=self.top_k,
-                        top_p=self._slots[slot_idx].request.top_p,
-                    )
+                    self._sample_first(logits[:, at, :], self._slots[slot_idx].request)
                     for slot_idx, at in ending
                 ]
             with self.tracer.span("pull"):

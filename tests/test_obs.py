@@ -584,6 +584,22 @@ def test_trace_report_puts_idle_gaps_down_to_host_spans():
     assert [s["name"] for s in inside] == ["admit", "decode_step", "dispatch", "pull", "commit", "round_metrics"]
 
 
+def test_trace_report_counts_programs_per_whole_round():
+    """--xplane's (iii): executions of XLA programs that start inside a whole
+    round, per round and by name; what runs outside every round is left out."""
+    tr = _load_trace_report()
+    rounds = [(10.0, 20.0), (20.0, 30.0)]
+    programs = [
+        (5.0, "jit_sample_rows"),  # before the first whole round
+        (11.0, "jit_prefill_chunk_fn"), (12.0, "jit_decode_paged_fn"), (13.0, "jit_sample_rows"),
+        (21.0, "jit_decode_paged_fn"), (22.0, "jit_sample_rows"),
+        (30.0, "jit_decode_paged_fn"),  # a round's end is the next one's start
+    ]
+    per_round = tr.programs_per_frame(programs, rounds)
+    assert per_round == {"jit_prefill_chunk_fn": 0.5, "jit_decode_paged_fn": 1.0, "jit_sample_rows": 1.0}
+    assert sum(per_round.values()) == 2.5
+
+
 # ---------------------------------------------------------------------------
 # every Pallas kernel carries a name a device trace can be searched for
 

@@ -761,3 +761,118 @@ def test_idle_step_leaves_no_span():
         paged, max_batch=2, tracer=Tracer(service="serve", recorder=rec)
     )
     assert sched.step() == [] and rec.spans() == []
+
+
+# -- the round's programs (PERF.md §6, PR 27): the host fills numpy, the -------
+# -- round's jitted programs derive the rest ----------------------------------
+
+
+def round_sched():
+    """A warmed paged scheduler of five rows: one drain has compiled every
+    shape a round uses (chunk, decode, the sampler at five rows and at one),
+    so whatever runs afterwards is dispatch, not tracing."""
+    _, paged = make_engines(TINY_LLAMA, num_pages=41)
+    sched = PagedContinuousBatchingScheduler(
+        paged, max_batch=5, key=jax.random.PRNGKey(42), prefix_cache=False
+    )
+    rng = np.random.default_rng(5)
+    sched.run([Request(uid=90 + i, prompt=rng.integers(1, 256, 11).tolist(), max_new_tokens=3) for i in range(2)])
+    return sched, rng
+
+
+def submit_short(sched, rng, uid, **kw):
+    """A request whose prompt is one chunk and whose output outlasts the test."""
+    sched.submit(Request(uid=uid, prompt=rng.integers(1, 256, 5).tolist(), max_new_tokens=24, **kw))
+
+
+def decoding_rows(sched):
+    return sum(s is not None and s.decoding for s in sched._slots)
+
+
+def test_round_builds_no_key_and_no_stack_on_the_host(monkeypatch):
+    """From admission through first tokens to a decode round of 4 live rows,
+    greedy and sampled: an eager ``jax.random.fold_in`` or ``jnp.stack`` (a
+    key per row built on the host) fails the step.  Inside a jitted program
+    their arguments are tracers, and pass."""
+    sched, rng = round_sched()
+    real_fold_in, real_stack = jax.random.fold_in, jnp.stack
+
+    def guarded(real):
+        def fn(*args, **kwargs):
+            flat = jax.tree_util.tree_leaves((args, kwargs))
+            assert any(isinstance(a, jax.core.Tracer) for a in flat), (
+                f"eager {real.__name__} in a scheduler round"
+            )
+            return real(*args, **kwargs)
+
+        return fn
+
+    monkeypatch.setattr(jax.random, "fold_in", guarded(real_fold_in))
+    monkeypatch.setattr(jnp, "stack", guarded(real_stack))
+    with pytest.raises(AssertionError, match="eager fold_in"):
+        jax.random.fold_in(sched.key, 1)  # the guard sees what it should
+    np.testing.assert_array_equal(  # and lets a traced call through
+        np.asarray(jax.jit(lambda k: jax.random.fold_in(k, 1))(sched.key)),
+        np.asarray(real_fold_in(sched.key, 1)),
+    )
+    for uid, kw in enumerate([{}, {"temperature": 0.9, "top_p": 0.8}, {}, {"temperature": 1.2}]):
+        submit_short(sched, rng, uid + 1, **kw)
+    while decoding_rows(sched) < 4:
+        assert sched.step() == []
+    before = [len(s.tokens) for s in sched._slots if s is not None]
+    assert sched.step() == [] and decoding_rows(sched) == 4
+    assert [len(s.tokens) for s in sched._slots if s is not None] == [n + 1 for n in before]
+    assert not hasattr(sched, "_request_key")
+
+
+def xla_programs(fn, trace_dir):
+    """How many XLA programs ran while ``fn()`` did, with the names of the
+    jitted functions called: the CPU client's execute events in a
+    ``jax.profiler`` session, the count a chip's trace gives as the events of
+    its "XLA Modules" line."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(trace_dir))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    [path] = glob.glob(f"{trace_dir}/plugins/profile/*/*.xplane.pb")
+    names = [
+        e.name
+        for plane in ProfileData.from_file(path).planes
+        if plane.name == "/host:CPU"
+        for line in plane.lines
+        for e in line.events
+    ]
+    called = sorted({n for n in names if n.startswith("PjitFunction(")})
+    return sum(n.endswith("Executable::Execute") for n in names), called
+
+
+def test_programs_a_round_do_not_grow_with_the_rows(tmp_path):
+    """A decode round dispatches two programs (``decode_paged`` and the
+    sampler) with 1 row decoding and with 4, and three with a prompt's chunk
+    in the round: none per row."""
+    sched, rng = round_sched()
+    counted, called = xla_programs(lambda: jnp.ones(3) + 1, tmp_path / "probe")
+    assert counted >= 1, "the profile shows no execute event: the count below would be blind"
+
+    submit_short(sched, rng, 1, temperature=0.9)
+    sched.step()  # its chunk, its first token, its first decode
+    assert decoding_rows(sched) == 1
+    one_row, called_1 = xla_programs(sched.step, tmp_path / "rows1")
+
+    for uid in (2, 3, 4):
+        submit_short(sched, rng, uid, temperature=0.0 if uid % 2 else 1.1)
+        sched.step()
+    assert decoding_rows(sched) == 4
+    four_rows, called_4 = xla_programs(sched.step, tmp_path / "rows4")
+    assert one_row == four_rows == 2, (one_row, called_1, four_rows, called_4)
+
+    # a two-chunk prompt: its first chunk rides a round with the four decodes
+    sched.submit(Request(uid=5, prompt=rng.integers(1, 256, 13).tolist(), max_new_tokens=4))
+    with_chunk, called_c = xla_programs(sched.step, tmp_path / "chunk")
+    assert decoding_rows(sched) == 4 and sched._slots[4].prefill_progress == 8
+    assert with_chunk == 3, (with_chunk, called_c)

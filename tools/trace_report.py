@@ -20,8 +20,9 @@ device planes' operations and, from the ``/host:CPU`` plane of the same file
 and so on the same clock, the annotations ``Tracer.span`` left there.  It
 prints each device idle gap over a millisecond with the innermost host span
 open at its midpoint, the gap seconds per span name and per scheduler round,
-and each round's host-only time (its duration less the interval from its
-first ``dispatch`` or ``prefill_chunk`` start to its last ``pull`` end).
+each round's host-only time (its duration less the interval from its
+first ``dispatch`` or ``prefill_chunk`` start to its last ``pull`` end), and
+the XLA programs the device executed, by name and per round.
 
     python tools/trace_report.py ckpts/flight_sigterm_1234.json
     python tools/trace_report.py traces/train_spans.jsonl --trace a1b2c3
@@ -217,20 +218,21 @@ def phase_summary(spans: List[Dict[str, Any]], out=sys.stdout) -> None:
 
 # -- a jax.profiler profile: device idle gaps put down to host spans ------------
 
-#: the device planes' per-operation line, and the shortest gap worth a line
+#: the device planes' per-operation line, their per-program line (one event
+#: for each execution of an XLA program), and the shortest gap worth a line
 XLA_OPS_LINE = "XLA Ops"
+XLA_MODULES_LINE = "XLA Modules"
 MIN_GAP_NS = 1e6
 NO_SPAN = "(no span open)"
 
 
-def load_xplane(path: str) -> Tuple[Dict[str, List[Tuple[float, float]]], List[Dict[str, Any]]]:
-    """``({device plane: [(start_ns, duration_ns)]}, host spans)`` of one
-    ``.xplane.pb``.  A host span is an event of the ``/host:CPU`` plane that
-    carries a ``span_id`` stat, which only ``Tracer.span``'s annotations do:
-    ``{"name", "start_ns", "dur_ns", "thread", "attrs"}``."""
+def _load_profile(path: str):
+    """``(device operations, device programs, host spans)`` of one
+    ``.xplane.pb``: see :func:`load_xplane` and :func:`load_programs`."""
     from jax.profiler import ProfileData
 
     device_ops: Dict[str, List[Tuple[float, float]]] = {}
+    programs: Dict[str, List[Tuple[float, str]]] = {}
     host_spans: List[Dict[str, Any]] = []
     for plane in ProfileData.from_file(path).planes:
         if plane.name.startswith("/device:"):
@@ -239,6 +241,10 @@ def load_xplane(path: str) -> Tuple[Dict[str, List[Tuple[float, float]]], List[D
                     device_ops[plane.name] = [
                         (float(e.start_ns), float(e.duration_ns)) for e in line.events
                     ]
+                elif line.name == XLA_MODULES_LINE:
+                    programs[plane.name] = sorted(
+                        (float(e.start_ns), e.name.split("(")[0]) for e in line.events
+                    )
         elif plane.name == "/host:CPU":
             for line in plane.lines:
                 for e in line.events:
@@ -256,7 +262,35 @@ def load_xplane(path: str) -> Tuple[Dict[str, List[Tuple[float, float]]], List[D
                             }
                         )
     host_spans.sort(key=lambda s: s["start_ns"])
+    return device_ops, programs, host_spans
+
+
+def load_xplane(path: str) -> Tuple[Dict[str, List[Tuple[float, float]]], List[Dict[str, Any]]]:
+    """``({device plane: [(start_ns, duration_ns)]}, host spans)`` of one
+    ``.xplane.pb``.  A host span is an event of the ``/host:CPU`` plane that
+    carries a ``span_id`` stat, which only ``Tracer.span``'s annotations do:
+    ``{"name", "start_ns", "dur_ns", "thread", "attrs"}``."""
+    device_ops, _, host_spans = _load_profile(path)
     return device_ops, host_spans
+
+
+def load_programs(path: str) -> Dict[str, List[Tuple[float, str]]]:
+    """``{device plane: [(start_ns, program name)]}``: one entry for each
+    execution of an XLA program (an event of the "XLA Modules" line), the
+    name without the fingerprint the profiler appends in brackets."""
+    return _load_profile(path)[1]
+
+
+def programs_per_frame(
+    programs: List[Tuple[float, str]], frames: List[Tuple[float, float]]
+) -> Dict[str, float]:
+    """Executions per frame, by program name, of the programs that started
+    inside one of ``frames`` (``(start_ns, end_ns)``: the whole rounds)."""
+    counts: Dict[str, float] = {}
+    for start, name in programs:
+        if any(lo <= start < hi for lo, hi in frames):
+            counts[name] = counts.get(name, 0.0) + 1.0 / len(frames)
+    return counts
 
 
 def idle_gaps(ops: List[Tuple[float, float]], min_ns: float = MIN_GAP_NS) -> List[Tuple[float, float]]:
@@ -301,7 +335,7 @@ def round_host_only(spans: List[Dict[str, Any]]) -> List[Tuple[Dict[str, Any], L
 
 
 def xplane_report(path: str, out=sys.stdout, max_gaps: int = 40) -> int:
-    device_ops, spans = load_xplane(path)
+    device_ops, device_programs, spans = _load_profile(path)
     if not device_ops:
         out.write(f"{path}: no device plane with an {XLA_OPS_LINE!r} line\n")
         return 1
@@ -347,6 +381,20 @@ def xplane_report(path: str, out=sys.stdout, max_gaps: int = 40) -> int:
             f"  put down to a named span: {100.0 * named / max(total, 1.0):.1f}%; "
             f"to {NO_SPAN}: {100.0 * (total - named) / max(total, 1.0):.1f}%\n"
         )
+    frames = [
+        (s["start_ns"], s["start_ns"] + s["dur_ns"])
+        for s in ([r for r, _, _ in rounds] or [s for s in spans if s["name"] == frame])
+    ]
+    for plane, programs in sorted(device_programs.items()):
+        out.write(f"\n{plane}: {len(programs)} executions of XLA programs\n")
+        if frames:
+            per_frame = programs_per_frame(programs, frames)
+            out.write(
+                f"  (iii) programs started inside the {len(frames)} whole {frame} spans: "
+                f"{sum(per_frame.values()):.1f} per {frame}\n"
+            )
+            for name, n in sorted(per_frame.items(), key=lambda kv: -kv[1]):
+                out.write(f"  {name:<40} {n:>8.2f}\n")
     if rounds:
         host = [ns for _, _, ns in rounds]
         out.write(
