@@ -8,8 +8,22 @@ nucleus filter, then temperature-scaled categorical.  Everything traces under
 - ``temperature`` and ``top_p`` may be traced scalars or per-row ``(B,)``
   arrays (the continuous-batching scheduler mixes requests with different
   sampling settings in one decode step).  ``temperature <= 0`` selects greedy
-  for that row — computed as a ``where`` over both branches, so the compiled
-  step never retraces when a greedy request shares a batch with sampled ones.
+  for that row; ``top_p = 1`` means the unfiltered distribution for that row.
+- The program does only the work some row of the batch needs.  ``batch_path``
+  classifies the batch from the two vectors (``PATHS``: ``greedy`` when no
+  row samples, ``categorical`` when some row samples and no sampling row asks
+  for a nucleus, ``nucleus`` otherwise) and ``sample`` branches on it with one
+  ``lax.switch`` *inside* the compiled program: an all-greedy batch is an
+  argmax (no mask, no softmax, no random bits), and only the ``nucleus``
+  branch holds ``top_p_mask``'s two sorts.  The choice is a device-side
+  conditional, never a static argument, so one program per shape serves every
+  mix and a sampled request after greedy ones compiles nothing.  A row's
+  token does not depend on the branch its batch took: greedy rows are the
+  same argmax in all three, and the nucleus mask is applied per row, only
+  where that row's ``top_p < 1`` (``filter_logits``) — in f32
+  ``top_p_mask(x, 1.0)`` is not the identity (it drops the tail once the
+  cumulative sum rounds to 1.0), so without the per-row guard a ``top_p = 1``
+  row would draw from another support beside a nucleus row than without one.
 - ``top_k`` is a static int (it changes the ``lax.top_k`` shape); 0 disables.
 - ``key`` is either one PRNG key shared across the batch, or a stacked
   ``(B, key_size)`` batch of per-row keys.  Per-row keys make a request's
@@ -35,11 +49,17 @@ independent of batch composition and of how many drafts rode along.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 _NEG_INF = jnp.finfo(jnp.float32).min
+
+#: what a batch asks of the sampler, by ``batch_path``'s index: the branches of
+#: ``sample`` and the labels of the schedulers' ``sample_draws_total``
+PATHS = ("greedy", "categorical", "nucleus")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -80,6 +100,51 @@ def top_p_mask(logits: jax.Array, top_p: jax.Array) -> jax.Array:
     return jnp.where(keep, logits, _NEG_INF)
 
 
+def batch_path(temperature, top_p):
+    """Index into ``PATHS`` of the work a batch needs, from its per-row
+    ``(B,)`` ``temperature`` and ``top_p``: 0 when no row samples, 1 when some
+    row samples and none of the sampling rows has ``top_p < 1``, 2 otherwise.
+    numpy in, numpy out; jnp (traced or not) in, jnp out — the host's count of
+    the paths and the branch the device takes are this one predicate."""
+    sampling = temperature > 0.0
+    nucleus = sampling & (top_p < 1.0)
+    return sampling.any().astype(np.int32) + nucleus.any().astype(np.int32)
+
+
+def filter_logits(logits: jax.Array, top_k: int, top_p: jax.Array) -> jax.Array:
+    """The target's support, ``(N, V)`` rows under per-row ``(N,)`` ``top_p``:
+    top-k, then the nucleus mask on the rows with ``top_p < 1`` alone.  Both
+    ``sample`` and ``spec_verify_draws`` draw from this, so they cannot drift."""
+    filtered = top_k_mask(logits, top_k)
+    return jnp.where((top_p < 1.0)[:, None], top_p_mask(filtered, top_p), filtered)
+
+
+@functools.lru_cache(maxsize=None)
+def _branches(top_k: int):
+    """``sample``'s branches in ``PATHS``' order, over ``(logits, key, temp,
+    top_p)``.  One tuple per ``top_k`` for the life of the process, so that an
+    eager call finds the conditional it traced before."""
+
+    def greedy(logits, key, temp, top_p):
+        return jnp.argmax(logits, axis=-1)
+
+    def draw(filtered, logits, key, temp):
+        scaled = filtered / jnp.maximum(temp, 1e-6)[:, None]
+        if key.ndim > 1:  # per-row keys
+            drawn = jax.vmap(jax.random.categorical)(key, scaled)
+        else:
+            drawn = jax.random.categorical(key, scaled)
+        return jnp.where(temp <= 0.0, jnp.argmax(logits, axis=-1), drawn)
+
+    def categorical(logits, key, temp, top_p):
+        return draw(top_k_mask(logits, top_k), logits, key, temp)
+
+    def nucleus(logits, key, temp, top_p):
+        return draw(filter_logits(logits, top_k, top_p), logits, key, temp)
+
+    return greedy, categorical, nucleus
+
+
 def sample(
     logits: jax.Array,
     key: jax.Array,
@@ -91,21 +156,14 @@ def sample(
     """Sample next-token ids ``(B,)`` from logits ``(B, V)``.
 
     ``temperature``/``top_p`` broadcast per-row; rows with ``temperature <= 0``
-    take the argmax.  ``key`` is one key or a ``(B, ...)`` stack of keys.
+    take the argmax.  ``key`` is one key or a ``(B, ...)`` stack of keys.  One
+    ``lax.switch`` on ``batch_path`` runs the branch the batch needs.
     """
     logits = logits.astype(jnp.float32)
     B = logits.shape[0]
-    greedy = jnp.argmax(logits, axis=-1)
-
-    filtered = top_k_mask(logits, top_k)
-    filtered = top_p_mask(filtered, jnp.broadcast_to(jnp.asarray(top_p, jnp.float32), (B,)))
     temp = jnp.broadcast_to(jnp.asarray(temperature, jnp.float32), (B,))
-    scaled = filtered / jnp.maximum(temp, 1e-6)[:, None]
-    if key.ndim > 1:  # per-row keys
-        drawn = jax.vmap(jax.random.categorical)(key, scaled)
-    else:
-        drawn = jax.random.categorical(key, scaled)
-    return jnp.where(temp <= 0.0, greedy, drawn)
+    top_p = jnp.broadcast_to(jnp.asarray(top_p, jnp.float32), (B,))
+    return jax.lax.switch(batch_path(temp, top_p), _branches(top_k), logits, key, temp, top_p)
 
 
 def request_key(base_key: jax.Array, uid, token_index) -> jax.Array:
@@ -190,10 +248,7 @@ def spec_verify_draws(
     temp = jnp.broadcast_to(jnp.asarray(temperature, jnp.float32), (B,))
     top_p_b = jnp.broadcast_to(jnp.asarray(top_p, jnp.float32), (B,))
     flat = logits.reshape(B * S, V)
-    filtered = top_k_mask(flat, top_k)
-    filtered = top_p_mask(
-        filtered, jnp.repeat(top_p_b, S)
-    ).reshape(B, S, V)
+    filtered = filter_logits(flat, top_k, jnp.repeat(top_p_b, S)).reshape(B, S, V)
     scaled = filtered / jnp.maximum(temp, 1e-6)[:, None, None]
     probs = jax.nn.softmax(scaled, axis=-1)  # (B, S, V) the target p
 

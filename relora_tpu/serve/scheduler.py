@@ -59,7 +59,7 @@ from relora_tpu.obs.tracer import NoopTracer
 from relora_tpu.serve import wire
 from relora_tpu.serve.engine import InferenceEngine, bucket_length
 from relora_tpu.serve.paging import PageAllocator, PrefixCache, pages_needed
-from relora_tpu.serve.sampling import SamplingParams, spec_verify_draws
+from relora_tpu.serve.sampling import PATHS, SamplingParams, batch_path, spec_verify_draws
 from relora_tpu.utils import faults
 from relora_tpu.utils.logging import MetricsLogger, get_logger
 
@@ -508,23 +508,27 @@ class ContinuousBatchingScheduler:
         """Every row's draw, enqueued and not waited for: the caller's read
         of it is the round's one bulk pull (the paged rounds time the two
         apart, as ``dispatch`` and ``pull``).  A row with no slot is drawn
-        greedily and its token discarded."""
-        return self._draw(
-            logits, [None if s is None else (s.request, len(s.tokens)) for s in slots]
-        )
+        greedily and its token discarded.  The ``sample`` span is this call,
+        and carries the batch's ``path``."""
+        with self.tracer.span("sample") as span:
+            return self._draw(
+                logits, [None if s is None else (s.request, len(s.tokens)) for s in slots], span
+            )
 
     def _sample_first(self, logits, req: Request) -> jax.Array:
         """A request's first token from the ``(1, V)`` logits of its prompt's
         last position: the same sampler, one row, token index 0."""
         return self._draw(logits, [(req, 0)])
 
-    def _draw(self, logits, rows) -> jax.Array:
+    def _draw(self, logits, rows, span=None) -> jax.Array:
         """One dispatch for ``rows`` of ``(request, token index)``.  A draw is
         keyed by (uid, token index), so a request's sample stream does not
         depend on which slot it landed in or what shares its batch; the host
         only fills numpy vectors, and the keys are built inside the sampler's
         program (``sampling.sample_rows``).  uids are uint32, the type
-        ``fold_in`` gives its data."""
+        ``fold_in`` gives its data.  The program branches on what the batch
+        needs (``sampling.batch_path``); the same predicate over the same
+        vectors labels ``sample_draws_total`` and ``span``."""
         B = len(rows)
         uids = np.zeros(B, np.uint32)
         token_index = np.zeros(B, np.int32)
@@ -538,6 +542,11 @@ class ContinuousBatchingScheduler:
             token_index[i] = index
             temps[i] = req.temperature
             top_ps[i] = req.top_p
+        path = PATHS[batch_path(temps, top_ps)]  # numpy in, a numpy integer out
+        if self.obs_registry is not None:
+            self.obs_registry.inc("sample_draws_total", label=("path", path))
+        if span is not None:
+            span.set(path=path)
         return self.engine._sample_rows(
             logits,
             self.key,
@@ -1585,8 +1594,7 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
                             s if (s is not None and s.decoding) else None
                             for s in self._slots
                         ]
-                        with self.tracer.span("sample"):
-                            drawn = self._sample_rows(logits, masked)
+                        drawn = self._sample_rows(logits, masked)
                     self._step_count += 1
                 with self.tracer.span("pull"):
                     if drafts:
@@ -1926,8 +1934,7 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
                         s if i in slot_off else None
                         for i, s in enumerate(self._slots)
                     ]
-                    with self.tracer.span("sample"):
-                        drawn = self._sample_rows(gathered, masked)
+                    drawn = self._sample_rows(gathered, masked)
                 firsts = [
                     self._sample_first(logits[:, at, :], self._slots[slot_idx].request)
                     for slot_idx, at in ending

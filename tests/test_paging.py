@@ -18,6 +18,7 @@ import pytest
 from relora_tpu.config.model import ModelConfig
 from relora_tpu.models.params_util import init_params
 from relora_tpu.serve.engine import InferenceEngine, build_decode_model
+from relora_tpu.serve import sampling
 from relora_tpu.serve.paging import NULL_PAGE, PageAllocator, PrefixCache, pages_needed
 from relora_tpu.serve.scheduler import (
     ContinuousBatchingScheduler,
@@ -851,28 +852,108 @@ def xla_programs(fn, trace_dir):
     return sum(n.endswith("Executable::Execute") for n in names), called
 
 
-def test_programs_a_round_do_not_grow_with_the_rows(tmp_path):
+class BackendCompiles:
+    """Counts JAX's backend compilations while ``on`` is set (a monitoring
+    listener cannot be taken off again, so it is switched)."""
+
+    def __init__(self):
+        import jax.monitoring
+
+        self.count, self.on = 0, False
+        jax.monitoring.register_event_duration_secs_listener(self._event)
+
+    def _event(self, event, duration, **_):
+        if self.on and event == "/jax/core/compile/backend_compile_duration":
+            self.count += 1
+
+
+#: temperature and top_p of the first request and of requests 2-4, and the
+#: path the sampler's one program takes in the four-row round
+ROUND_MIXES = {
+    "mixed": ((0.9, 1.0), [(1.1, 1.0), (0.0, 1.0), (1.1, 1.0)], "categorical"),
+    "greedy": ((0.0, 1.0), [(0.0, 1.0), (0.0, 0.5), (0.0, 1.0)], "greedy"),
+    "sampled": ((0.7, 0.9), [(1.1, 1.0), (1.0, 0.8), (0.0, 1.0)], "nucleus"),
+}
+
+
+@pytest.mark.parametrize("mix", list(ROUND_MIXES))
+def test_programs_a_round_do_not_grow_with_the_rows(tmp_path, mix):
     """A decode round dispatches two programs (``decode_paged`` and the
     sampler) with 1 row decoding and with 4, and three with a prompt's chunk
-    in the round: none per row."""
+    in the round: none per row, whether the rows are greedy, sampled or mixed.
+    The scheduler was warmed by greedy requests alone: the first sampled draw
+    takes another branch of the program it has, and compiles nothing."""
+    from relora_tpu.obs.metrics import MetricsRegistry
+
+    first, others, path = ROUND_MIXES[mix]
     sched, rng = round_sched()
+    sched.obs_registry = MetricsRegistry()
     counted, called = xla_programs(lambda: jnp.ones(3) + 1, tmp_path / "probe")
     assert counted >= 1, "the profile shows no execute event: the count below would be blind"
+    compiles = BackendCompiles()
+    compiles.on = True
+    jax.jit(lambda x: x * 3 + len(mix))(jnp.ones(2))  # a program nothing has compiled yet
+    assert compiles.count >= 1, "no compile event: the count below would be blind"
+    compiles.count = 0
 
-    submit_short(sched, rng, 1, temperature=0.9)
+    submit_short(sched, rng, 1, temperature=first[0], top_p=first[1])
     sched.step()  # its chunk, its first token, its first decode
     assert decoding_rows(sched) == 1
     one_row, called_1 = xla_programs(sched.step, tmp_path / "rows1")
 
-    for uid in (2, 3, 4):
-        submit_short(sched, rng, uid, temperature=0.0 if uid % 2 else 1.1)
+    for uid, (temperature, top_p) in zip((2, 3, 4), others):
+        submit_short(sched, rng, uid, temperature=temperature, top_p=top_p)
         sched.step()
     assert decoding_rows(sched) == 4
+    draws = lambda: {p: sched.obs_registry.counter_value("sample_draws_total", ("path", p)) for p in sampling.PATHS}
+    before = draws()
     four_rows, called_4 = xla_programs(sched.step, tmp_path / "rows4")
     assert one_row == four_rows == 2, (one_row, called_1, four_rows, called_4)
+    assert {p: n - before[p] for p, n in draws().items() if n != before[p]} == {path: 1}
 
     # a two-chunk prompt: its first chunk rides a round with the four decodes
     sched.submit(Request(uid=5, prompt=rng.integers(1, 256, 13).tolist(), max_new_tokens=4))
     with_chunk, called_c = xla_programs(sched.step, tmp_path / "chunk")
     assert decoding_rows(sched) == 4 and sched._slots[4].prefill_progress == 8
     assert with_chunk == 3, (with_chunk, called_c)
+    compiles.on = False
+    assert compiles.count == 0, "a draw compiled: the sampler's path must be chosen inside its program"
+
+
+def test_sample_draws_total_counts_each_draw_under_its_batchs_path():
+    """``sample_draws_total{path=...}`` takes one count a dispatch of the
+    sampler, labelled by ``sampling.batch_path`` over the rows' temperatures
+    and top_ps (the predicate the program branches on), and the round's
+    ``sample`` span carries the same ``path``."""
+    from relora_tpu.obs.flight import FlightRecorder
+    from relora_tpu.obs.metrics import MetricsRegistry
+    from relora_tpu.obs.tracer import Tracer
+
+    sched, rng = round_sched()
+    rec = FlightRecorder()
+    sched.obs_registry, sched.tracer = MetricsRegistry(), Tracer(service="serve", recorder=rec)
+    count = lambda p: sched.obs_registry.counter_value("sample_draws_total", ("path", p))
+    uids = iter(range(10, 20))
+
+    def step(**kw):
+        """Submit a request with ``kw`` (if any), run a round: the draws it
+        counted by path, and the ``path`` of its ``sample`` spans."""
+        if kw:
+            submit_short(sched, rng, next(uids), **kw)
+        before = {p: count(p) for p in sampling.PATHS}
+        n_spans = len(rec.spans())
+        sched.step()
+        spans = [s["attrs"]["path"] for s in rec.spans()[n_spans:] if s["name"] == "sample"]
+        return {p: count(p) - before[p] for p in sampling.PATHS}, spans
+
+    # a greedy request alone: its first token and its first decode, both greedy
+    assert step(temperature=0.0) == ({"greedy": 2, "categorical": 0, "nucleus": 0}, ["greedy"])
+    assert step() == ({"greedy": 1, "categorical": 0, "nucleus": 0}, ["greedy"])
+    # a greedy row's top_p asks for nothing
+    assert step(temperature=0.0, top_p=0.5) == ({"greedy": 2, "categorical": 0, "nucleus": 0}, ["greedy"])
+    # a sampling row: its one-row first token and the round it joins are categorical
+    assert step(temperature=0.8) == ({"greedy": 0, "categorical": 2, "nucleus": 0}, ["categorical"])
+    # a nucleus row turns the round; its neighbours' tokens do not depend on it
+    assert step(temperature=0.8, top_p=0.9) == ({"greedy": 0, "categorical": 0, "nucleus": 2}, ["nucleus"])
+    assert step() == ({"greedy": 0, "categorical": 0, "nucleus": 1}, ["nucleus"])
+    assert "sample_draws_total.greedy" in sched.obs_registry.snapshot()

@@ -23,7 +23,7 @@ import pytest
 from relora_tpu.config.model import ModelConfig
 from relora_tpu.models.params_util import init_params
 from relora_tpu.serve.engine import InferenceEngine, build_decode_model
-from relora_tpu.serve.sampling import spec_verify_draws, top_k_mask, top_p_mask
+from relora_tpu.serve.sampling import sample_rows, spec_verify_draws, top_k_mask, top_p_mask
 from relora_tpu.serve.scheduler import PagedContinuousBatchingScheduler, Request
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -162,6 +162,53 @@ def test_spec_verify_draws_sampled_marginal():
     assert np.asarray(accept)[:, 0].mean() == pytest.approx(target[d], abs=0.02)
     np.testing.assert_allclose(emp, target, atol=0.02)
     assert emp[target < 1e-12].sum() == 0.0  # filtered-out tokens never appear
+
+
+@pytest.mark.parametrize("neighbour_top_p", [1.0, 0.5], ids=["alone", "beside_a_nucleus"])
+@pytest.mark.parametrize("top_p", [1.0, 0.6])
+def test_spec_verify_and_sample_share_one_filtered_support(top_p, neighbour_top_p):
+    """``spec_verify_draws`` and ``sample`` filter through one helper
+    (``sampling.filter_logits``): a ``top_p = 1`` row's target is the
+    unfiltered distribution in both, with a nucleus row in the batch or
+    without, and a ``top_p < 1`` row's is its nucleus in both.  The logits'
+    last 24 tokens lie 30 below the rest, a tail that ``top_p_mask(x, 1.0)``
+    drops in f32 and that temperature 90 draws all the time."""
+    V, tail, N, temp = 64, 24, 512, 90.0
+    row = np.array(jax.random.normal(jax.random.PRNGKey(4), (V,), jnp.float32))
+    row[V - tail :] -= 30.0
+    masked_at_one = np.asarray(top_p_mask(jnp.asarray(row)[None], jnp.ones(1)))[0]
+    assert (masked_at_one[V - tail :] < -1e30).all()  # the premise: not the identity
+    logits = jnp.broadcast_to(jnp.asarray(row), (N, 2, V))
+    top_ps = np.tile(np.array([top_p, neighbour_top_p], np.float32), N // 2)
+    mine = np.arange(N) % 2 == 0  # the rows under test; the odd ones are the neighbours
+    uids, start = jnp.arange(N, dtype=jnp.int32), jnp.full(N, 7, jnp.int32)
+    base, temps = jax.random.PRNGKey(1), jnp.full(N, temp)
+    d = V - 1  # a drafted token in the tail
+
+    def verify(k_eff):
+        accept, alt = spec_verify_draws(
+            logits, jnp.full((N, 1), d, jnp.int32), base, uids, start,
+            jnp.full(N, k_eff, jnp.int32), temperature=temps, top_p=jnp.asarray(top_ps),
+        )
+        return np.asarray(accept)[:, 0], np.asarray(alt)
+
+    # no draft: the bonus draw at slot 0 is sample's own draw, token for token
+    _, alt = verify(0)
+    plain = np.asarray(
+        sample_rows(logits[:, 0, :], base, uids, start, temperature=temps, top_p=jnp.asarray(top_ps))
+    )
+    np.testing.assert_array_equal(alt[:, 0], plain)
+    in_tail = plain[mine] >= V - tail
+    # a drafted tail token: accepted with its probability under the same target,
+    # and the residual draw stays inside the same support
+    accept, alt = verify(1)
+    if top_p < 1.0:
+        assert not in_tail.any() and not accept[mine].any()
+        assert (alt[mine] < V - tail).all()
+    else:
+        assert 0.15 < in_tail.mean() < 0.45  # 0.30: 24 of 64 tokens at exp(-30/90) of the others' weight
+        assert 1 <= accept[mine].sum() <= 12  # p(d) is about 1/64 over 256 rows
+        assert 0.15 < (alt[mine, 0] >= V - tail).mean() < 0.45
 
 
 # -- the parity oracle --------------------------------------------------------
