@@ -43,8 +43,7 @@ def main(argv=None) -> dict:
 
     if cfg.prng_impl:
         # e.g. 'rbg': hardware random bits instead of threefry — dropout
-        # bits per LoRA-wrapped linear are a measurable TPU cost (the
-        # bench_sweep --prng lever, promoted to a recipe knob)
+        # bits per LoRA-wrapped linear are a measurable TPU cost
         jax.config.update("jax_default_prng_impl", cfg.prng_impl)
 
     if int(os.environ.get("RELORA_TPU_DISTRIBUTED", "0")):

@@ -638,8 +638,8 @@ def _module_relpath(dotted: str) -> str:
 
 
 class ProjectIndex:
-    """All scanned modules plus read-only *context* modules (tools/, tests/,
-    bench.py): consumer surfaces the fleet-consistency rules must see even
+    """All scanned modules plus read-only *context* modules (tools/,
+    tests/): consumer surfaces the fleet-consistency rules must see even
     though only the package itself is being linted.  Findings may anchor in
     either set; ``# noqa`` works in both."""
 
@@ -715,6 +715,6 @@ def build_project_index(files: Dict[str, str]) -> ProjectIndex:
 
 
 #: repo-root files/dirs pulled in as read-only context for the project pass
-PROJECT_CONTEXT_GLOBS = ("tools", "tests", "bench.py")
+PROJECT_CONTEXT_GLOBS = ("tools", "tests")
 #: the project pass only makes sense when the fleet plane is in the scan set
 PROJECT_SENTINEL = "relora_tpu/obs/fleet.py"

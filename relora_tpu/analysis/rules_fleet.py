@@ -3,13 +3,12 @@
 The observability/fleet tier is stitched together by names: a serving
 replica registers ``ttft_seconds`` under the ``relora_serve`` namespace, the
 collector derives ``relora_serve_ttft_seconds_p95`` from scraped bucket
-deltas, the autoscaler and ``tools/fleet_report.py`` consume that exact
-string, and ``tools/bench_gate.py`` regresses on the derived report.  None
-of that is type-checked — a typo on either side silently yields "no data"
+deltas, and the autoscaler and ``tools/fleet_report.py`` consume that exact
+string.  None of that is type-checked — a typo on either side silently yields "no data"
 instead of an error.  These rules recover the contract statically by
 building the produced-name and consumed-name universes over the whole
 project (:class:`~relora_tpu.analysis.core.ProjectIndex`, including the
-read-only ``tools/``/``tests/``/``bench.py`` context files) and diffing
+read-only ``tools/``/``tests/`` context files) and diffing
 them.
 
 Produced series = metric registrations (``inc``/``set_gauge``/``observe``/
@@ -38,9 +37,7 @@ base must itself be produced).
 
 Deliberately out of scope: a never-consumed *series* warn (the collector's
 generic ``*_per_s`` derivation consumes every counter, so the vice-versa
-check for series is all noise), and ``bench_gate`` JSON fields (it reads
-derived BENCH reports, whose series provenance is checked at the
-collector/report layer above).
+check for series is all noise).
 """
 
 from __future__ import annotations
@@ -135,9 +132,9 @@ class _FileScan(ast.NodeVisitor):
         rel = ctx.relpath
         self.in_pkg = rel.startswith("relora_tpu/")
         #: the production universe: series/event producer AND consumer
-        #: surfaces are the package plus tools/bench — test fixtures neither
+        #: surfaces are the package plus tools/ — test fixtures neither
         #: satisfy a production consumer nor get their ad-hoc stores checked
-        self.consumer = self.in_pkg or rel.startswith("tools/") or rel == "bench.py"
+        self.consumer = self.in_pkg or rel.startswith("tools/")
         self.producer = self.consumer
         self.pp_module = "parse_prometheus" in ctx.text
         self.faults_env = "RELORA_TPU_FAULTS" in ctx.text

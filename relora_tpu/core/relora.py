@@ -82,8 +82,8 @@ class LoraSpec:
     num_slots: int = 0
 
     def __post_init__(self):
-        # validate HERE (not just TrainingConfig): bench.py/bench_sweep/
-        # plan_memory construct LoraSpec directly, and a typo'd or
+        # validate HERE (not just TrainingConfig): tools/plan_memory.py and
+        # the tests construct LoraSpec directly, and a typo'd or
         # quantize-shadowed base_dtype would otherwise run the f32 master
         # while the recorded measurement claims bf16
         if self.base_dtype not in (None, "bf16"):

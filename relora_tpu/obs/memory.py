@@ -14,8 +14,7 @@ Three views of device memory, from cheapest to most detailed:
 - :func:`live_memory_stats` / :class:`MemoryPoller` — the allocator's live
   and peak gauges.  ``device.memory_stats()`` returns None on the CPU
   backend; the normalized schema keeps ``available: False`` there so CPU and
-  TPU runs share one code path (used by ``utils/benchlib`` for the
-  ``hbm_peak_gb`` BENCH field).
+  TPU runs share one code path.
 
 Everything imports jax lazily, keeping ``relora_tpu.obs`` import-light.  The
 module is registered hot (analysis/hotpaths.py): nothing here may sync the

@@ -1,16 +1,12 @@
 """Peak-FLOPs detection and live MFU from compiled-step cost analysis.
 
-Two MFU paths share this module:
-
-- ``bench.py`` / ``utils/benchlib.py``: offline throughput benches that
-  previously hardcoded the v5e peak (``PEAK_FLOPS_V5E``).
-- the trainer's **live MFU gauge**: per-update MFU computed from the actual
-  FLOPs XLA reports for the compiled train step (``lower(...).cost_analysis()
-  ['flops']``), falling back to the 6ND approximation when cost analysis is
-  unavailable.  cost_analysis counts what the program *really* does —
-  attention scores, remat recomputation, LoRA factor matmuls — where 6ND is
-  a dense-transformer estimate, so the two can legitimately differ by tens
-  of percent under remat.
+The trainer's **live MFU gauge** reads this module: per-update MFU computed
+from the actual FLOPs XLA reports for the compiled train step
+(``lower(...).cost_analysis()['flops']``), falling back to the 6ND
+approximation when cost analysis is unavailable.  cost_analysis counts what
+the program *really* does — attention scores, remat recomputation, LoRA
+factor matmuls — where 6ND is a dense-transformer estimate, so the two can
+legitimately differ by tens of percent under remat.
 
 Peak-FLOPs resolution: on platform ``tpu`` a ``device_kind`` substring match
 against :data:`PEAK_FLOPS_BY_KIND` and nothing else — a TPU the table has

@@ -2041,8 +2041,8 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
 
     def disagg_stats(self) -> Dict[str, Any]:
         """Cumulative disaggregation counters — the /healthz ``disagg``
-        block (role + migration/prefix-fetch economics) bench.py and the
-        smoke drill read."""
+        block (role + migration/prefix-fetch economics) the smoke drill
+        reads."""
         return {
             "role": self.role,
             "pages_migrated": self._pages_migrated,
@@ -2055,7 +2055,7 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
 
     def dispatch_stats(self) -> Dict[str, Any]:
         """Cumulative dispatch-economics counters — the /healthz
-        ``dispatch`` block bench.py reads per-level deltas from."""
+        ``dispatch`` block; ``tests/test_packed.py`` reads it directly."""
         stats: Dict[str, Any] = {
             "mode": "packed" if self._packed else "sequential",
             "rounds": self._round_total,
@@ -2086,7 +2086,7 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
 
     def spec_stats(self) -> Dict[str, Any]:
         """Cumulative speculative-decoding counters — the /healthz ``spec``
-        block and the source bench.py reads effective accept rates from."""
+        block; the speculation tests read accept rates from it."""
         return {
             "mode": self._spec,
             "k": self.engine.spec_k,

@@ -59,7 +59,7 @@ def main() -> None:
         "experiment": "llama_1b r=128 seq1024 single v5e (16 GB, 90% budget): "
                       "feasible (remat, loss, micro_batch) set by frozen-base storage",
         "baseline_note": "r4 ranking found dots/dots_all infeasible above mb4/mb2 "
-                         "with an f32 master base (bench_results/r4_lever_rank.json)",
+                         "with an f32 master base",
         "findings": [
             "quantized base does NOT admit dots at mb8+: dots-remat activations, "
             "not the frozen base, are the wall there (the r4 hypothesis that freed "

@@ -16,7 +16,7 @@ Covers the PR's serving acceptance criteria:
   only the added/departed replica's tenants.
 
 The full 1→2→1 resize under live HTTP load runs as a scripts/smoke_test.sh
-stage and ``bench.py --mode autoscale``; here the execution pipeline is
+stage; here the execution pipeline is
 drilled with cheap sleeper processes so tier-1 stays fast.
 """
 

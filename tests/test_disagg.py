@@ -57,11 +57,12 @@ THRESHOLD = 12  # prompt tokens at/above this go to the prefill pool
 _ENGINES: dict = {}
 
 
-def make_engine(cfg, *, fresh=False):
+def make_engine(cfg, *, fresh=False, kv_dtype="int8"):
     """One int8-pool paged engine per config (the wire's 4x-under-bf16 claim
     rides the int8 codes + per-page scales, so the tests exercise exactly
-    that layout).  Cached so parity drains share jit caches and weights."""
-    key = cfg.family
+    that layout; ``kv_dtype="bf16"`` is the arm the byte ratio is held against).
+    Cached so parity drains share jit caches and weights."""
+    key = (cfg.family, kv_dtype)
     if not fresh and key in _ENGINES:
         return _ENGINES[key]
     model = build_decode_model(cfg, cache_size=CACHE)
@@ -74,7 +75,7 @@ def make_engine(cfg, *, fresh=False):
         page_size=PAGE,
         num_pages=3 * (CACHE // PAGE) + 1,
         chunk_size=CHUNK,
-        kv_dtype="int8",
+        kv_dtype=kv_dtype,
     )
     if not fresh:
         _ENGINES[key] = engine
@@ -319,7 +320,7 @@ def test_migrated_insert_zero_steady_state_retraces():
     assert len(completions) == 4
     assert recv._migrated_inserts == 2
     assert engine.compile_watcher.steady_state_retraces == 0
-    _ENGINES[TINY_LLAMA.family] = engine
+    _ENGINES[TINY_LLAMA.family, "int8"] = engine
 
 
 def mixed_baseline(engine):
@@ -367,6 +368,24 @@ def test_disagg_drain_token_identical(cfg):
         recv.prefix_cache.clear()
     assert donor.allocator.used_pages == 0
     assert recv.allocator.used_pages == 0
+
+
+def test_int8_pool_migrates_under_a_third_of_the_bf16_bytes():
+    """Quantized page payloads are the point of migrating an int8 pool: the
+    same long prompts through the same wire cost at most 0.3x the bytes of
+    the unquantized pool, per-page scales and framing included.
+    ``kv_dtype="bf16"`` stores pages at the engine's compute dtype, which
+    is f32 in this tiny engine: the bound is a quarter plus overhead."""
+    donors = {}
+    for kv_dtype in ("int8", "bf16"):
+        engine = make_engine(TINY_LLAMA, kv_dtype=kv_dtype)
+        completions, donor, recv = drain_disagg_pair(engine, mixed_requests())
+        assert len(completions) == 4
+        assert recv._migrated_inserts == 2
+        assert donor._migration_failures == 0
+        donors[kv_dtype] = donor
+    assert donors["int8"]._pages_migrated == donors["bf16"]._pages_migrated > 0
+    assert 0 < donors["int8"]._migration_bytes <= 0.3 * donors["bf16"]._migration_bytes
 
 
 def test_disagg_sink_rejection_fails_open_token_identical():

@@ -330,7 +330,7 @@ def test_peak_flops_table_and_env_override(monkeypatch):
     assert peak_flops(_FakeDevice("TPU v6e")) == 918e12
     assert peak_flops(_FakeDevice("TPU v4")) == 275e12
     assert peak_flops(_FakeDevice("NVIDIA H100 80GB", "gpu")) == 989e12
-    assert peak_flops(_FakeDevice("cpu", "cpu")) == PEAK_FLOPS_DEFAULT
+    assert peak_flops(_FakeDevice("cpu", "cpu")) == PEAK_FLOPS_DEFAULT == 197e12
     monkeypatch.setenv("RELORA_TPU_PEAK_FLOPS", "123e12")
     assert peak_flops(_FakeDevice("NVIDIA H100 80GB", "gpu")) == 123e12  # off-TPU override wins
     assert peak_flops(_FakeDevice("TPU v5 lite")) == 197e12  # a TPU reads the table only
@@ -343,13 +343,6 @@ def test_step_flops_from_cost_analysis_shapes():
     assert step_flops_from_cost_analysis({}) is None
     assert step_flops_from_cost_analysis({"flops": 0.0}) is None
     assert step_flops_from_cost_analysis({"bytes": 1}) is None
-
-
-def test_benchlib_peak_flops_alias():
-    # importers of the old constant keep working, and it matches the table
-    from relora_tpu.utils.benchlib import PEAK_FLOPS_V5E
-
-    assert PEAK_FLOPS_V5E == PEAK_FLOPS_DEFAULT == 197e12
 
 
 # ---------------------------------------------------------------------------

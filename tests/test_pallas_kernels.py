@@ -6,6 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from relora_tpu.ops.attention import dot_product_attention
 from relora_tpu.ops.pallas_quant_matmul import dequant_matmul
 from relora_tpu.ops.quant import dequantize_int8, quantize_int8
 
@@ -80,3 +81,52 @@ def test_dequant_matmul_validation():
         dequant_matmul(x, q, s, block_m=64, block_n=128, interpret=True)
     with pytest.raises(ValueError, match="mismatch"):
         dequant_matmul(jnp.zeros((128, 32)), q, s, interpret=True)
+
+
+@pytest.mark.parametrize("seq", [8, 200])
+def test_pallas_impl_falls_back_below_tile(seq):
+    """Sub-tile or unaligned lengths route to the XLA path instead of
+    crashing in the kernel's block verifier (e.g. the (1, 8) init trace)."""
+    q = jax.random.normal(jax.random.PRNGKey(0), (1, seq, 2, 16), jnp.float32)
+    out_p = dot_product_attention(q, q, q, causal=True, impl="pallas")
+    out_x = dot_product_attention(q, q, q, causal=True, impl="xla")
+    np.testing.assert_allclose(np.asarray(out_p), np.asarray(out_x), atol=1e-6)
+
+
+def test_pallas_block_size_selection():
+    """Block sizes must divide the sequence exactly: 768 is a 128-multiple
+    where a naive min(512, S) would be rejected by the kernel; sub-tile or
+    unaligned lengths return None (the XLA fallback)."""
+    from relora_tpu.ops.attention import flash_block_size
+
+    assert flash_block_size(1024, 1024) == 512
+    assert flash_block_size(768, 768) == 256
+    assert flash_block_size(640, 1024) == 128
+    assert flash_block_size(128, 128) == 128
+    assert flash_block_size(8, 8) is None
+    assert flash_block_size(200, 200) is None
+    assert flash_block_size(1024, 96) is None
+
+
+@pytest.mark.usefixtures("devices")
+def test_flash_partitionable_follows_the_current_mesh():
+    """GSPMD cannot partition a Mosaic kernel, so under a multi-device mesh
+    the flash arm runs per shard: a candidate only where batch and heads
+    split exactly (not the batch-1 init trace)."""
+    from relora_tpu.ops.attention import flash_partitionable
+    from relora_tpu.parallel.mesh import MeshSpec, current_mesh, make_mesh, set_current_mesh
+
+    before = current_mesh()
+    try:
+        set_current_mesh(None)
+        assert flash_partitionable(1, 8, 8)
+        set_current_mesh(make_mesh(MeshSpec(data=1, fsdp=1), devices=jax.devices()[:1]))
+        assert flash_partitionable(1, 8, 8)
+        set_current_mesh(make_mesh(MeshSpec(data=1, fsdp=4, tensor=2)))
+        assert flash_partitionable(4, 8, 8)
+        assert not flash_partitionable(1, 8, 8)  # batch does not split over fsdp
+        assert not flash_partitionable(4, 8, 1)  # kv heads do not split over tensor
+        set_current_mesh(make_mesh(MeshSpec(data=1, fsdp=2, sequence=2), devices=jax.devices()[:4]))
+        assert not flash_partitionable(4, 8, 8)  # sequence-sharded: ring/ulysses, not flash
+    finally:
+        set_current_mesh(before)

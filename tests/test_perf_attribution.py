@@ -1,11 +1,10 @@
 """Performance attribution: compile/retrace telemetry, HBM accounting, the
-MFU-gap waterfall, and the perf_report / bench_gate tools.
+MFU-gap waterfall, and the perf_report tool.
 
 The contract under test (docs/observability.md): warmup compiles are tagged
 expected and steady-state retraces are not; metrics.jsonl carries an
 ``mfu_gap`` breakdown whose shares sum to ~100%; memory plans come back in
-one normalized schema on every backend; the bench gate fails on a synthetic
-throughput regression and passes on the committed BENCH files.
+one normalized schema on every backend.
 """
 
 import json
@@ -232,8 +231,6 @@ def test_trainer_emits_mfu_gap_and_memory_plan(tmp_path, monkeypatch):
             sys.executable,
             str(REPO / "tools" / "perf_report.py"),
             str(tmp_path / "ckpt"),
-            "--bench-dir",
-            "",
             "--assert-no-retraces",
         ],
         capture_output=True,
@@ -261,7 +258,7 @@ def test_perf_report_asserts_on_synthetic_retrace(tmp_path):
     (run / "metrics.jsonl").write_text("\n".join(json.dumps(l) for l in lines) + "\n")
     proc = subprocess.run(
         [sys.executable, str(REPO / "tools" / "perf_report.py"), str(run),
-         "--bench-dir", "", "--assert-no-retraces"],
+         "--assert-no-retraces"],
         capture_output=True, text=True, timeout=60,
     )
     assert proc.returncode == 1
@@ -341,66 +338,3 @@ def test_scheduler_records_batch_fill_and_prefill_stall(tmp_path):
         assert r["serve/prefill_stall_s"] >= 0.0
         assert r["compile/steady_state_retraces"] == 0
     assert max(r["serve/batch_fill"] for r in steps) == 1.0  # 3 reqs, 2 slots
-
-
-# ---------------------------------------------------------------------------
-# bench gate
-# ---------------------------------------------------------------------------
-
-
-def _run_gate(*argv):
-    return subprocess.run(
-        [sys.executable, str(REPO / "tools" / "bench_gate.py"), "--check", *argv],
-        capture_output=True, text=True, timeout=60,
-    )
-
-
-def test_bench_gate_passes_on_committed_files():
-    proc = _run_gate()
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    assert "bench gate: OK" in proc.stdout
-
-
-def test_bench_gate_fails_on_synthetic_regression(tmp_path):
-    # a driver round as bench.py's default run prints it on a chip
-    base = {
-        "n": 5,
-        "rc": 0,
-        "parsed": {
-            "metric": "llama_1b ReLoRA r=128 seq1024 bf16 training throughput",
-            "value": 7000.0,
-            "unit": "tokens/sec/chip",
-            "vs_baseline": 0.54,
-            "detail": {"mfu": 0.27, "step_time_s": 1.17, "device": "TPU v5 lite0"},
-        },
-    }
-    (tmp_path / "BENCH_r05.json").write_text(json.dumps(base))
-    worse = dict(base, n=6)
-    worse["parsed"] = dict(base["parsed"], value=round(base["parsed"]["value"] * 0.8, 1))
-    (tmp_path / "BENCH_r06.json").write_text(json.dumps(worse))
-
-    proc = _run_gate("--dir", str(tmp_path))
-    assert proc.returncode == 1
-    assert "REGRESSION: train tok/s" in proc.stdout
-
-    proc = _run_gate("--dir", str(tmp_path), "--warn-only")
-    assert proc.returncode == 0
-    assert "REGRESSION" in proc.stdout
-
-    # a watchdog round (value 0) after the regression must not mask it,
-    # and widening the tolerance past the drop passes
-    stalled = dict(base, n=7)
-    stalled["parsed"] = dict(base["parsed"], value=0)
-    (tmp_path / "BENCH_r07.json").write_text(json.dumps(stalled))
-    assert _run_gate("--dir", str(tmp_path)).returncode == 1
-    assert _run_gate("--dir", str(tmp_path), "--tolerance", "0.3").returncode == 0
-
-
-def test_bench_gate_obs_budget_rule(tmp_path):
-    (tmp_path / "BENCH_obs.json").write_text(json.dumps({
-        "value": 2.5,
-        "detail": {"within_budget": False, "budget_pct": 1.0},
-    }))
-    proc = _run_gate("--dir", str(tmp_path))
-    assert proc.returncode == 1
-    assert "obs overhead" in proc.stdout

@@ -39,14 +39,21 @@ def topo():
 def one_chip(topo):
     """ShapeDtypeStruct factory on the first described chip; the persistent
     cache stays off around the compiles (an entry written for a described
-    chip cannot be read back without one)."""
+    chip cannot be read back without one), and so does the process's current
+    mesh: a Trainer built by an earlier test file of this worker leaves its
+    CPU mesh registered, and the flash arm would shard_map over it."""
     from jax.experimental.compilation_cache import compilation_cache
 
+    from relora_tpu.parallel.mesh import current_mesh, set_current_mesh
+
     was = jax.config.jax_enable_compilation_cache
+    mesh_was = current_mesh()
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
+    set_current_mesh(None)
     sharding = SingleDeviceSharding(topo.devices[0])
     yield lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+    set_current_mesh(mesh_was)
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
 

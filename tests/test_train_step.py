@@ -2,13 +2,15 @@
 path on an 8-virtual-device CPU mesh (the capability the reference never had
 an equivalent of — SURVEY.md §4)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
 
-from relora_tpu.config.model import ModelConfig
+from relora_tpu.config.model import MODEL_ZOO, ModelConfig
 from relora_tpu.core.optim import build_optimizer
 from relora_tpu.core.relora import LoraSpec, trainable_param_mask
 from relora_tpu.models.llama import LlamaForCausalLM
@@ -385,3 +387,84 @@ def test_chunked_loss_train_step_matches_dense():
         np.asarray(s_d.params["lm_head"]["kernel"]),
         atol=1e-6,
     )
+
+
+def _recipe_model(policy):
+    """llama_9m as the recipe trains it: bf16, scanned, LoRA dropout 0.1."""
+    return LlamaForCausalLM(
+        MODEL_ZOO["llama_9m"],
+        lora=LoraSpec(r=128, alpha=32, dropout=0.1),
+        dtype=jnp.bfloat16,
+        scan_layers=True,
+        remat=True,
+        remat_policy=policy,
+    )
+
+
+@functools.lru_cache(maxsize=None)
+def _recipe_params():
+    # the policy changes what the backward pass keeps, not the parameters
+    return init_params(_recipe_model("full"), jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+
+
+@functools.lru_cache(maxsize=None)
+def _loss_after_three_updates(policy):
+    from relora_tpu.core.partition import partition
+
+    model, params = _recipe_model(policy), _recipe_params()
+    mask = trainable_param_mask(params)
+    tx = build_optimizer(schedule=lambda s: 1e-3)
+    state = TrainState.create(params, tx.init(partition(params, mask)[0]))
+    step = jax.jit(make_train_step(model, tx, mask))
+    batch = jax.random.randint(jax.random.PRNGKey(1), (1, 2, 32), 0, model.config.vocab_size)
+    for i in range(3):
+        state, metrics = step(state, batch, jax.random.fold_in(jax.random.PRNGKey(2), i))
+    return float(metrics["loss"])
+
+
+@pytest.mark.parametrize("policy", ["dots", "dots_narrow", "dots_all"])
+def test_remat_policy_matches_full(policy):
+    """Saving matmul outputs instead of recomputing the whole layer must be
+    a pure scheduling change: the same losses as 'full'."""
+    full = _loss_after_three_updates("full")
+    assert np.isfinite(full)
+    np.testing.assert_allclose(_loss_after_three_updates(policy), full, rtol=1e-5)
+
+
+def test_remat_policy_dots_narrow_predicate():
+    """dots_narrow saves hidden-width dot outputs, recomputes wider ones and
+    batched dots — checked directly against the policy callable."""
+    from relora_tpu.models.params_util import remat_policy
+
+    pol = remat_policy("dots_narrow", max_save_width=64)
+
+    class P:
+        name = "dot_general"
+
+    class Aval:
+        def __init__(self, shape):
+            self.shape = shape
+
+    dn = lambda rhs_c, batch=(): {"dimension_numbers": (((1,), rhs_c), (batch, batch))}
+    # hidden-width projection (rhs 64x64): saved
+    assert pol(P(), Aval((8, 64)), Aval((64, 64)), **dn((0,)))
+    # wide MLP projection (rhs 64x171): recomputed
+    assert not pol(P(), Aval((8, 64)), Aval((64, 171)), **dn((0,)))
+    # down-projection back to hidden (rhs 171x64): saved
+    assert pol(P(), Aval((8, 171)), Aval((171, 64)), **dn((0,)))
+    # batched dot (attention QK^T shape): recomputed regardless of width
+    assert not pol(P(), Aval((2, 8, 16)), Aval((2, 16, 8)), **dn((1,), (0,)))
+    # non-dot primitives: never saved
+    class Q:
+        name = "exp"
+
+    assert not pol(Q(), Aval((8, 64)))
+    with pytest.raises(ValueError, match="max_save_width"):
+        remat_policy("dots_narrow")
+
+
+def test_remat_policy_unknown_raises():
+    from relora_tpu.models.params_util import remat_policy
+
+    with pytest.raises(ValueError, match="remat policy"):
+        remat_policy("bogus")

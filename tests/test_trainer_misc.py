@@ -170,3 +170,24 @@ def test_eval_every_zero_disables_midtraining_eval(tmp_path):
     assert len(finals) == 1
     # the 256-token cap bounds the final eval to cap + one microbatch
     assert finals[0]["final_eval_tokens"] <= 256 + cfg.batch_size * cfg.max_length
+
+
+def test_enable_compile_cache_env_control(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set -> JAX reads it itself and the code sets
+    no directory; unset -> the one fixed path inside the checkout."""
+    from relora_tpu.utils import logging as rlog
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", "sentinel")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/outside")
+        assert rlog.enable_compile_cache() == "/somewhere/outside"
+        assert jax.config.jax_compilation_cache_dir == "sentinel"
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        fixed = os.path.join(repo, ".jax_compile_cache")
+        assert rlog.enable_compile_cache() == fixed == rlog.COMPILE_CACHE_DIR
+        assert jax.config.jax_compilation_cache_dir == fixed
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

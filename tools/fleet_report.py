@@ -29,7 +29,6 @@ Sections:
 from __future__ import annotations
 
 import argparse
-import glob
 import json
 import os
 import sys
@@ -229,76 +228,6 @@ def timeline(store: SeriesStore, last: int, out=sys.stdout) -> None:
         )
 
 
-def bench_freshness(bench_dir: str, out=sys.stdout) -> None:
-    """Loudly flag stale/watchdog bench rounds (the same classification as
-    tools/bench_gate.py): a trajectory whose newest ``BENCH_r*.json`` rounds
-    are watchdog zeros (``parsed.value <= 0``), stale replays
-    (``detail.stale``) or off-TPU runs carries NO fresh performance signal,
-    and a fleet report that silently tabulates next to it invites reading
-    dead numbers as live ones."""
-    rounds = []  # (n, kind, mtime)
-    for path in glob.glob(os.path.join(bench_dir, "BENCH_r[0-9]*.json")):
-        try:
-            with open(path) as fh:
-                doc = json.load(fh)
-        except (OSError, json.JSONDecodeError):
-            continue
-        parsed = doc.get("parsed") or {}
-        detail = parsed.get("detail") if isinstance(parsed, dict) else None
-        detail = detail if isinstance(detail, dict) else {}
-        value = parsed.get("value") if isinstance(parsed, dict) else None
-        if detail.get("stale"):
-            kind = "stale_replay"
-        elif not isinstance(value, (int, float)) or value <= 0:
-            kind = "watchdog"
-        elif "cpu" in str(detail.get("device", "")).lower():
-            kind = "off_tpu"
-        else:
-            kind = "real"
-        try:
-            mtime = os.path.getmtime(path)
-        except OSError:
-            mtime = 0.0
-        rounds.append((int(doc.get("n", 0)), kind, mtime))
-    if not rounds:
-        return
-    rounds.sort()
-    newest_n, newest_kind, _ = rounds[-1]
-    real = [r for r in rounds if r[1] == "real"]
-    if newest_kind == "real":
-        out.write(
-            f"bench trajectory: round r{newest_n} is a real on-TPU "
-            f"measurement ({len(real)}/{len(rounds)} rounds real)\n\n"
-        )
-        return
-    counts: Dict[str, int] = {}
-    for _, kind, _ in rounds:
-        counts[kind] = counts.get(kind, 0) + 1
-    breakdown = ", ".join(f"{v} {k}" for k, v in sorted(counts.items()))
-    out.write("!" * 72 + "\n")
-    out.write(
-        f"!!! BENCH STALENESS: newest round r{newest_n} is {newest_kind.upper()}, "
-        f"not a real on-TPU measurement\n"
-    )
-    out.write(f"!!! rounds in {bench_dir}: {breakdown}\n")
-    if real:
-        real_n, _, real_mtime = real[-1]
-        age = ""
-        if real_mtime > 0:
-            age = f", recorded {(time.time() - real_mtime) / 86400.0:.1f} days ago"
-        out.write(
-            f"!!! newest REAL on-TPU round: r{real_n} "
-            f"({newest_n - real_n} rounds behind{age})\n"
-        )
-    else:
-        out.write("!!! NO real on-TPU round exists in this trajectory\n")
-    out.write(
-        "!!! perf numbers below reflect serving telemetry only; do not read\n"
-        "!!! the bench trajectory as fresh (tools/bench_gate.py --check)\n"
-    )
-    out.write("!" * 72 + "\n\n")
-
-
 def main(argv: Optional[List[str]] = None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("path", help="fleet_series.jsonl written by the FleetCollector")
@@ -318,12 +247,6 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap.add_argument(
         "--json", action="store_true",
         help="emit machine-readable JSON instead of the text report",
-    )
-    ap.add_argument(
-        "--bench-dir",
-        default=str(Path(__file__).resolve().parents[1]),
-        help="where BENCH_r*.json rounds live (default: repo root); stale or "
-        "watchdog trajectories get a loud banner ('' disables the check)",
     )
     args = ap.parse_args(argv)
 
@@ -364,8 +287,6 @@ def main(argv: Optional[List[str]] = None) -> int:
         return 0
 
     print(f"fleet report: {args.path}  ({n} records, now={now:.2f})\n")
-    if args.bench_dir:
-        bench_freshness(args.bench_dir)
     fleet_health(store, now)
     replica_comparison(store, now, args.window_s)
     slo_status(store, engine, now)

@@ -1,7 +1,7 @@
 #!/usr/bin/env python
-"""One performance-attribution report: metrics.jsonl + traces + BENCH files.
+"""One performance-attribution report: metrics.jsonl + traces.
 
-Joins the three telemetry streams the obs layer produces into the answer to
+Joins the telemetry streams the obs layer produces into the answer to
 "where is the MFU going":
 
 1. **MFU-gap waterfall** — the trainer's per-flush ``mfu_gap/*`` records:
@@ -20,7 +20,6 @@ Joins the three telemetry streams the obs layer produces into the answer to
    round, tokens per dispatch, packed-token utilization).
 5. **Span phases** — p50/p95 per phase from a ``train_spans.jsonl`` stream
    (``--traces``, or auto-detected next to the run dir).
-6. **BENCH trajectory** — committed ``BENCH_*.json`` context (``--bench-dir``).
 
     python tools/perf_report.py ckpts/run
     python tools/perf_report.py ckpts/run --traces traces/train_spans.jsonl
@@ -30,7 +29,6 @@ Joins the three telemetry streams the obs layer produces into the answer to
 from __future__ import annotations
 
 import argparse
-import glob
 import json
 import os
 import sys
@@ -271,47 +269,10 @@ def print_phases(trace_path: str, out) -> None:
         )
 
 
-def print_bench(bench_dir: str, out) -> None:
-    rounds = []
-    for path in sorted(glob.glob(os.path.join(bench_dir, "BENCH_r[0-9]*.json"))):
-        try:
-            with open(path) as fh:
-                doc = json.load(fh)
-        except (OSError, json.JSONDecodeError):
-            continue
-        value = (doc.get("parsed") or {}).get("value")
-        if value:
-            rounds.append((doc.get("n"), value, (doc.get("parsed") or {}).get("detail") or {}))
-    if not rounds:
-        return
-    out.write("\nBENCH trajectory (train tok/s)\n")
-    for n, value, detail in rounds:
-        mfu = detail.get("mfu")
-        out.write(
-            f"  round {n}: {value:,.1f} tok/s"
-            + (f"  mfu {mfu:.4f}" if isinstance(mfu, (int, float)) else "")
-            + ("  [stale]" if detail.get("stale") else "")
-            + "\n"
-        )
-    obs_path = os.path.join(bench_dir, "BENCH_obs.json")
-    if os.path.exists(obs_path):
-        with open(obs_path) as fh:
-            obs = json.load(fh)
-        out.write(
-            f"  obs overhead: {obs.get('value')}% of step time "
-            f"(budget {((obs.get('detail') or {}).get('budget_pct'))}%)\n"
-        )
-
-
 def main(argv: Optional[List[str]] = None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("run_dir", help="run dir containing metrics.jsonl (or the file itself)")
     ap.add_argument("--traces", help="train_spans.jsonl stream (default: autodetect)")
-    ap.add_argument(
-        "--bench-dir",
-        default=str(Path(__file__).resolve().parents[1]),
-        help="directory with BENCH_*.json (default: repo root); '' disables",
-    )
     ap.add_argument(
         "--assert-no-retraces",
         action="store_true",
@@ -341,9 +302,6 @@ def main(argv: Optional[List[str]] = None) -> int:
         trace_path = candidate if os.path.exists(candidate) else None
     if trace_path and os.path.exists(trace_path):
         print_phases(trace_path, out)
-
-    if args.bench_dir and os.path.isdir(args.bench_dir):
-        print_bench(args.bench_dir, out)
 
     if args.assert_no_retraces and n_retraces > 0:
         out.write(f"\nFAIL: {n_retraces} steady-state retraces (expected 0)\n")
