@@ -290,91 +290,221 @@ def paged_cached_attention(
 # ---------------------------------------------------------------------------
 
 
+#: tokens of one row that a step of the paged-decode kernel attends: the
+#: step's pages are one (tokens, n_kv, H) operand of the score product
+_DECODE_STEP_TOKENS = 128
+#: VMEM the K and V buffers of a step may hold, two buffers each
+_DECODE_STEP_VMEM_BYTES = 4 * 1024 * 1024
+
+
+def decode_pages_per_step(
+    page_size: int, n_kv: int, head_dim: int, itemsize: int, table_width: int
+) -> int:
+    """Table entries ``P`` one step of :func:`paged_decode_attention` walks:
+    as many pages as make ``_DECODE_STEP_TOKENS`` tokens, fewer where K and V
+    buffers of that many pages, two each and padded to the dtype's
+    ``(sublane, 128)`` tile, would pass ``_DECODE_STEP_VMEM_BYTES``, and never
+    more than the table has."""
+    sublanes = 8 * (4 // itemsize)
+    page_bytes = (
+        page_size * -(-n_kv // sublanes) * sublanes * -(-head_dim // 128) * 128 * itemsize
+    )
+    by_vmem = _DECODE_STEP_VMEM_BYTES // (4 * page_bytes)
+    return max(1, min(table_width, _DECODE_STEP_TOKENS // page_size, by_vmem))
+
+
+def _as_column(row):
+    """``(1, n)`` -> ``(n, 1)`` through the diagonal of its sublane
+    broadcast: a select and a lane reduction, where Mosaic may refuse the
+    transpose of so small a tile."""
+    n = row.shape[1]
+    diag = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0) == jax.lax.broadcasted_iota(
+        jnp.int32, (n, n), 1
+    )
+    return jnp.sum(jnp.where(diag, row, 0.0), axis=1, keepdims=True)
+
+
+def _online_softmax(s, visible, m_prev, l_prev, axis):
+    """One flash-style update over the tokens along ``axis``: returns the
+    unnormalized probabilities, the rescale ``alpha`` of what was
+    accumulated before, and the new running max and denominator (each of
+    size 1 along ``axis``)."""
+    s = jnp.where(visible, s, -1e30)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=axis, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    # mask p itself, not just the logits: if every token of a step is
+    # hidden from a query, exp(-1e30 - m) could still round to nonzero garbage
+    p = jnp.where(visible, jnp.exp(s - m_new), 0.0)
+    return p, alpha, m_new, l_prev * alpha + jnp.sum(p, axis=axis, keepdims=True)
+
+
+def _page_scale_columns(scale_ref, step, pages: int, page_size: int):
+    """The step's ``(page, kv_head)`` scales as ``(T, n_kv, 1)``: each page's
+    ``(1, n_kv)`` row turned to a column and repeated over its tokens."""
+    cols = [
+        _as_column(scale_ref[0, pl.ds(step * pages + e, 1), :]) for e in range(pages)
+    ]
+    return jnp.concatenate(
+        [jnp.broadcast_to(c[None], (page_size, *c.shape)) for c in cols], axis=0
+    )
+
+
 def _paged_decode_kernel(
     # scalar-prefetch operands (SMEM)
     bt_ref,  # (B, W) int32 block tables
     pos_ref,  # (B, S) int32 per-query-token positions
-    # VMEM inputs
-    q_ref,  # (1, N*S, H) this row's queries, head-major (row = head*S + s)
-    k_ref,  # (1, ps, n_kv, H) pool page selected by bt[b, w]
-    v_ref,  # (1, ps, n_kv, H)
-    ks_ref,  # (1, 1, n_kv) f32 page scales (ones when unquantized)
-    vs_ref,  # (1, 1, n_kv)
-    # VMEM output
-    o_ref,  # (1, N*S, H)
-    # VMEM scratch, carried across the W grid steps of one row
-    acc_ref,  # (N*S, H) f32 running numerator
-    m_ref,  # (N*S, 1) f32 running max
-    l_ref,  # (N*S, 1) f32 running denominator
-    *,
+    last_ref,  # (B,) int32 last table entry any query of the row can see
+    # inputs: the row's queries in VMEM, the pools left in HBM
+    q_ref,  # (1, N*S, H) head-major (row = head*S + s)
+    k_hbm,  # (num_pages, ps, n_kv, H)
+    v_hbm,
+    *refs,  # if quantized: the scales of the row's table entries, k then v,
+    #         (1, steps*P, n_kv) f32 in VMEM; then the output (1, N*S, H) and
+    #         the scratch
     sm_scale: float,
     page_size: int,
     n_kv: int,
     q_len: int,
+    pages: int,
     quantized: bool,
 ):
+    P, S, ps = pages, q_len, page_size
+    ks_ref = vs_ref = None
+    if quantized:
+        ks_ref, vs_ref, *refs = refs
+    o_ref, k_buf, v_buf, sem, slot_ref, acc_ref, m_ref, l_ref = refs
+    # k_buf, v_buf: (2, P*ps, n_kv, H), a step's pages, two buffers;
+    # sem: DMA (2, 2) by (k|v, buffer); slot_ref: SMEM (1,), the buffer that
+    # holds this row's first step; acc (N*S, H), m, l (N*S, 1): f32 state
     b = pl.program_id(0)
-    w = pl.program_id(1)
-    n_pages = pl.num_programs(1)
-    S = q_len
-    g = q_ref.shape[1] // (n_kv * S)
-    gS = g * S
+    n_rows = pl.num_programs(0)
+    T = P * ps
+    gS = q_ref.shape[1] // n_kv
 
-    @pl.when(w == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, -1e30)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def step_copies(row, step, slot, *, wait=False):
+        """Start, or wait for, the copies of the pages ``step`` of ``row``
+        walks, into buffer ``slot``.  A table entry past the row's last live
+        one is not copied: its tokens are hidden by their index, whatever
+        the buffer still holds there."""
+        for e in range(P):
+            entry = step * P + e
 
-    # absolute token index of each slot in this page; (1, ps) because TPU
-    # requires >=2D iota
-    idx = w * page_size + jax.lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
-    # per-query-token visibility: S is a small static int, so S scalar SMEM
-    # reads build the (S, 1) position column; broadcast against idx and tile
-    # over the g heads of a group to match the head-major row order
-    poss = jnp.concatenate(
-        [pos_ref[b, s].reshape(1, 1) for s in range(S)], axis=0
-    )  # (S, 1)
-    visible_s = idx <= poss  # (S, ps)
-    visible = jnp.broadcast_to(visible_s[None], (g, S, page_size)).reshape(
-        gS, page_size
-    )
+            @pl.when(entry <= last_ref[row])
+            def _():
+                page = bt_ref[row, entry]
+                for side, (hbm, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
+                    copy = pltpu.make_async_copy(
+                        hbm.at[page], buf.at[slot, pl.ds(e * ps, ps)], sem.at[side, slot]
+                    )
+                    copy.wait() if wait else copy.start()
 
-    for j in range(n_kv):
-        kj = k_ref[0, :, j, :].astype(jnp.float32)  # (ps, H)
-        vj = v_ref[0, :, j, :].astype(jnp.float32)
+    @pl.when(b == 0)
+    def _first_row():
+        # what a buffer holds where no page was copied is multiplied by
+        # p = 0: it has to be finite
+        k_buf[...] = jnp.zeros_like(k_buf)
+        v_buf[...] = jnp.zeros_like(v_buf)
+        slot_ref[0] = 0
+        step_copies(0, 0, 0)
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, -1e30)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+    def attend_every_head_at_once(step, slot):
+        # g*S == 1: a score is the dot of a head's one query with each key of
+        # that head, so on the (T, n_kv, H) buffer the scores of every head
+        # are one broadcast multiply and one reduction over H, and p.V one
+        # broadcast multiply and one reduction over tokens.  Scores keep the
+        # reduced lane as (T, n_kv, 1), the layout p needs to scale V rows.
+        tok = step * T + jax.lax.broadcasted_iota(jnp.int32, (T, 1, 1), 0)
+        visible = tok <= pos_ref[b, 0]
+        q = q_ref[0].astype(jnp.float32) * sm_scale  # (n_kv, H)
+        k = k_buf[slot].astype(jnp.float32)
+        v = v_buf[slot].astype(jnp.float32)
+        s = jnp.sum(k * q[None], axis=-1, keepdims=True)  # (T, n_kv, 1)
         if quantized:
-            kj = kj * ks_ref[0, 0, j]
-            vj = vj * vs_ref[0, 0, j]
-        qj = q_ref[0, j * gS : (j + 1) * gS, :].astype(jnp.float32)  # (gS, H)
-        s = (
-            jax.lax.dot_general(
-                qj, kj, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            # codes . q times the page's scale is (codes * scale) . q
+            s = s * _page_scale_columns(ks_ref, step, P, ps)
+        p, alpha, m_new, l_new = _online_softmax(
+            s, visible, m_ref[...][None], l_ref[...][None], axis=0
+        )
+        m_ref[...] = m_new[0]
+        l_ref[...] = l_new[0]
+        if quantized:
+            p = p * _page_scale_columns(vs_ref, step, P, ps)
+        acc_ref[...] = acc_ref[...] * alpha[0] + jnp.sum(p * v, axis=0)
+
+    def attend_head_by_head(step, slot):
+        # g*S > 1 (grouped queries, the verify window): per kv head a
+        # (g*S, H) x (H, T) product over the step's T tokens
+        g = gS // S
+        tok = step * T + jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
+        # row i*S + s of a head's block is query token s: g*S scalar SMEM
+        # reads build the position column
+        poss = jnp.concatenate(
+            [pos_ref[b, s].reshape(1, 1) for _ in range(g) for s in range(S)], axis=0
+        )
+        visible = tok <= poss  # (gS, T)
+        for j in range(n_kv):
+            rows = slice(j * gS, (j + 1) * gS)
+
+            def head_tokens(buf, scale_ref):
+                if scale_ref is None:
+                    return buf[slot, :, j, :].astype(jnp.float32)  # (T, H)
+                return jnp.concatenate(
+                    [
+                        buf[slot, e * ps : (e + 1) * ps, j, :].astype(jnp.float32)
+                        * scale_ref[0, step * P + e, j]
+                        for e in range(P)
+                    ],
+                    axis=0,
+                )
+
+            kj = head_tokens(k_buf, ks_ref)
+            vj = head_tokens(v_buf, vs_ref)
+            qj = q_ref[0, rows, :].astype(jnp.float32)
+            s = (
+                jax.lax.dot_general(
+                    qj, kj, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+                )
+                * sm_scale
+            )  # (gS, T)
+            p, alpha, m_new, l_new = _online_softmax(
+                s, visible, m_ref[rows, :], l_ref[rows, :], axis=1
             )
-            * sm_scale
-        )  # (gS, ps)
-        s = jnp.where(visible, s, -1e30)
+            m_ref[rows, :] = m_new
+            l_ref[rows, :] = l_new
+            acc_ref[rows, :] = acc_ref[rows, :] * alpha + jax.lax.dot_general(
+                p, vj, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            )
 
-        m_prev = m_ref[j * gS : (j + 1) * gS, :]  # (gS, 1)
-        l_prev = l_ref[j * gS : (j + 1) * gS, :]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)  # (gS, 1)
-        # mask p itself, not just the logits: if every slot of a page is
-        # hidden, exp(-1e30 - m) could still round to nonzero garbage
-        p = jnp.where(visible, jnp.exp(s - m_new), 0.0)  # (gS, ps)
-        m_ref[j * gS : (j + 1) * gS, :] = m_new
-        l_ref[j * gS : (j + 1) * gS, :] = l_prev * alpha + jnp.sum(
-            p, axis=1, keepdims=True
-        )
-        acc_ref[j * gS : (j + 1) * gS, :] = acc_ref[
-            j * gS : (j + 1) * gS, :
-        ] * alpha + jax.lax.dot_general(
-            p, vj, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+    attend = attend_every_head_at_once if gS == 1 else attend_head_by_head
+    # only the steps that hold a page some query of the row can see: a step
+    # past them would contribute alpha = 1, p = 0, so it is neither fetched
+    # nor run
+    n_steps = last_ref[b] // P + 1
+    slot0 = slot_ref[0]
 
-    @pl.when(w == n_pages - 1)
-    def _emit():
-        o_ref[0, :, :] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+    def walk(step, _):
+        slot = (slot0 + step) % 2
+
+        # fetch ahead into the other buffer: this row's next step, or the
+        # next row's first
+        @pl.when(step + 1 < n_steps)
+        def _():
+            step_copies(b, step + 1, 1 - slot)
+
+        @pl.when((step + 1 == n_steps) & (b + 1 < n_rows))
+        def _():
+            step_copies(b + 1, 0, 1 - slot)
+
+        step_copies(b, step, slot, wait=True)
+        attend(step, slot)
+
+    jax.lax.fori_loop(0, n_steps, walk, None)
+    slot_ref[0] = (slot0 + n_steps) % 2
+    o_ref[0, :, :] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
 def paged_decode_attention(
@@ -391,19 +521,36 @@ def paged_decode_attention(
 ) -> jax.Array:
     """Fused small-S decode/verify attention straight out of the page pool.
 
-    One Pallas launch over grid ``(B, W)``: the block table rides in as a
-    scalar-prefetch operand, so each grid step's BlockSpec index map picks
-    the pool page ``bt[b, w]`` and the DMA engine streams exactly the pages
-    each row owns — the gathered ``(B, W*ps, n_kv, H)`` cache copy of
-    :func:`paged_cached_attention` never exists in HBM.  Scores stay in
-    registers/VMEM as flash-style online-softmax state (running max ``m``,
-    denominator ``l``, numerator ``acc`` carried across the W steps of a
-    row), so the ``(B, N, S, S_kv)`` score matrix never exists either.
+    One Pallas launch over grid ``(B,)``, a row a grid step.  The pools stay
+    in HBM; the block table, the positions and each row's last live table
+    entry ride in as scalar-prefetch operands, and the kernel walks only the
+    table entries some query of the row can see — ``last // P + 1`` steps of
+    ``P`` entries (:func:`decode_pages_per_step`: ``P * page_size`` is 128
+    tokens unless the buffers would not fit), not the table's whole width.
+    A step's pages are copied by ``P`` async copies for K and ``P`` for V
+    into one of two VMEM buffers while the step before is attended, and a
+    row's last step fetches the next row's first, so the copies hide behind
+    the arithmetic across rows too.  Neither the gathered
+    ``(B, W*ps, n_kv, H)`` cache copy of :func:`paged_cached_attention` nor
+    the ``(B, N, S, S_kv)`` score matrix ever exists in HBM, and a table
+    entry past a row's position costs nothing: it is neither copied nor
+    attended (it would contribute ``alpha = 1``, ``p = 0``).  What a buffer
+    holds where a step copied no page is hidden by the ``j <= position``
+    mask like the tail of the row's own last page.
 
-    With ``k_scale``/``v_scale`` the pool is int8 and each page is
-    dequantized in VMEM by its own ``(page, kv_head)`` scale after the DMA —
-    HBM traffic per cached token drops to 1 byte per element plus the
-    per-page scales.
+    Scores stay in VMEM as flash-style online-softmax state (running max
+    ``m``, denominator ``l``, numerator ``acc``, f32, carried across a row's
+    steps).  With ``g * S == 1`` (multi-head decode) every kv head is
+    attended in one operation on the step's ``(tokens, n_kv, H)`` buffer: a
+    broadcast multiply by the row's ``(n_kv, H)`` queries and a reduction
+    over ``H``, then ``p * V`` reduced over tokens, with ``m`` and ``l`` as
+    ``(n_kv, 1)`` columns.  With ``g * S > 1`` (grouped queries, the verify
+    window) each kv head takes a ``(g*S, H) x (H, tokens)`` product.
+
+    With ``k_scale``/``v_scale`` the pool is int8: the codes are copied (1
+    byte per element from HBM), the scales of the row's own table entries are
+    gathered outside the kernel (``(B, W, n_kv)`` f32) and ride in VMEM, and
+    a page is dequantized by its ``(page, kv_head)`` scale after the copy.
 
     ``q`` is ``(B, S, N, H)`` for a *small* static S — 1 for plain decode,
     ``K+1`` for the speculative-decoding verify window (the dispatcher caps
@@ -411,9 +558,7 @@ def paged_decode_attention(
     Queries lay out head-major ``(B, N*S, H)`` inside the kernel so each
     kv-head group stays one contiguous row block, and per-token positions
     ride in as SMEM scalars to build the ``j <= position`` visibility mask
-    per query row.  Each query row's online-softmax state is independent
-    and walks the W pages in the same order regardless of S, so S=1
-    reproduces the original decode kernel exactly.
+    per query row.
 
     ``positions`` is ``(B,)``/``(B, 1)`` (broadcast — every query at the
     same position) or ``(B, S)`` per-token.  Returns ``(B, S, N, H)`` in
@@ -423,7 +568,7 @@ def paged_decode_attention(
     order.
     """
     B, T, N, H = q.shape
-    num_pages, page_size, n_kv, _ = pool_k.shape
+    _, page_size, n_kv, _ = pool_k.shape
     W = block_tables.shape[1]
     if N % n_kv:
         raise ValueError(f"num_heads={N} must divide by kv_heads={n_kv}")
@@ -432,16 +577,6 @@ def paged_decode_attention(
     quantized = k_scale is not None
     if quantized != (v_scale is not None):
         raise ValueError("k_scale and v_scale must be given together")
-    # scales ride as (num_pages, 1, n_kv): the TPU lowering wants a block's
-    # last two dims to equal the array's (or tile by 8x128), which a
-    # (1, n_kv) block of a (num_pages, n_kv) array does not
-    if quantized:
-        ks = k_scale.astype(jnp.float32).reshape(num_pages, 1, n_kv)
-        vs = v_scale.astype(jnp.float32).reshape(num_pages, 1, n_kv)
-    else:
-        # constant-folded away; keeps one kernel signature for both flavors
-        ks = jnp.ones((num_pages, 1, n_kv), jnp.float32)
-        vs = ks
 
     # head-major rows: (B, S, N, H) -> (B, N, S, H) -> (B, N*S, H); row
     # n*S + s holds query token s of head n, so kv-head j's group block is
@@ -452,6 +587,25 @@ def paged_decode_attention(
         positions.size == B
     ) else positions.reshape(B, T)
     pos = pos.astype(jnp.int32)
+    last = jnp.clip(jnp.max(pos, axis=1) // page_size, 0, W - 1)
+
+    P = decode_pages_per_step(page_size, n_kv, H, jnp.dtype(pool_k.dtype).itemsize, W)
+
+    def row_block(rows, cols):
+        return pl.BlockSpec((1, rows, cols), lambda b, bt, pos, last: (b, 0, 0))
+
+    in_specs = [row_block(N * T, H)] + [pl.BlockSpec(memory_space=pl.ANY)] * 2
+    operands = [q3, pool_k, pool_v]
+    if quantized:
+        # a (1, n_kv) slab of a (num_pages, n_kv) array is no tile a copy can
+        # address, and a row walks at most W pages: their scales are gathered
+        # here, (B, W, n_kv) padded to whole steps, and ride in VMEM by row
+        whole_steps = -(-W // P) * P
+        for s in (k_scale, v_scale):
+            of_row = jnp.take(s.astype(jnp.float32), bt, axis=0)
+            operands.append(jnp.pad(of_row, ((0, 0), (0, whole_steps - W), (0, 0))))
+            in_specs.append(row_block(whole_steps, n_kv))
+    page_buf = pltpu.VMEM((2, P * page_size, n_kv, H), pool_k.dtype)
 
     kernel = functools.partial(
         _paged_decode_kernel,
@@ -459,24 +613,19 @@ def paged_decode_attention(
         page_size=page_size,
         n_kv=n_kv,
         q_len=T,
+        pages=P,
         quantized=quantized,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, W),
-        in_specs=[
-            pl.BlockSpec((1, N * T, H), lambda b, w, bt, pos: (b, 0, 0)),
-            pl.BlockSpec(
-                (1, page_size, n_kv, H), lambda b, w, bt, pos: (bt[b, w], 0, 0, 0)
-            ),
-            pl.BlockSpec(
-                (1, page_size, n_kv, H), lambda b, w, bt, pos: (bt[b, w], 0, 0, 0)
-            ),
-            pl.BlockSpec((1, 1, n_kv), lambda b, w, bt, pos: (bt[b, w], 0, 0)),
-            pl.BlockSpec((1, 1, n_kv), lambda b, w, bt, pos: (bt[b, w], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, N * T, H), lambda b, w, bt, pos: (b, 0, 0)),
+        num_scalar_prefetch=3,
+        grid=(B,),
+        in_specs=in_specs,
+        out_specs=row_block(N * T, H),
         scratch_shapes=[
+            page_buf,
+            page_buf,
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
             pltpu.VMEM((N * T, H), jnp.float32),
             pltpu.VMEM((N * T, 1), jnp.float32),
             pltpu.VMEM((N * T, 1), jnp.float32),
@@ -488,7 +637,7 @@ def paged_decode_attention(
         out_shape=jax.ShapeDtypeStruct((B, N * T, H), q.dtype),
         interpret=interpret,
         name="paged_decode_attention",
-    )(bt, pos, q3, pool_k, pool_v, ks, vs)
+    )(bt, pos, last, *operands)
     return out.reshape(B, N, T, H).transpose(0, 2, 1, 3)
 
 

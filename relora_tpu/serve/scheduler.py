@@ -858,6 +858,9 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
         # serve/kv_cache_bytes and serve/kv_bytes_per_token gauges
         self._kv_cache_bytes = engine.pool_bytes()
         self._kv_bytes_per_token = engine.kv_bytes_per_token()
+        # table entries the last decode had to walk (the decode_step span's
+        # live_pages; the serve/decode_live_page_share gauge)
+        self._decode_live_pages = 0
         # disaggregated serving (docs/serving.md): a prefill-role scheduler
         # hands each finished prompt's page run to ``migration_sink`` (set by
         # the server; runs on the model thread, must not block) and parks the
@@ -1573,7 +1576,7 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
                 step=self._step_count,
                 active_slots=n_decoding,
                 spec_drafted=n_drafted,
-                kv_bytes=self._decode_kv_bytes(),
+                **self._decode_reads(),
             ):
                 with self.tracer.span("dispatch"):
                     if drafts:
@@ -1629,11 +1632,19 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
             if not any(s is not None and not s.migrating for s in self._slots):
                 sp.drop()  # nothing to run: the round will leave no span either
 
-    def _decode_kv_bytes(self) -> float:
-        """The K/V bytes a decode over the live tokens must read, whatever
-        kernel reads them: every decoding row attends positions ``0..pos``."""
-        live = sum(s.pos + 1 for s in self._slots if s is not None and s.decoding)
-        return live * self._kv_bytes_per_token
+    def _decode_reads(self) -> Dict[str, float]:
+        """What a decode over the live tokens must read, whatever kernel
+        reads it (the ``decode_step`` span's attributes): every decoding row
+        attends positions ``0..pos``, which are ``kv_bytes`` bytes of K/V on
+        ``live_pages`` entries of the block tables; the other entries of the
+        ``max_batch x table width`` tables are what a kernel may skip."""
+        positions = [s.pos for s in self._slots if s is not None and s.decoding]
+        ps = self.engine.page_size
+        self._decode_live_pages = sum(p // ps + 1 for p in positions)
+        return {
+            "kv_bytes": sum(p + 1 for p in positions) * self._kv_bytes_per_token,
+            "live_pages": self._decode_live_pages,
+        }
 
     def _commit_tokens(
         self, next_tokens: List[int], rows: List[int], finished: List[Completion]
@@ -1696,6 +1707,7 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
         dispatches_per_round = self._dispatch_total / max(self._round_total, 1)
         tokens_per_dispatch = self._dispatch_tokens / max(self._dispatch_total, 1)
         token_utilization = self._dispatch_tokens_real / max(self._dispatch_tokens, 1)
+        live_page_share = self._decode_live_pages / self._tables.size
         if self.obs_registry is not None:
             self.obs_registry.set_gauge("batch_fill", batch_fill)
             self.obs_registry.set_gauge("prefill_stall_share", stall_share)
@@ -1705,6 +1717,7 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
             self.obs_registry.set_gauge("prefill_pad_share", pad_share)
             self.obs_registry.set_gauge("kv_cache_bytes", self._kv_cache_bytes)
             self.obs_registry.set_gauge("kv_bytes_per_token", self._kv_bytes_per_token)
+            self.obs_registry.set_gauge("decode_live_page_share", live_page_share)
             self.obs_registry.set_gauge("dispatches_per_round", dispatches_per_round)
             self.obs_registry.set_gauge("tokens_per_dispatch", tokens_per_dispatch)
             self.obs_registry.set_gauge("packed_token_utilization", token_utilization)
@@ -1746,6 +1759,7 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
                 "serve/prefill_pad_share": round(pad_share, 4),
                 "serve/kv_cache_bytes": self._kv_cache_bytes,
                 "serve/kv_bytes_per_token": round(self._kv_bytes_per_token, 4),
+                "serve/decode_live_page_share": round(live_page_share, 4),
                 "serve/dispatches_per_round": round(dispatches_per_round, 4),
                 "serve/tokens_per_dispatch": round(tokens_per_dispatch, 4),
                 "serve/packed_token_utilization": round(token_utilization, 4),
@@ -1892,7 +1906,7 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
             active_slots=n_decoding,
             spec_drafted=int(k_eff.sum()),
             packed_tokens=bucket,
-            kv_bytes=self._decode_kv_bytes(),
+            **self._decode_reads(),
         ):
             with self.tracer.span("dispatch"):
                 logits, self._pool = engine.step_paged(

@@ -299,6 +299,12 @@ def test_packed_warmup_no_steady_state_retrace():
 # -- the round's spans: the same names as the sequential step -----------------
 
 
+def test_packed_decode_step_counts_the_table_entries_a_decode_must_walk():
+    from tests.test_paging import check_live_page_counts
+
+    check_live_page_counts(make_engine(TINY_LLAMA)[0], packed=True)
+
+
 def test_packed_round_has_the_sequential_rounds_span_names():
     """S1 will move the serving cell to the packed step: its rounds must
     feed the same metrics, so they carry the same span names and attributes

@@ -16,6 +16,7 @@ import numpy as np
 import pytest
 
 from relora_tpu.ops.attention import (
+    decode_pages_per_step,
     dot_product_attention,
     paged_cached_attention,
     paged_decode_attention,
@@ -152,6 +153,145 @@ def test_fused_decode_requires_both_scales():
     qk, k_scale = quantize_kv_page(pk)
     with pytest.raises(ValueError, match="k_scale"):
         paged_decode_attention(q, qk, pv, bt, pos, k_scale=k_scale, interpret=True)
+
+
+# ---------------------------------------------------------------------------
+# the walk: only a row's live pages, P table entries a step
+# ---------------------------------------------------------------------------
+
+PAGE = 16  # with 128 tokens a step: P = 8 table entries
+
+
+def _walk_case(
+    seed, last_positions, *, W, S=1, heads=2, kv_heads=2, head_dim=32, kv="f32",
+    stray_tail=False,
+):
+    """Rows of the given last positions over one pool of 16-token pages.  A
+    row owns the pages its queries can see, in scrambled pool order; a row at
+    position 0 with ``None`` in ``last_positions`` rides on an all-null
+    table, as an idle slot does.  Every page no row owns (the null page
+    among them) holds garbage — with ``stray_tail`` the table entries past a
+    row's last live one point at such pages instead of the null page.
+    Returns the kernel's arguments, its scales (``{}`` unless ``kv`` is
+    int8) and the mask of owned pool pages."""
+    B = len(last_positions)
+    rng = np.random.default_rng(seed)
+    need = [0 if p is None else p // PAGE + 1 for p in last_positions]
+    num_pages = 1 + sum(need) + 4
+    perm = rng.permutation(np.arange(1, num_pages))
+    owned = np.zeros(num_pages, bool)
+    bt = np.zeros((B, W), np.int32)
+    strays = perm[sum(need):]
+    at = 0
+    for b, n in enumerate(need):
+        bt[b, :n] = perm[at : at + n]
+        owned[perm[at : at + n]] = True
+        at += n
+        if stray_tail and n:
+            bt[b, n:] = strays[np.arange(W - n) % len(strays)]
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(ks[0], (B, S, heads, head_dim), jnp.float32)
+    pool = [
+        jax.random.normal(k, (num_pages, PAGE, kv_heads, head_dim), jnp.float32)
+        for k in ks[1:]
+    ]
+    # per-token positions p-S+1 .. p: the window ends at the row's last position
+    last = np.array([0 if p is None else p for p in last_positions])
+    pos = np.maximum(last[:, None] - (S - 1) + np.arange(S)[None, :], 0)
+    scales = {}
+    if kv == "int8":
+        (pool[0], scales["k_scale"]), (pool[1], scales["v_scale"]) = (
+            quantize_kv_page(x) for x in pool
+        )
+    elif kv == "bf16":
+        pool = [x.astype(jnp.bfloat16) for x in pool]
+    return (q, pool[0], pool[1], jnp.asarray(bt), jnp.asarray(pos, jnp.int32)), scales, owned
+
+
+#: beside each other in one batch: an idle slot on an all-null table, a row
+#: that fills all 20 table entries (three steps, the last of 4 entries: the
+#: table is no multiple of P), one that ends mid-page, one that ends on the
+#: last token of a step's last page, and one on the first token of the next
+RAGGED = [None, 20 * PAGE - 1, 37, 8 * PAGE - 1, 8 * PAGE]
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+@pytest.mark.parametrize("S", [1, 5, 16])
+@pytest.mark.parametrize("g", [1, 4])
+def test_fused_walk_matches_naive(g, S, kv):
+    args, scales, _ = _walk_case(g * 10 + S, RAGGED, W=20, S=S, heads=2 * g, kv=kv)
+    want = paged_cached_attention(*args, **scales)
+    got = paged_decode_attention(*args, **scales, interpret=True)
+    assert got.shape == want.shape and np.isfinite(np.asarray(got)).all()
+    assert _max_err(got, want) < 2e-5
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+@pytest.mark.parametrize("kv_heads, head_dim", [(16, 128), (8, 256)])
+def test_fused_walk_at_real_head_shapes(kv_heads, head_dim, kv):
+    """The serving cell's heads (16 x 128) and the trainer's (8 x 256)."""
+    args, scales, _ = _walk_case(
+        5, RAGGED, W=20, heads=kv_heads, kv_heads=kv_heads, head_dim=head_dim, kv=kv
+    )
+    want = paged_cached_attention(*args, **scales)
+    got = paged_decode_attention(*args, **scales, interpret=True)
+    assert _max_err(got, want) < 2e-5
+
+
+@pytest.mark.parametrize("W", [3, 8, 16, 20, 27])
+def test_fused_walk_any_table_width(W):
+    """Narrower than a step, exactly one and two steps, and widths that
+    leave a last step of 4 and of 3 entries; a full row beside a short one."""
+    args, scales, _ = _walk_case(W, [W * PAGE - 1, PAGE + 2, None], W=W)
+    want = paged_cached_attention(*args, **scales)
+    got = paged_decode_attention(*args, **scales, interpret=True)
+    assert _max_err(got, want) < 2e-5
+
+
+@pytest.mark.parametrize("S", [1, 5])
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_fused_walk_never_reads_a_page_the_row_does_not_own(kv, S):
+    """Poison every pool page no row owns — the null page, and the pages the
+    tables' tails point at: NaN in a bf16 pool, +-127 codes under huge scales
+    in an int8 one.  The gather oracle would carry the poison into its sums
+    (0 x NaN); the walk never copies such a page, so its output is finite and
+    bit for bit what the clean pool gives."""
+    # (an idle slot attends token 0 of the null page by design: here the row
+    # at position 0 owns its page)
+    args, scales, owned = _walk_case(
+        11, [0] + RAGGED[1:], W=20, S=S, heads=4, kv=kv, stray_tail=True
+    )
+    q, pk, pv, bt, pos = args
+    clean = paged_decode_attention(*args, **scales, interpret=True)
+    assert _max_err(clean, paged_cached_attention(*args, **scales)) < 2e-5
+    foreign = jnp.asarray(~owned)[:, None, None, None]
+    if kv == "int8":
+        sign = jnp.where(jnp.arange(pk.shape[1]) % 2 == 0, 127, -127).astype(jnp.int8)
+        pk, pv = (jnp.where(foreign, sign[None, :, None, None], x) for x in (pk, pv))
+        scales = {
+            name: jnp.where(foreign[:, 0, :, 0], 1e30, x) for name, x in scales.items()
+        }
+    else:
+        pk, pv = (jnp.where(foreign, jnp.nan, x) for x in (pk, pv))
+    got = paged_decode_attention(q, pk, pv, bt, pos, **scales, interpret=True)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(clean))
+
+
+@pytest.mark.parametrize(
+    "page_size, n_kv, head_dim, itemsize, W, want",
+    [
+        (16, 16, 128, 2, 128, 8),  # the serving cell: 128 tokens a step
+        (16, 8, 256, 2, 128, 8),  # pythia_1b's heads: the same bytes a page
+        (16, 16, 128, 1, 128, 8),  # int8 codes
+        (16, 8, 128, 2, 5, 5),  # never more entries than the table has
+        (32, 8, 128, 2, 64, 4),
+        (256, 8, 128, 2, 8, 1),  # a page longer than a step: one page a step
+        (16, 32, 256, 4, 128, 2),  # f32, 512 KiB a page: the VMEM budget binds
+    ],
+)
+def test_decode_pages_per_step_follows_the_shapes(page_size, n_kv, head_dim, itemsize, W, want):
+    assert decode_pages_per_step(page_size, n_kv, head_dim, itemsize, W) == want
 
 
 # ---------------------------------------------------------------------------

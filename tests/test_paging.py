@@ -752,6 +752,54 @@ def test_round_spans_split_the_round_where_the_device_waits():
     assert sum(a["attrs"]["admitted"] for a in admits) == len(reqs)
 
 
+def check_live_page_counts(paged, *, packed):
+    """``live_pages`` of a ``decode_step`` span is the sum over the decoding
+    rows of ``position // page_size + 1`` (the rows' positions as the model
+    was handed them), and the round's ``serve/decode_live_page_share`` is
+    that over the ``max_batch x table width`` entries a kernel that walked
+    every one would visit.  (tests/test_packed.py runs it on the packed step.)"""
+
+    class Records:
+        def __init__(self):
+            self.records = []
+
+        def log(self, record):
+            self.records.append(record)
+
+    from relora_tpu.obs.metrics import MetricsRegistry
+
+    metrics, registry = Records(), MetricsRegistry()
+    sched, rounds, children, _, calls = traced_drain(
+        paged,
+        mixed_requests(TINY_LLAMA.vocab_size),
+        spy="step_paged" if packed else "decode_paged",
+        packed=packed,
+        metrics=metrics,
+        obs_registry=registry,
+    )
+    decode_steps = check_round_tree(rounds, children)
+    shares = [r["serve/decode_live_page_share"] for r in metrics.records if "serve/decode_step" in r]
+    assert len(decode_steps) == len(calls) == len(shares) > 4
+    ps, entries = paged.page_size, 2 * paged.block_table_width
+    seen = set()
+    for step, share, (_pool, _tokens, positions, *rest) in zip(decode_steps, shares, calls):
+        positions = np.asarray(positions)
+        if packed:  # the decoding rows' one-token windows come first
+            positions = positions[0, : step["attrs"]["active_slots"]]
+        else:  # a decoding row's table is not all null
+            positions = positions[:, 0][np.asarray(rest[0]).any(axis=1)]
+        want = int((positions // ps + 1).sum())
+        assert step["attrs"]["live_pages"] == want
+        assert share == pytest.approx(want / entries, abs=1e-4)
+        seen.add(want)
+    assert len(seen) > 2 and max(seen) > 2  # rows crossed page boundaries
+    assert registry.gauge_value("decode_live_page_share") == pytest.approx(shares[-1], abs=1e-4)
+
+
+def test_decode_step_counts_the_table_entries_a_decode_must_walk():
+    check_live_page_counts(make_engines(TINY_LLAMA)[1], packed=False)
+
+
 def test_idle_step_leaves_no_span():
     from relora_tpu.obs.flight import FlightRecorder
     from relora_tpu.obs.tracer import Tracer

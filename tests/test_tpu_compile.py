@@ -5,8 +5,8 @@ aligned to the 8x128 tiling, more VMEM than a kernel may use).  libtpu is
 installed here, and it compiles for a chip that is described and not attached
 — nothing runs, so these say nothing about numbers or times (chip_smoke.py
 does, on the chip); they guard every later PR against a kernel of the main
-path that no longer lowers, at no chip time.  All at ``pythia_1b`` widths:
-8 heads of 256, hidden 2048, FFN 8192, page 16.
+path that no longer lowers, at no chip time.  At ``pythia_1b`` widths (8 heads
+of 256, hidden 2048, FFN 8192, page 16) unless a case says otherwise.
 
 The one file with such compiles, and the topology is described inside a
 fixture (on-chip-measurement guide, section 2): only one process may load the
@@ -62,25 +62,42 @@ def compiled_text(fn, *args) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-def _pool(S, dtype):
-    return S((N_PAGES, PAGE, N_HEADS, HEAD_DIM), dtype)
+def _pool(S, dtype, n_pages=N_PAGES, n_kv=N_HEADS, head_dim=HEAD_DIM):
+    return S((n_pages, PAGE, n_kv, head_dim), dtype)
 
 
-@pytest.mark.parametrize("q_len, kv_dtype", [(1, "bfloat16"), (5, "bfloat16"), (1, "int8")])
-def test_paged_decode_attention_compiles(one_chip, q_len, kv_dtype):
-    """Decode (S=1), the speculative verify window (S=5) and the int8 pool."""
+@pytest.mark.parametrize(
+    "batch, heads, n_kv, head_dim, n_pages, q_len, kv_dtype",
+    [
+        # pythia_1b's heads: decode, the speculative verify window, the int8 pool
+        (BATCH, N_HEADS, N_HEADS, HEAD_DIM, N_PAGES, 1, "bfloat16"),
+        (BATCH, N_HEADS, N_HEADS, HEAD_DIM, N_PAGES, 5, "bfloat16"),
+        (BATCH, N_HEADS, N_HEADS, HEAD_DIM, N_PAGES, 1, "int8"),
+        # serve.pythia_1.4b.chat_c32 as the benchmark runs it: 32 rows, 16 heads
+        # of 128, a 1,025-page pool
+        (32, 16, 16, 128, 1025, 1, "bfloat16"),
+        (32, 16, 16, 128, 1025, 1, "int8"),
+        # grouped queries (Llama): decode, and a verify window over an int8 pool
+        (32, 32, 8, 128, 1025, 1, "bfloat16"),
+        (BATCH, 32, 8, 128, N_PAGES, 5, "int8"),
+    ],
+)
+def test_paged_decode_attention_compiles(
+    one_chip, batch, heads, n_kv, head_dim, n_pages, q_len, kv_dtype
+):
     from relora_tpu.ops.attention import paged_decode_attention
 
     S = one_chip
+    pool = _pool(S, kv_dtype, n_pages, n_kv, head_dim)
     args = [
-        S((BATCH, q_len, N_HEADS, HEAD_DIM), jnp.bfloat16),
-        _pool(S, kv_dtype),
-        _pool(S, kv_dtype),
-        S((BATCH, TABLE_W), jnp.int32),
-        S((BATCH, q_len), jnp.int32),
+        S((batch, q_len, heads, head_dim), jnp.bfloat16),
+        pool,
+        pool,
+        S((batch, TABLE_W), jnp.int32),
+        S((batch, q_len), jnp.int32),
     ]
     if kv_dtype == "int8":
-        args += [S((N_PAGES, N_HEADS), jnp.float32)] * 2
+        args += [S((n_pages, n_kv), jnp.float32)] * 2
 
     def fn(q, k, v, bt, pos, *scales):
         kw = dict(k_scale=scales[0], v_scale=scales[1]) if scales else {}
