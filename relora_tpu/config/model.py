@@ -15,17 +15,20 @@ from __future__ import annotations
 import dataclasses
 import json
 from dataclasses import dataclass
-from typing import Optional
+from typing import Optional, Tuple
 
 
 @dataclass(frozen=True)
 class ModelConfig:
-    """Architecture hyperparameters for both model families.
+    """Architecture hyperparameters for the three model families.
 
     ``family`` is "llama" (RMSNorm, SwiGLU, no biases, separate q/k/v) or
     "neox" (LayerNorm, GELU MLP, biases, fused QKV, parallel residual,
     partial rotary) — the two families the reference implements
-    (modeling_llama.py, modeling_pythia.py).
+    (modeling_llama.py, modeling_pythia.py) — or "mimo" (HF ``mimo_v2``:
+    sliding-window layers with a sink bias beside global layers, K heads
+    wider than V heads, a dense FFN in the leading layers and sigmoid-routed
+    experts after them; served only, see models/mimo.py).
     """
 
     family: str = "llama"
@@ -50,10 +53,33 @@ class ModelConfig:
     tie_word_embeddings: bool = False
     bos_token_id: int = 0
     eos_token_id: int = 1
+    # -- mimo: per-layer kinds, two head geometries, the expert fields --------
+    # 1 = sliding-window attention, 0 = global (HF hybrid_layer_pattern)
+    layer_window: Tuple[int, ...] = ()
+    # 1 = routed experts, 0 = dense FFN (HF moe_layer_freq)
+    layer_moe: Tuple[int, ...] = ()
+    qk_head_dim: int = 0
+    v_head_dim: int = 0
+    window_kv_heads: int = 0  # swa_num_key_value_heads
+    sliding_window: int = 0
+    window_rotary_base: float = 10000.0  # swa_rope_theta
+    window_sink: bool = False  # add_swa_attention_sink_bias
+    global_sink: bool = False  # add_full_attention_sink_bias
+    value_scale: float = 1.0  # attention_value_scale
+    moe_intermediate_size: int = 0
+    n_routed_experts: int = 0  # the router's width, as published
+    # the experts this chip holds: expert_offset .. expert_offset+experts_held-1
+    # (0 held = all of them)
+    experts_held: int = 0
+    expert_offset: int = 0
+    num_experts_per_tok: int = 0
+    scoring_func: str = "sigmoid"
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
 
     @property
     def head_dim(self) -> int:
-        return self.hidden_size // self.num_attention_heads
+        return self.qk_head_dim or self.hidden_size // self.num_attention_heads
 
     @property
     def kv_heads(self) -> int:
@@ -66,6 +92,19 @@ class ModelConfig:
     def num_params(self, include_embeddings: bool = True) -> int:
         """Approximate parameter count (dense, untied)."""
         h, i, L, v = self.hidden_size, self.intermediate_size, self.num_hidden_layers, self.vocab_size
+        if self.family == "mimo":
+            # what this chip holds: its share of the experts, every other leaf whole
+            q, o = self.num_attention_heads * self.qk_head_dim, self.num_attention_heads * self.v_head_dim
+            n = h
+            for window, moe in zip(self.layer_window, self.layer_moe):
+                n_kv = self.window_kv_heads if window else self.kv_heads
+                n += h * (q + n_kv * (self.qk_head_dim + self.v_head_dim)) + o * h + 2 * h
+                n += self.num_attention_heads if (self.window_sink if window else self.global_sink) else 0
+                if moe:
+                    n += (h + 1) * self.n_routed_experts + self.experts_held * 3 * h * self.moe_intermediate_size
+                else:
+                    n += 3 * h * i
+            return n + (2 * v * h if include_embeddings else 0)
         if self.family == "llama":
             per_layer = 4 * h * h + 3 * h * i + 2 * h
             extra = h  # final norm
@@ -84,16 +123,23 @@ class ModelConfig:
     @classmethod
     def from_dict(cls, d: dict) -> "ModelConfig":
         known = {f.name for f in dataclasses.fields(cls)}
-        return cls(**{k: v for k, v in d.items() if k in known})
+        # a JSON round trip turns the per-layer tuples into lists
+        return cls(**{k: tuple(v) if isinstance(v, list) else v for k, v in d.items() if k in known})
 
     @classmethod
     def from_hf_json(cls, path: str) -> "ModelConfig":
         """Read an HF-style config JSON (the reference's configs/*.json format)."""
         with open(path) as f:
             d = json.load(f)
-        family = "neox" if d.get("model_type") == "gpt_neox" else "llama"
+        model_type = d.get("model_type", "llama")
+        if model_type not in MODEL_TYPES:
+            raise ValueError(
+                f"{path}: model_type {model_type!r} is none of {sorted(MODEL_TYPES)}"
+            )
+        if model_type == "mimo_v2":
+            return cls._from_mimo_json(d)
         return cls(
-            family=family,
+            family=MODEL_TYPES[model_type],
             vocab_size=d["vocab_size"],
             hidden_size=d["hidden_size"],
             intermediate_size=d["intermediate_size"],
@@ -113,6 +159,73 @@ class ModelConfig:
             rope_scaling_type=(d.get("rope_scaling") or {}).get("type"),
             rope_scaling_factor=(d.get("rope_scaling") or {}).get("factor", 1.0),
         )
+
+    @classmethod
+    def _from_mimo_json(cls, d: dict) -> "ModelConfig":
+        """HF ``mimo_v2`` keys.  ``experts_held`` / ``expert_offset`` are this
+        repo's: the chip's share of ``n_routed_experts`` (absent = all)."""
+        L = d["num_hidden_layers"]
+        window, moe = tuple(d["hybrid_layer_pattern"]), tuple(d["moe_layer_freq"])
+        if len(window) != L or len(moe) != L:
+            raise ValueError(
+                f"hybrid_layer_pattern ({len(window)}) and moe_layer_freq "
+                f"({len(moe)}) must have num_hidden_layers = {L} entries"
+            )
+        for key in ("num_attention_heads", "head_dim", "v_head_dim"):
+            if d.get(f"swa_{key}", d[key]) != d[key]:
+                raise ValueError(f"swa_{key} = {d['swa_' + key]} differs from {key} = {d[key]}: not supported")
+        if d.get("n_group", 1) != 1 or d.get("topk_group", 1) != 1:
+            raise ValueError("group-limited routing (n_group / topk_group > 1) is not supported")
+        if d.get("n_shared_experts"):
+            raise ValueError("shared experts are not supported")
+        if d.get("scoring_func", "sigmoid") != "sigmoid":
+            raise ValueError(f"scoring_func {d['scoring_func']!r} is not supported (sigmoid only)")
+        n_experts = d["n_routed_experts"]
+        held = d.get("experts_held", n_experts)
+        offset = d.get("expert_offset", 0)
+        if not 0 < held <= n_experts or not 0 <= offset <= n_experts - held:
+            raise ValueError(
+                f"experts {offset} .. {offset + held - 1} are not among the {n_experts} routed"
+            )
+        return cls(
+            family="mimo",
+            vocab_size=d["vocab_size"],
+            hidden_size=d["hidden_size"],
+            intermediate_size=d["intermediate_size"],
+            num_hidden_layers=L,
+            num_attention_heads=d["num_attention_heads"],
+            num_key_value_heads=d["num_key_value_heads"],
+            max_sequence_length=d.get("max_position_embeddings", 2048),
+            rms_norm_eps=d.get("layernorm_epsilon", 1e-5),
+            initializer_range=d.get("initializer_range", 0.02),
+            rotary_pct=d.get("partial_rotary_factor", 1.0),
+            rotary_emb_base=d.get("rope_theta", 10000.0),
+            tie_word_embeddings=d.get("tie_word_embeddings", False),
+            bos_token_id=d.get("bos_token_id", 0),
+            eos_token_id=d.get("eos_token_id", 1),
+            layer_window=window,
+            layer_moe=moe,
+            qk_head_dim=d["head_dim"],
+            v_head_dim=d["v_head_dim"],
+            window_kv_heads=d["swa_num_key_value_heads"],
+            sliding_window=d["sliding_window"],
+            window_rotary_base=d.get("swa_rope_theta", 10000.0),
+            window_sink=d.get("add_swa_attention_sink_bias", False),
+            global_sink=d.get("add_full_attention_sink_bias", False),
+            value_scale=d.get("attention_value_scale", 1.0),
+            moe_intermediate_size=d["moe_intermediate_size"],
+            n_routed_experts=n_experts,
+            experts_held=held,
+            expert_offset=offset,
+            num_experts_per_tok=d["num_experts_per_tok"],
+            norm_topk_prob=d.get("norm_topk_prob", True),
+            routed_scaling_factor=d.get("routed_scaling_factor") or 1.0,
+        )
+
+
+#: HF ``model_type`` -> family; any other is an error (an absent key is Llama,
+#: the reference's own configs/*.json carry none)
+MODEL_TYPES = {"llama": "llama", "gpt_neox": "neox", "mimo_v2": "mimo"}
 
 
 def _llama(h: int, i: int, L: int, heads: int, seq: int = 1024, vocab: int = 32100) -> ModelConfig:

@@ -175,17 +175,27 @@ def cached_attention(
     positions: jax.Array,
     *,
     scale: Optional[float] = None,
+    key_positions: Optional[jax.Array] = None,
+    window: Optional[int] = None,
+    sink: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Masked decode attention against a fixed-capacity KV cache.
 
     ``q`` is ``(B, T, N, H)`` — T is 1 for single-token decode, up to S for
     prefill — holding queries at absolute positions ``positions`` ``(B, T)``
     (or ``(1, T)``, broadcast over batch).  ``k``/``v`` are the cache buffers
-    ``(B, C, N_kv, H)`` with capacity C; entry ``j`` of the cache is visible
+    ``(B, C, N_kv, H)`` with capacity C (V's head size may differ from K's);
+    entry ``j`` of the cache is visible
     to the query at position ``p`` iff ``j <= p``, which is simultaneously
     the causal mask (prefill), the length mask that hides not-yet-written
     (or stale, from an evicted slot) cache tail entries (decode), and the
     pad mask for right-padded prompts.
+
+    ``key_positions`` ``(B, C)`` says which position each entry holds where
+    that is not its index (a ring: :func:`ring_key_positions`); a negative
+    one is never visible.  ``window`` hides entries at ``p - window`` and
+    before.  ``sink`` ``(N,)`` is one more score per query head that joins
+    the softmax's denominator and carries no value.
 
     Math in f32 like the ``naive`` oracle: decode is memory-bound — the
     arithmetic is negligible next to streaming the cache from HBM — so
@@ -200,13 +210,42 @@ def cached_attention(
         raise ValueError(f"num_heads={N} must divide by kv_heads={n_kv}")
     qg = q.astype(jnp.float32).reshape(B, T, n_kv, N // n_kv, H)
     logits = jnp.einsum("btkgh,bskh->bkgts", qg, k.astype(jnp.float32)) * scale
-    visible = jnp.arange(C)[None, None, :] <= positions[..., None]  # (B|1, T, C)
+    if key_positions is None:
+        visible = jnp.arange(C)[None, None, :] <= positions[..., None]  # (B|1, T, C)
+    else:
+        kp = key_positions[:, None, :]
+        visible = (kp <= positions[..., None]) & (kp >= 0)
+    if window is not None:
+        kp = jnp.arange(C)[None, None, :] if key_positions is None else key_positions[:, None, :]
+        visible = visible & (kp > positions[..., None] - window)
     logits = jnp.where(
         visible[:, None, None, :, :], logits, jnp.finfo(jnp.float32).min
     )
-    probs = jax.nn.softmax(logits, axis=-1)
+    if sink is None:
+        probs = jax.nn.softmax(logits, axis=-1)
+    else:
+        # the sink is a column of the softmax that no value stands behind
+        col = jnp.broadcast_to(
+            sink.astype(jnp.float32).reshape(1, n_kv, N // n_kv, 1, 1), (*logits.shape[:-1], 1)
+        )
+        probs = jax.nn.softmax(jnp.concatenate([logits, col], axis=-1), axis=-1)[..., :-1]
     out = jnp.einsum("bkgts,bskh->btkgh", probs, v.astype(jnp.float32))
-    return out.reshape(B, T, N, H).astype(q.dtype)
+    return out.reshape(B, T, N, v.shape[-1]).astype(q.dtype)
+
+
+def ring_key_positions(positions: jax.Array, table_width: int, page_size: int) -> jax.Array:
+    """The position each gathered entry of a ring table holds, ``(B, W*ps)``:
+    entry ``e`` of the table holds the newest logical page ``<=`` the row's
+    last written one that is ``e`` modulo the width — negative (never
+    visible) where the row has not reached that entry yet.  For a table no
+    row has wrapped (a paged one) these are the entries' own indices where
+    written and negative past them."""
+    newest = jnp.max(positions, axis=-1, keepdims=True) // page_size  # (B, 1)
+    entry = jnp.arange(table_width)[None, :]
+    page = newest - (newest - entry) % table_width  # (B, W)
+    return (page[:, :, None] * page_size + jnp.arange(page_size)[None, None, :]).reshape(
+        positions.shape[0], table_width * page_size
+    )
 
 
 def dequantize_gathered_pages(
@@ -256,6 +295,8 @@ def paged_cached_attention(
     k_scale: Optional[jax.Array] = None,
     v_scale: Optional[jax.Array] = None,
     scale: Optional[float] = None,
+    window: Optional[int] = None,
+    sink: Optional[jax.Array] = None,
 ) -> jax.Array:
     """``cached_attention`` against a paged K/V pool.
 
@@ -275,6 +316,10 @@ def paged_cached_attention(
     is dequantized to f32 before attending.  This is the differential
     oracle for the fused :func:`paged_decode_attention` kernel — same math,
     but it materializes both the gathered cache and the score matrix in HBM.
+
+    With ``window`` the table may be a ring (logical page ``p`` in entry
+    ``p % W``): each gathered entry's position comes from
+    :func:`ring_key_positions`, and only the last ``window`` are visible.
     """
     k = gather_kv_pages(pool_k, block_tables)
     v = gather_kv_pages(pool_v, block_tables)
@@ -282,7 +327,15 @@ def paged_cached_attention(
         k = dequantize_gathered_pages(k, k_scale, block_tables)
     if v_scale is not None:
         v = dequantize_gathered_pages(v, v_scale, block_tables)
-    return cached_attention(q, k, v, positions, scale=scale)
+    key_positions = None
+    if window is not None:
+        B = q.shape[0]
+        key_positions = ring_key_positions(
+            jnp.broadcast_to(positions, (B, positions.shape[-1])), block_tables.shape[1], pool_k.shape[1]
+        )
+    return cached_attention(
+        q, k, v, positions, scale=scale, key_positions=key_positions, window=window, sink=sink
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -353,13 +406,15 @@ def _paged_decode_kernel(
     # scalar-prefetch operands (SMEM)
     bt_ref,  # (B, W) int32 block tables
     pos_ref,  # (B, S) int32 per-query-token positions
-    last_ref,  # (B,) int32 last table entry any query of the row can see
+    low_ref,  # (B, S) int32 the lowest position each query token can see
+    span_ref,  # (B, 2) int32 first and last logical page any query of the row can see
     # inputs: the row's queries in VMEM, the pools left in HBM
-    q_ref,  # (1, N*S, H) head-major (row = head*S + s)
-    k_hbm,  # (num_pages, ps, n_kv, H)
-    v_hbm,
+    q_ref,  # (1, N*S, Hk) head-major (row = head*S + s)
+    k_hbm,  # (num_pages, ps, n_kv, Hk)
+    v_hbm,  # (num_pages, ps, n_kv, Hv)
     *refs,  # if quantized: the scales of the row's table entries, k then v,
-    #         (1, steps*P, n_kv) f32 in VMEM; then the output (1, N*S, H) and
+    #         (1, steps*P, n_kv) f32 in VMEM; if there is a sink: its score
+    #         per query row (N*S, 1) f32; then the output (1, N*S, Hv) and
     #         the scratch
     sm_scale: float,
     page_size: int,
@@ -367,31 +422,37 @@ def _paged_decode_kernel(
     q_len: int,
     pages: int,
     quantized: bool,
+    has_sink: bool,
 ):
     P, S, ps = pages, q_len, page_size
-    ks_ref = vs_ref = None
+    ks_ref = vs_ref = sink_ref = None
     if quantized:
         ks_ref, vs_ref, *refs = refs
+    if has_sink:
+        sink_ref, *refs = refs
     o_ref, k_buf, v_buf, sem, slot_ref, acc_ref, m_ref, l_ref = refs
     # k_buf, v_buf: (2, P*ps, n_kv, H), a step's pages, two buffers;
     # sem: DMA (2, 2) by (k|v, buffer); slot_ref: SMEM (1,), the buffer that
-    # holds this row's first step; acc (N*S, H), m, l (N*S, 1): f32 state
+    # holds this row's first step; acc (N*S, Hv), m, l (N*S, 1): f32 state
     b = pl.program_id(0)
     n_rows = pl.num_programs(0)
     T = P * ps
     gS = q_ref.shape[1] // n_kv
+    W = bt_ref.shape[1]
 
     def step_copies(row, step, slot, *, wait=False):
         """Start, or wait for, the copies of the pages ``step`` of ``row``
-        walks, into buffer ``slot``.  A table entry past the row's last live
-        one is not copied: its tokens are hidden by their index, whatever
-        the buffer still holds there."""
+        walks, into buffer ``slot``.  A logical page outside the row's live
+        span is not copied: its tokens are hidden by their position, whatever
+        the buffer still holds there.  Logical page ``p`` is entry ``p % W``
+        of the table: itself in a table as wide as the cache, the ring's
+        entry in a window layer's."""
         for e in range(P):
             entry = step * P + e
 
-            @pl.when(entry <= last_ref[row])
+            @pl.when((entry >= span_ref[row, 0]) & (entry <= span_ref[row, 1]))
             def _():
-                page = bt_ref[row, entry]
+                page = bt_ref[row, entry % W]
                 for side, (hbm, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
                     copy = pltpu.make_async_copy(
                         hbm.at[page], buf.at[slot, pl.ds(e * ps, ps)], sem.at[side, slot]
@@ -405,11 +466,17 @@ def _paged_decode_kernel(
         k_buf[...] = jnp.zeros_like(k_buf)
         v_buf[...] = jnp.zeros_like(v_buf)
         slot_ref[0] = 0
-        step_copies(0, 0, 0)
+        step_copies(0, span_ref[0, 0] // P, 0)
 
     acc_ref[...] = jnp.zeros_like(acc_ref)
-    m_ref[...] = jnp.full_like(m_ref, -1e30)
-    l_ref[...] = jnp.zeros_like(l_ref)
+    if has_sink:
+        # the sink is a score with no value behind it: the softmax starts
+        # from it, at a denominator of exp(sink - sink)
+        m_ref[...] = sink_ref[...]
+        l_ref[...] = jnp.ones_like(l_ref)
+    else:
+        m_ref[...] = jnp.full_like(m_ref, -1e30)
+        l_ref[...] = jnp.zeros_like(l_ref)
 
     def attend_every_head_at_once(step, slot):
         # g*S == 1: a score is the dot of a head's one query with each key of
@@ -418,7 +485,7 @@ def _paged_decode_kernel(
         # broadcast multiply and one reduction over tokens.  Scores keep the
         # reduced lane as (T, n_kv, 1), the layout p needs to scale V rows.
         tok = step * T + jax.lax.broadcasted_iota(jnp.int32, (T, 1, 1), 0)
-        visible = tok <= pos_ref[b, 0]
+        visible = (tok <= pos_ref[b, 0]) & (tok >= low_ref[b, 0])
         q = q_ref[0].astype(jnp.float32) * sm_scale  # (n_kv, H)
         k = k_buf[slot].astype(jnp.float32)
         v = v_buf[slot].astype(jnp.float32)
@@ -442,10 +509,12 @@ def _paged_decode_kernel(
         tok = step * T + jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
         # row i*S + s of a head's block is query token s: g*S scalar SMEM
         # reads build the position column
-        poss = jnp.concatenate(
-            [pos_ref[b, s].reshape(1, 1) for _ in range(g) for s in range(S)], axis=0
-        )
-        visible = tok <= poss  # (gS, T)
+        def column(ref):
+            return jnp.concatenate(
+                [ref[b, s].reshape(1, 1) for _ in range(g) for s in range(S)], axis=0
+            )
+
+        visible = (tok <= column(pos_ref)) & (tok >= column(low_ref))  # (gS, T)
         for j in range(n_kv):
             rows = slice(j * gS, (j + 1) * gS)
 
@@ -481,23 +550,25 @@ def _paged_decode_kernel(
 
     attend = attend_every_head_at_once if gS == 1 else attend_head_by_head
     # only the steps that hold a page some query of the row can see: a step
-    # past them would contribute alpha = 1, p = 0, so it is neither fetched
-    # nor run
-    n_steps = last_ref[b] // P + 1
+    # outside them would contribute alpha = 1, p = 0, so it is neither
+    # fetched nor run
+    step0 = span_ref[b, 0] // P
+    n_steps = span_ref[b, 1] // P - step0 + 1
     slot0 = slot_ref[0]
 
-    def walk(step, _):
-        slot = (slot0 + step) % 2
+    def walk(i, _):
+        step = step0 + i
+        slot = (slot0 + i) % 2
 
         # fetch ahead into the other buffer: this row's next step, or the
         # next row's first
-        @pl.when(step + 1 < n_steps)
+        @pl.when(i + 1 < n_steps)
         def _():
             step_copies(b, step + 1, 1 - slot)
 
-        @pl.when((step + 1 == n_steps) & (b + 1 < n_rows))
+        @pl.when((i + 1 == n_steps) & (b + 1 < n_rows))
         def _():
-            step_copies(b + 1, 0, 1 - slot)
+            step_copies(b + 1, span_ref[jnp.minimum(b + 1, n_rows - 1), 0] // P, 1 - slot)
 
         step_copies(b, step, slot, wait=True)
         attend(step, slot)
@@ -517,6 +588,8 @@ def paged_decode_attention(
     k_scale: Optional[jax.Array] = None,
     v_scale: Optional[jax.Array] = None,
     scale: Optional[float] = None,
+    window: Optional[int] = None,
+    sink: Optional[jax.Array] = None,
     interpret: bool = False,
 ) -> jax.Array:
     """Fused small-S decode/verify attention straight out of the page pool.
@@ -560,8 +633,18 @@ def paged_decode_attention(
     ride in as SMEM scalars to build the ``j <= position`` visibility mask
     per query row.
 
+    The kernel adapts to what it is handed.  V pages may have another head
+    size than K pages (the output has V's).  With ``window`` a query sees
+    only positions ``p - window + 1 .. p``: the walk starts at the first
+    logical page any query of the row can see, and logical page ``e`` is read
+    from table entry ``e % W`` — so a window layer's table may be a ring
+    of a few pages per row (models/step.py), and a table as wide as the cache
+    reads as before.  ``sink`` ``(N,)`` is a score per query head that joins
+    the softmax's denominator with no value behind it: the online softmax
+    starts from ``m = sink, l = 1`` where it otherwise starts from nothing.
+
     ``positions`` is ``(B,)``/``(B, 1)`` (broadcast — every query at the
-    same position) or ``(B, S)`` per-token.  Returns ``(B, S, N, H)`` in
+    same position) or ``(B, S)`` per-token.  Returns ``(B, S, N, Hv)`` in
     ``q.dtype``; math is f32 like every decode path here.  Off-TPU use
     ``interpret=True`` (differential tests); numerics match the naive arm
     to f32 tolerance, not bitwise — online softmax sums in a different
@@ -569,6 +652,7 @@ def paged_decode_attention(
     """
     B, T, N, H = q.shape
     _, page_size, n_kv, _ = pool_k.shape
+    Hv = pool_v.shape[-1]
     W = block_tables.shape[1]
     if N % n_kv:
         raise ValueError(f"num_heads={N} must divide by kv_heads={n_kv}")
@@ -577,6 +661,8 @@ def paged_decode_attention(
     quantized = k_scale is not None
     if quantized != (v_scale is not None):
         raise ValueError("k_scale and v_scale must be given together")
+    if quantized and window is not None:
+        raise ValueError("an int8 pool has no ring: its scales ride by table entry, not by logical page")
 
     # head-major rows: (B, S, N, H) -> (B, N, S, H) -> (B, N*S, H); row
     # n*S + s holds query token s of head n, so kv-head j's group block is
@@ -587,12 +673,22 @@ def paged_decode_attention(
         positions.size == B
     ) else positions.reshape(B, T)
     pos = pos.astype(jnp.int32)
-    last = jnp.clip(jnp.max(pos, axis=1) // page_size, 0, W - 1)
+    if window is None:
+        low = jnp.zeros_like(pos)
+        last = jnp.clip(jnp.max(pos, axis=1) // page_size, 0, W - 1)
+    else:
+        # a ring has no last entry to clip to: a row past its cache is a row
+        # whose table is null throughout
+        low = jnp.maximum(pos - (window - 1), 0)
+        last = jnp.maximum(jnp.max(pos, axis=1), 0) // page_size
+    span = jnp.stack([jnp.min(low, axis=1) // page_size, last], axis=1)
 
-    P = decode_pages_per_step(page_size, n_kv, H, jnp.dtype(pool_k.dtype).itemsize, W)
+    P = decode_pages_per_step(
+        page_size, n_kv, max(H, Hv), jnp.dtype(pool_k.dtype).itemsize, W
+    )
 
     def row_block(rows, cols):
-        return pl.BlockSpec((1, rows, cols), lambda b, bt, pos, last: (b, 0, 0))
+        return pl.BlockSpec((1, rows, cols), lambda b, *_: (b, 0, 0))
 
     in_specs = [row_block(N * T, H)] + [pl.BlockSpec(memory_space=pl.ANY)] * 2
     operands = [q3, pool_k, pool_v]
@@ -605,7 +701,13 @@ def paged_decode_attention(
             of_row = jnp.take(s.astype(jnp.float32), bt, axis=0)
             operands.append(jnp.pad(of_row, ((0, 0), (0, whole_steps - W), (0, 0))))
             in_specs.append(row_block(whole_steps, n_kv))
-    page_buf = pltpu.VMEM((2, P * page_size, n_kv, H), pool_k.dtype)
+    if sink is not None:
+        # row n*S + s of the kernel's state is query token s of head n
+        operands.append(jnp.repeat(sink.astype(jnp.float32), T).reshape(N * T, 1))
+        in_specs.append(pl.BlockSpec((N * T, 1), lambda b, *_: (0, 0)))
+
+    def page_buf(pool):
+        return pltpu.VMEM((2, P * page_size, n_kv, pool.shape[-1]), pool.dtype)
 
     kernel = functools.partial(
         _paged_decode_kernel,
@@ -615,18 +717,19 @@ def paged_decode_attention(
         q_len=T,
         pages=P,
         quantized=quantized,
+        has_sink=sink is not None,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(B,),
         in_specs=in_specs,
-        out_specs=row_block(N * T, H),
+        out_specs=row_block(N * T, Hv),
         scratch_shapes=[
-            page_buf,
-            page_buf,
+            page_buf(pool_k),
+            page_buf(pool_v),
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SMEM((1,), jnp.int32),
-            pltpu.VMEM((N * T, H), jnp.float32),
+            pltpu.VMEM((N * T, Hv), jnp.float32),
             pltpu.VMEM((N * T, 1), jnp.float32),
             pltpu.VMEM((N * T, 1), jnp.float32),
         ],
@@ -634,11 +737,11 @@ def paged_decode_attention(
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, N * T, H), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, N * T, Hv), q.dtype),
         interpret=interpret,
         name="paged_decode_attention",
-    )(bt, pos, last, *operands)
-    return out.reshape(B, N, T, H).transpose(0, 2, 1, 3)
+    )(bt, pos, low, span, *operands)
+    return out.reshape(B, N, T, Hv).transpose(0, 2, 1, 3)
 
 
 # ---------------------------------------------------------------------------

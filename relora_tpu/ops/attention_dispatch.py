@@ -318,6 +318,8 @@ def paged_attention(
     k_scale: Optional[jax.Array] = None,
     v_scale: Optional[jax.Array] = None,
     scale: Optional[float] = None,
+    window: Optional[int] = None,
+    sink: Optional[jax.Array] = None,
     arm: str = "auto",
     interpret: Optional[bool] = None,
 ) -> jax.Array:
@@ -351,12 +353,13 @@ def paged_attention(
         _note_traced_arm("paged_attention", arm, q.shape, pool_k.dtype, interpret)
         return paged_decode_attention(
             q, pool_k, pool_v, block_tables, positions,
-            k_scale=k_scale, v_scale=v_scale, scale=scale, interpret=interpret,
+            k_scale=k_scale, v_scale=v_scale, scale=scale, window=window, sink=sink,
+            interpret=interpret,
         )
     _note_traced_arm("paged_attention", arm, q.shape, pool_k.dtype, False)
     return paged_cached_attention(
         q, pool_k, pool_v, block_tables, positions,
-        k_scale=k_scale, v_scale=v_scale, scale=scale,
+        k_scale=k_scale, v_scale=v_scale, scale=scale, window=window, sink=sink,
     )
 
 

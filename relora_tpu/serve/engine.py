@@ -53,6 +53,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from relora_tpu.config.model import ModelConfig
 from relora_tpu.core.relora import LoraSpec
+from relora_tpu.models import step as model_step
+from relora_tpu.models.step import PAGED, RING, StepContext
 from relora_tpu.obs import memory as obs_memory
 from relora_tpu.obs.compile import CompileWatcher
 from relora_tpu.parallel.mesh import DATA_AXIS, FSDP_AXIS, TENSOR_AXIS, param_shardings
@@ -220,7 +222,32 @@ def build_decode_model(
         from relora_tpu.models.pythia import GPTNeoXForCausalLM
 
         return GPTNeoXForCausalLM(**kwargs)
+    if model_cfg.family == "mimo":
+        from relora_tpu.models.mimo import MimoForCausalLM
+
+        return MimoForCausalLM(
+            model_cfg, dtype=dtype, param_dtype=dtype, decode=True, page_size=page_size
+        )
     raise ValueError(f"Unknown model family {model_cfg.family!r}")
+
+
+def _forward(model, params, cache, ids, ctx: StepContext):
+    """One forward over ``cache``: logits, the cache after it and — from a
+    model with routed experts — the step's ``[local assignments, distinct
+    experts hit]`` summed over its layers (None otherwise).  A model that
+    takes the step context gets it whole; Llama and GPT-NeoX get its fields
+    as the keywords they have always taken."""
+    variables = {"params": params, "cache": cache}
+    if getattr(model, "takes_step_context", False):
+        logits, new = model.apply(variables, ids, ctx, mutable=["cache", "stats"])
+        counts = sum(jax.tree_util.tree_leaves(new.get("stats", {})), jnp.zeros((2,), jnp.int32))
+        return logits, new["cache"], counts
+    tables = None if ctx.tables is None else ctx.tables[PAGED]
+    logits, new = model.apply(
+        variables, ids, positions=ctx.positions, block_tables=tables,
+        adapter_idx=ctx.adapter_idx, row_map=ctx.row_map, mutable=["cache"],
+    )
+    return logits, new["cache"], None
 
 
 class InferenceEngine:
@@ -327,6 +354,7 @@ class InferenceEngine:
         self.requested_num_pages = num_pages or 0
         self.num_pages = (num_pages or 0) * (self.kv_shards if num_pages else 1)
         self.chunk_size = min(chunk_size, cache_size)
+        self._pool_dtype = jnp.int8 if kv_dtype == "int8" else jnp.dtype(dtype)
         self.model = build_decode_model(
             model_cfg,
             cache_size=cache_size,
@@ -336,6 +364,24 @@ class InferenceEngine:
             lora=lora,
             adapter_slots=adapter_slots,
         )
+        # what this family cannot do yet (its model class says): asked for
+        # here, each is an error by the feature's name
+        self.refuses = tuple(getattr(self.model, "refuses", ()))
+        asked = {
+            "the contiguous cache": not self.paged,
+            "adapters": lora is not None or adapter_slots,
+            "int8 pages": kv_dtype == "int8",
+            "speculation": spec_k,
+            "tp": mesh is not None,
+            "packed steps": token_budget,
+        }
+        model_step.check_refused(model_cfg.family, self.refuses, asked)
+        # the ring spec, if the model has window layers (its table width and
+        # window hold for any number of slots)
+        self._ring = next((c for c in self.cache_specs() if c.kind == RING), None) if self.paged else None
+        #: [local assignments, distinct experts hit] of the last forward, on
+        #: the device (None: the model has no routed experts)
+        self.moe_counts = None
         if adapter_slots:
             # the checkpoint carries unstacked (in, r) factors; the slotted
             # model wants (num_slots, in, r) slabs.  Rebuild: non-LoRA leaves
@@ -358,18 +404,13 @@ class InferenceEngine:
         self.draft_params: Optional[PyTree] = None
 
         def prefill_fn(p, ids, positions, cache, adapter_idx):
-            logits, variables = self.model.apply(
-                {"params": p, "cache": cache}, ids, positions=positions,
-                adapter_idx=adapter_idx, mutable=["cache"]
-            )
-            return logits, variables["cache"]
+            ctx = StepContext(positions=positions, adapter_idx=adapter_idx)
+            return _forward(self.model, p, cache, ids, ctx)
 
         def decode_fn(p, cache, token, pos, adapter_idx):
-            logits, variables = self.model.apply(
-                {"params": p, "cache": cache}, token, positions=pos,
-                adapter_idx=adapter_idx, mutable=["cache"]
-            )
-            return logits[:, -1, :], variables["cache"]
+            ctx = StepContext(positions=pos, adapter_idx=adapter_idx)
+            logits, cache, counts = _forward(self.model, p, cache, token, ctx)
+            return logits[:, -1, :], cache, counts
 
         def insert_fn(dcache, pcache, slot):
             def ins(d, src):
@@ -427,27 +468,16 @@ class InferenceEngine:
                 adapter_slots=adapter_slots,
             )
 
-            def prefill_chunk_fn(p, ids, positions, pool, block_tables, adapter_idx):
-                logits, variables = self.paged_model.apply(
-                    {"params": p, "cache": pool},
-                    ids,
-                    positions=positions,
-                    block_tables=block_tables,
-                    adapter_idx=adapter_idx,
-                    mutable=["cache"],
-                )
-                return logits, variables["cache"]
+            # ``tables`` is the block tables of each cache kind
+            # (:meth:`tables_by_kind`); the step context carries them down
+            def prefill_chunk_fn(p, ids, positions, pool, tables, adapter_idx):
+                ctx = StepContext(positions=positions, tables=tables, adapter_idx=adapter_idx)
+                return _forward(self.paged_model, p, pool, ids, ctx)
 
-            def decode_paged_fn(p, pool, token, pos, block_tables, adapter_idx):
-                logits, variables = self.paged_model.apply(
-                    {"params": p, "cache": pool},
-                    token,
-                    positions=pos,
-                    block_tables=block_tables,
-                    adapter_idx=adapter_idx,
-                    mutable=["cache"],
-                )
-                return logits[:, -1, :], variables["cache"]
+            def decode_paged_fn(p, pool, token, pos, tables, adapter_idx):
+                ctx = StepContext(positions=pos, tables=tables, adapter_idx=adapter_idx)
+                logits, pool, counts = _forward(self.paged_model, p, pool, token, ctx)
+                return logits[:, -1, :], pool, counts
 
             # the pool argument is donated AND (under a mesh) committed to
             # pool_shardings by init_pool: jit infers the input sharding from
@@ -468,23 +498,17 @@ class InferenceEngine:
                 "verify_paged", jax.jit(prefill_chunk_fn, donate_argnums=(3,))
             )
 
-            def step_paged_fn(p, ids, positions, pool, block_tables, row_map, adapter_idx):
+            def step_paged_fn(p, ids, positions, pool, tables, row_map, adapter_idx):
                 # the packed mixed-batch forward: one (1, Tb) token-major
                 # window where row_map[t] names the slot token t belongs to.
                 # Attention routes each token through its own block table
                 # (models/llama.attend_with_paged_cache row_map path), so a
                 # single dispatch serves every decode row, verify window, and
                 # however many prefill chunks the token budget admitted.
-                logits, variables = self.paged_model.apply(
-                    {"params": p, "cache": pool},
-                    ids,
-                    positions=positions,
-                    block_tables=block_tables,
-                    adapter_idx=adapter_idx,
-                    row_map=row_map,
-                    mutable=["cache"],
+                ctx = StepContext(
+                    positions=positions, tables=tables, row_map=row_map, adapter_idx=adapter_idx
                 )
-                return logits, variables["cache"]
+                return _forward(self.paged_model, p, pool, ids, ctx)
 
             self._step_paged = cw.wrap(
                 "step_paged", jax.jit(step_paged_fn, donate_argnums=(3,))
@@ -759,7 +783,7 @@ class InferenceEngine:
             raise ValueError("no draft model loaded (call load_draft_params first)")
 
     def draft_prefill_chunk(
-        self, ids: jax.Array, start: int, pool: PyTree, block_table
+        self, ids: jax.Array, start: int, pool: PyTree, block_table, slot: int = 0
     ) -> Tuple[jax.Array, PyTree]:
         """``prefill_chunk`` through the draft weights: same chunk, same
         positions, the draft's own block table (draft pages are allocated
@@ -769,14 +793,14 @@ class InferenceEngine:
         self._require_draft()
         B, T = ids.shape
         positions = _chunk_positions(start, B, T)
-        return self._prefill_chunk(
+        return self._took(self._prefill_chunk(
             self.draft_params,
             jnp.asarray(ids),
             positions,
             pool,
-            jnp.asarray(block_table, jnp.int32),
+            self.tables_by_kind(block_table, slot),
             self._row_idx(None, B),
-        )
+        ))
 
     def draft_decode_paged(
         self, pool: PyTree, token: jax.Array, pos: jax.Array, block_tables
@@ -787,14 +811,14 @@ class InferenceEngine:
         and ``pos = cache_size`` clip their writes into the null page."""
         self._require_paged()
         self._require_draft()
-        return self._decode_paged(
+        return self._took(self._decode_paged(
             self.draft_params,
             pool,
             jnp.asarray(token),
             jnp.asarray(pos, jnp.int32),
-            jnp.asarray(block_tables, jnp.int32),
+            self.tables_by_kind(block_tables),
             self._row_idx(None, token.shape[0]),
-        )
+        ))
 
     def _row_idx(self, adapter_idx, rows: int):
         """Normalize an optional per-row adapter index to a concrete (rows,)
@@ -805,6 +829,34 @@ class InferenceEngine:
         if idx.shape != (rows,):
             raise ValueError(f"adapter_idx must have shape ({rows},), got {idx.shape}")
         return idx
+
+    def _took(self, out):
+        """A jitted step's ``(logits, cache, MoE counts or None)``: the counts
+        kept as :attr:`moe_counts`, the two the callers know returned."""
+        logits, cache, self.moe_counts = out
+        return logits, cache
+
+    def cache_specs(self, batch: int = 0) -> Tuple[model_step.CacheSpec, ...]:
+        """One spec per cache kind of the model's layers (models/step.py);
+        ``batch`` decode slots size the rings of window layers."""
+        self._require_paged()
+        return model_step.cache_specs(
+            self.config, page_size=self.page_size, num_pages=self.num_pages,
+            cache_size=self.cache_size, chunk_size=self.chunk_size, max_batch=batch,
+            itemsize=jnp.dtype(self._pool_dtype).itemsize,
+        )
+
+    def tables_by_kind(self, block_tables, slot=None) -> dict:
+        """The block tables of every cache kind for a step over
+        ``block_tables`` ``(rows, W)``, the paged kind's: row ``i`` is decode
+        slot ``i`` (or the one ``slot``, a prefill chunk's), and a row whose
+        paged table is null is null in every kind."""
+        tables = {PAGED: jnp.asarray(block_tables, jnp.int32)}
+        if self._ring is not None:
+            paged = np.asarray(block_tables)  # noqa: RTL204 - the scheduler's own numpy tables
+            slots = np.arange(len(paged)) if slot is None else np.full(len(paged), slot)
+            tables[RING] = model_step.ring_tables(self._ring, slots, paged[:, 0] != 0)
+        return tables
 
     # -- step functions ------------------------------------------------------
 
@@ -819,19 +871,19 @@ class InferenceEngine:
             raise ValueError(f"prompt length {T} exceeds cache capacity {self.cache_size}")
         positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None, :], (B, T))
         cache = self.init_cache(B)
-        return self._prefill(
+        return self._took(self._prefill(
             self.params, jnp.asarray(ids), positions, cache, self._row_idx(adapter_idx, B)
-        )
+        ))
 
     def decode(self, cache: PyTree, token: jax.Array, pos: jax.Array, adapter_idx=None) -> Tuple[jax.Array, PyTree]:
         """One decode step: ``token``/``pos`` are ``(B, 1)``; returns logits
         ``(B, V)`` and the updated cache.  The input cache is donated —
         the caller must not reuse it after this call."""
         B = token.shape[0]
-        return self._decode(
+        return self._took(self._decode(
             self.params, cache, jnp.asarray(token), jnp.asarray(pos, jnp.int32),
             self._row_idx(adapter_idx, B),
-        )
+        ))
 
     def insert(self, dcache: PyTree, pcache: PyTree, slot) -> PyTree:
         """Copy a single-row prefilled cache into decode slot ``slot``.
@@ -844,13 +896,17 @@ class InferenceEngine:
         if not self.paged:
             raise ValueError("engine was built without page_size: no paged entry points")
 
-    def pool_shapes(self) -> PyTree:
+    def pool_shapes(self, batch: int = 0) -> PyTree:
         """Abstract tree of the shared K/V page pool — per-layer leaves of
         shape (num_pages, page_size, kv_heads, head_dim) (a leading layers
         axis when scanned).  Its byte size scales with ``num_pages``, not
         ``max_batch × cache_size`` — the paged memory win, visible in
-        ``memory_plans()``'s pytree breakdown."""
+        ``memory_plans()``'s pytree breakdown.  A model with more than one
+        cache kind lays its leaves out from :meth:`cache_specs`; its window
+        layers' rings are sized for ``batch`` decode slots."""
         self._require_paged()
+        if hasattr(self.paged_model, "pool_shapes"):
+            return self.paged_model.pool_shapes(self.cache_specs(batch), self._pool_dtype)
         ids = jnp.zeros((1, 1), jnp.int32)
         bt = jnp.zeros((1, self.block_table_width), jnp.int32)
         variables = jax.eval_shape(
@@ -858,22 +914,26 @@ class InferenceEngine:
         )
         return variables["cache"]
 
-    def pool_bytes(self) -> int:
+    def pool_bytes(self, batch: int = 0, kind: Optional[str] = None) -> int:
         """Resident bytes of the shared K/V page pool — codes plus (int8)
-        the per-page scale leaves.  The ``serve/kv_cache_bytes`` gauge."""
+        the per-page scale leaves; of one cache kind alone, what its spec
+        says (rings at ``batch`` slots).  The ``serve/kv_cache_bytes`` gauge."""
         self._require_paged()
+        if kind is not None:
+            return sum(c.pool_bytes for c in self.cache_specs(batch) if c.kind == kind)
         return sum(
             int(np.prod(leaf.shape)) * jnp.dtype(leaf.dtype).itemsize
-            for leaf in jax.tree_util.tree_leaves(self.pool_shapes())
+            for leaf in jax.tree_util.tree_leaves(self.pool_shapes(batch))
         )
 
     def kv_bytes_per_token(self) -> float:
-        """Pool bytes amortized per cacheable token position
+        """Paged-pool bytes amortized per cacheable token position
         (``num_pages × page_size`` across the whole pool) — the
         ``serve/kv_bytes_per_token`` gauge.  ~2×heads×head_dim×itemsize per
         layer; int8 roughly quarters it against an f32 pool."""
         self._require_paged()
-        return self.pool_bytes() / float(self.num_pages * self.page_size)
+        paged = self.pool_bytes() - self.pool_bytes(kind=RING)
+        return paged / float(self.num_pages * self.page_size)
 
     def pool_shardings(self) -> Optional[PyTree]:
         """NamedSharding tree for the page pool: the kv_heads axis shards
@@ -895,14 +955,15 @@ class InferenceEngine:
 
         return jax.tree_util.tree_map(spec, self.pool_shapes())
 
-    def init_pool(self) -> PyTree:
+    def init_pool(self, batch: int = 0) -> PyTree:
         """Concrete zero page pool, kv-head-sharded over ``tensor`` when a
         mesh is set (pool_shardings); the committed placement is what the
         donated prefill_chunk/decode_paged steps inherit, so the pool never
-        leaves its shards across the whole serve loop."""
+        leaves its shards across the whole serve loop.  ``batch`` decode
+        slots size the rings of a model's window layers (models/step.py)."""
         self._require_paged()
         shardings = self.pool_shardings()
-        shapes = self.pool_shapes()
+        shapes = self.pool_shapes(batch)
         if shardings is None:
             return jax.tree_util.tree_map(lambda s: jnp.zeros(s.shape, s.dtype), shapes)
         return jax.tree_util.tree_map(
@@ -912,7 +973,7 @@ class InferenceEngine:
         )
 
     def prefill_chunk(
-        self, ids: jax.Array, start: int, pool: PyTree, block_table, adapter_idx=None
+        self, ids: jax.Array, start: int, pool: PyTree, block_table, adapter_idx=None, slot: int = 0
     ) -> Tuple[jax.Array, PyTree]:
         """Prefill one fixed-size chunk of a single prompt: ``ids`` is
         ``(1, chunk_size)`` (right-padded past the prompt), written at
@@ -920,18 +981,20 @@ class InferenceEngine:
         ``block_table`` ``(1, W)``.  Returns full chunk logits
         ``(1, chunk_size, V)`` and the updated pool (input pool donated).
         One compiled shape total — chunking is what keeps a long prompt off
-        the decode loop's critical path for more than one chunk."""
+        the decode loop's critical path for more than one chunk.  ``slot`` is
+        the decode slot the request will take: a window layer's ring is the
+        slot's (models/step.py)."""
         self._require_paged()
         B, T = ids.shape
         positions = _chunk_positions(start, B, T)
-        return self._prefill_chunk(
+        return self._took(self._prefill_chunk(
             self.params,
             jnp.asarray(ids),
             positions,
             pool,
-            jnp.asarray(block_table, jnp.int32),
+            self.tables_by_kind(block_table, slot),
             self._row_idx(adapter_idx, B),
-        )
+        ))
 
     def decode_paged(
         self, pool: PyTree, token: jax.Array, pos: jax.Array, block_tables, adapter_idx=None
@@ -942,14 +1005,14 @@ class InferenceEngine:
         the null page, never in a page another request is prefilling into.
         Returns logits ``(B, V)`` and the updated pool (input donated)."""
         self._require_paged()
-        return self._decode_paged(
+        return self._took(self._decode_paged(
             self.params,
             pool,
             jnp.asarray(token),
             jnp.asarray(pos, jnp.int32),
-            jnp.asarray(block_tables, jnp.int32),
+            self.tables_by_kind(block_tables),
             self._row_idx(adapter_idx, token.shape[0]),
-        )
+        ))
 
     def verify_paged(
         self, pool: PyTree, tokens: jax.Array, pos: jax.Array, block_tables, adapter_idx=None
@@ -969,14 +1032,14 @@ class InferenceEngine:
         overwritten by the next round's forward before any query can attend
         them."""
         self._require_paged()
-        return self._verify_paged(
+        return self._took(self._verify_paged(
             self.params,
             jnp.asarray(tokens),
             jnp.asarray(pos, jnp.int32),
             pool,
-            jnp.asarray(block_tables, jnp.int32),
+            self.tables_by_kind(block_tables),
             self._row_idx(adapter_idx, tokens.shape[0]),
-        )
+        ))
 
     def step_paged(
         self,
@@ -1001,15 +1064,15 @@ class InferenceEngine:
         whole prompts can prefill inside one step."""
         self._require_paged()
         T = ids.shape[1]
-        return self._step_paged(
+        return self._took(self._step_paged(
             self.params,
             jnp.asarray(ids),
             jnp.asarray(positions, jnp.int32),
             pool,
-            jnp.asarray(block_tables, jnp.int32),
+            self.tables_by_kind(block_tables),
             jnp.asarray(row_map, jnp.int32),
             self._row_idx(adapter_idx, T),
-        )
+        ))
 
     def packed_buckets(self) -> Tuple[int, ...]:
         """The packed-step shapes warmed and used at steady state: halving
@@ -1190,7 +1253,7 @@ class InferenceEngine:
             buckets = self.packed_buckets()
             W1 = self.block_table_width + 1
             with cw.expected_compiles("warmup"):
-                pool = self.init_pool()
+                pool = self.init_pool(batch)
                 logits = None
                 for Tb in buckets:
                     logits, pool = self.step_paged(
@@ -1229,7 +1292,7 @@ class InferenceEngine:
             }
         if self.paged:
             with cw.expected_compiles("warmup"):
-                pool = self.init_pool()
+                pool = self.init_pool(batch)
                 _, pool = self.prefill_chunk(
                     jnp.zeros((1, self.chunk_size), jnp.int32),
                     0,
@@ -1337,7 +1400,14 @@ class InferenceEngine:
         numbers describe host buffers, but the relative breakdown holds."""
         i32 = jnp.int32
         if self.paged:
-            pool = self.pool_shapes()
+            pool = self.pool_shapes(batch)
+
+            def tables(rows: int, width: int) -> dict:
+                kinds = {PAGED: jax.ShapeDtypeStruct((rows, width), i32)}
+                if self._ring is not None:
+                    kinds[RING] = jax.ShapeDtypeStruct((rows, self._ring.table_width), i32)
+                return kinds
+
             plans: dict = {
                 "pytree": obs_memory.pytree_breakdown(
                     {"params": self.params, "kv_cache": pool}
@@ -1349,7 +1419,7 @@ class InferenceEngine:
                 jax.ShapeDtypeStruct((1, self.chunk_size), i32),
                 jax.ShapeDtypeStruct((1, self.chunk_size), i32),
                 pool,
-                jax.ShapeDtypeStruct((1, self.block_table_width), i32),
+                tables(1, self.block_table_width),
                 jax.ShapeDtypeStruct((1,), i32),
             )
             plans["decode_paged"] = obs_memory.plan_for(
@@ -1358,7 +1428,7 @@ class InferenceEngine:
                 pool,
                 jax.ShapeDtypeStruct((batch, 1), i32),
                 jax.ShapeDtypeStruct((batch, 1), i32),
-                jax.ShapeDtypeStruct((batch, self.block_table_width), i32),
+                tables(batch, self.block_table_width),
                 jax.ShapeDtypeStruct((batch,), i32),
             )
             if self.spec_k > 0:
@@ -1369,7 +1439,7 @@ class InferenceEngine:
                     jax.ShapeDtypeStruct((batch, S), i32),
                     jax.ShapeDtypeStruct((batch, S), i32),
                     pool,
-                    jax.ShapeDtypeStruct((batch, self.block_table_width + 1), i32),
+                    tables(batch, self.block_table_width + 1),
                     jax.ShapeDtypeStruct((batch,), i32),
                 )
             if self.token_budget:
@@ -1380,7 +1450,7 @@ class InferenceEngine:
                     jax.ShapeDtypeStruct((1, Tb), i32),
                     jax.ShapeDtypeStruct((1, Tb), i32),
                     pool,
-                    jax.ShapeDtypeStruct((batch + 1, self.block_table_width + 1), i32),
+                    tables(batch + 1, self.block_table_width + 1),
                     jax.ShapeDtypeStruct((Tb,), i32),
                     jax.ShapeDtypeStruct((Tb,), i32),
                 )

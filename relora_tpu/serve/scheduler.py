@@ -55,6 +55,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from relora_tpu.models.step import PAGED, RING, check_refused
 from relora_tpu.obs.tracer import NoopTracer
 from relora_tpu.serve import wire
 from relora_tpu.serve.engine import InferenceEngine, bucket_length
@@ -669,6 +670,16 @@ class ContinuousBatchingScheduler:
             )
 
 
+def _one_expert_bytes(params) -> int:
+    """Bytes of one routed expert's weights as the engine holds them (its
+    slice of the first ``gate_up`` and ``down`` stacks); 0 without experts."""
+    flat, _ = jax.tree_util.tree_flatten_with_path(params)
+    for path, leaf in flat:
+        if jax.tree_util.keystr(path).endswith("['gate_up']"):
+            return 3 * leaf.shape[1] * (leaf.shape[2] // 2) * leaf.dtype.itemsize
+    return 0
+
+
 @dataclasses.dataclass
 class _PagedSlot(_Slot):
     pages: List[int] = dataclasses.field(default_factory=list)  # logical order
@@ -796,6 +807,13 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
                 "PagedContinuousBatchingScheduler needs an engine built with "
                 "page_size/num_pages (got a contiguous InferenceEngine)"
             )
+        asked = {
+            "prefix reuse": prefix_cache,
+            "speculation": spec != "off",
+            "packed steps": packed,
+            "page migration": role != "mixed",
+        }
+        check_refused(engine.config.family, getattr(engine, "refuses", ()), asked)
         self._packed = packed
         if packed:
             if not getattr(engine, "token_budget", 0):
@@ -814,10 +832,18 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
                     f"decode row's window: need >= {floor} "
                     f"(max_batch x window size)"
                 )
+        # one spec per cache kind of the model's layers (models/step.py): the
+        # paged kind's pages are what admission allocates; a window layer's
+        # ring is its slot's for good and costs a request nothing to hold
+        specs = {c.kind: c for c in engine.cache_specs(self.max_batch)}
+        self._paged_spec, self._ring_spec = specs[PAGED], specs.get(RING)
+        ring_bytes = engine.pool_bytes(self.max_batch, RING)
+        self._kv_cache_bytes = engine.pool_bytes(self.max_batch)
+        self._kv_cache_bytes_by_kind = {PAGED: self._kv_cache_bytes - ring_bytes, RING: ring_bytes}
         self.allocator = PageAllocator(
             engine.num_pages,
             engine.page_size,
-            page_bytes=engine.pool_bytes() // engine.num_pages,
+            page_bytes=self._kv_cache_bytes_by_kind[PAGED] // engine.num_pages,
         )
         self.prefix_cache = (
             PrefixCache(self.allocator, max_entries=prefix_cache_entries)
@@ -855,9 +881,15 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
         self._admit_time_s = 0.0  # cumulative prefill/admission wall time
         self._decode_time_s = 0.0  # cumulative decode/packed-step wall time
         # static for the engine's lifetime (pool shapes never change): the
-        # serve/kv_cache_bytes and serve/kv_bytes_per_token gauges
-        self._kv_cache_bytes = engine.pool_bytes()
+        # serve/kv_cache_bytes (set above) and serve/kv_bytes_per_token gauges
         self._kv_bytes_per_token = engine.kv_bytes_per_token()
+        # routed experts (ops/moe.py): the device's [local assignments,
+        # distinct experts hit] of forwards not pulled yet — they ride the
+        # next pull the tokens take — and what one expert's weights weigh
+        self._moe_pending: List[Any] = []
+        cfg = engine.config
+        self._moe_fanout = cfg.num_experts_per_tok * sum(cfg.layer_moe)
+        self._expert_bytes = _one_expert_bytes(engine.params)
         # table entries the last decode had to walk (the decode_step span's
         # live_pages; the serve/decode_live_page_share gauge)
         self._decode_live_pages = 0
@@ -882,7 +914,7 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
 
     def _ensure_pool(self):
         if self._pool is None:
-            self._pool = self.engine.init_pool()
+            self._pool = self.engine.init_pool(self.max_batch)
         return self._pool
 
     def _admit_pass(self, finished: List[Completion]) -> None:
@@ -1015,10 +1047,10 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
         with self.tracer.span(
             "prefill_chunk", trace_id=tid, parent=self.tracer.current_span(),
             uid=req.uid, start=start, chunk=chunk, real=n_real,
-        ):
+        ) as sp_chunk:
             logits, self._pool = self.engine.prefill_chunk(
                 jnp.asarray(ids), start, self._ensure_pool(), table,
-                adapter_idx=np.full(1, slot.adapter_slot, np.int32),
+                adapter_idx=np.full(1, slot.adapter_slot, np.int32), slot=slot_idx,
             )
             self._count_dispatch(chunk, n_real)
             if self._spec == "model":
@@ -1035,6 +1067,10 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
                 first = self._sample_first(logits[:, L - 1 - start, :], req)
                 with self.tracer.span("pull"):
                     first_id = int(np.asarray(first)[0])
+                    # the chunk that ends a prompt is pulled anyway: its own
+                    # counts go on its span (an earlier chunk's reach the
+                    # counters with the next pull)
+                    sp_chunk.set(**self._pull_moe_counts())
         self._observe("prefill_seconds", time.monotonic() - t0)
         if first_id is None:
             return  # more chunks to go; decode proceeds this round regardless
@@ -1577,7 +1613,7 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
                 active_slots=n_decoding,
                 spec_drafted=n_drafted,
                 **self._decode_reads(),
-            ):
+            ) as sp_decode:
                 with self.tracer.span("dispatch"):
                     if drafts:
                         # draft→verify→accept: one verify window per row
@@ -1604,6 +1640,7 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
                         accept, alt = np.asarray(accept), np.asarray(alt)
                     else:
                         next_tokens = np.asarray(drawn).tolist()
+                    sp_decode.set(**self._pull_moe_counts())
             decode_s = time.monotonic() - t_decode
             self._observe("decode_step_seconds", decode_s)
             self._count_round()
@@ -1641,10 +1678,36 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
         positions = [s.pos for s in self._slots if s is not None and s.decoding]
         ps = self.engine.page_size
         self._decode_live_pages = sum(p // ps + 1 for p in positions)
+        if self._ring_spec is None:
+            return {
+                "kv_bytes": sum(p + 1 for p in positions) * self._kv_bytes_per_token,
+                "live_pages": self._decode_live_pages,
+            }
+        # two cache kinds: a window layer reads its last ``window`` tokens,
+        # however long the request
+        by_kind = [sum(c.read_bytes(p) for p in positions) for c in (self._paged_spec, self._ring_spec)]
         return {
-            "kv_bytes": sum(p + 1 for p in positions) * self._kv_bytes_per_token,
+            "kv_bytes": sum(by_kind),
+            "kv_bytes_global": by_kind[0],
+            "kv_bytes_window": by_kind[1],
             "live_pages": self._decode_live_pages,
         }
+
+    def _pull_moe_counts(self) -> Dict[str, int]:
+        """Pull what the forwards since the last pull counted (the arrays are
+        on the device behind results this round has already waited for),
+        add them to the counters, and return the newest forward's as span
+        attributes; nothing for a model without routed experts."""
+        pending, self._moe_pending = self._moe_pending, []
+        if not pending:
+            return {}
+        pulled = [(routed, np.asarray(counts)) for routed, counts in pending]  # noqa: RTL204 - in the round's pull
+        if self.obs_registry is not None:
+            self.obs_registry.inc("moe_assignments_total", by=sum(r for r, _ in pulled))
+            self.obs_registry.inc("moe_assignments_local_total", by=int(sum(c[0] for _, c in pulled)))
+            self.obs_registry.inc("moe_experts_hit_total", by=int(sum(c[1] for _, c in pulled)))
+        local, hit = (int(v) for v in pulled[-1][1])
+        return {"moe_assignments_local": local, "expert_bytes": hit * self._expert_bytes}
 
     def _commit_tokens(
         self, next_tokens: List[int], rows: List[int], finished: List[Completion]
@@ -1680,7 +1743,12 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
 
     def _count_dispatch(self, tokens: int, real: int) -> None:
         """One model dispatch of ``tokens`` window positions, ``real`` of
-        which carried live work (the rest is shape padding)."""
+        which carried live work (the rest is shape padding).  A model with
+        routed experts routes every position, padding too: the dispatch's
+        assignments and the device's count of the local ones wait for the
+        next pull (:meth:`_pull_moe_counts`)."""
+        if self.engine.moe_counts is not None:
+            self._moe_pending.append((tokens * self._moe_fanout, self.engine.moe_counts))
         self._dispatch_total += 1
         self._dispatch_tokens += tokens
         self._dispatch_tokens_real += real
@@ -1716,6 +1784,13 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
             self.obs_registry.set_gauge("prefix_cache_hit_rate", hit_rate)
             self.obs_registry.set_gauge("prefill_pad_share", pad_share)
             self.obs_registry.set_gauge("kv_cache_bytes", self._kv_cache_bytes)
+            if self._ring_spec is not None:
+                for kind, nbytes in self._kv_cache_bytes_by_kind.items():
+                    self.obs_registry.set_gauge(f"kv_cache_bytes_{kind}", nbytes)
+                self.obs_registry.set_gauge("window_ring_pages", self._ring_spec.table_width)
+            if self._moe_fanout:
+                for name in ("moe_assignments_total", "moe_assignments_local_total", "moe_experts_hit_total"):
+                    self.obs_registry.inc(name, by=0)
             self.obs_registry.set_gauge("kv_bytes_per_token", self._kv_bytes_per_token)
             self.obs_registry.set_gauge("decode_live_page_share", live_page_share)
             self.obs_registry.set_gauge("dispatches_per_round", dispatches_per_round)
@@ -1758,6 +1833,15 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
                 "serve/prefix_cache_hit_rate": round(hit_rate, 4),
                 "serve/prefill_pad_share": round(pad_share, 4),
                 "serve/kv_cache_bytes": self._kv_cache_bytes,
+                **(
+                    {
+                        "serve/kv_cache_bytes_paged": self._kv_cache_bytes_by_kind[PAGED],
+                        "serve/kv_cache_bytes_ring": self._kv_cache_bytes_by_kind[RING],
+                        "serve/window_ring_pages": self._ring_spec.table_width,
+                    }
+                    if self._ring_spec is not None
+                    else {}
+                ),
                 "serve/kv_bytes_per_token": round(self._kv_bytes_per_token, 4),
                 "serve/decode_live_page_share": round(live_page_share, 4),
                 "serve/dispatches_per_round": round(dispatches_per_round, 4),

@@ -54,7 +54,7 @@ import threading
 import time
 import zlib
 from collections import deque
-from typing import Any, Callable, Deque, Dict, Optional, Set, Tuple
+from typing import Any, Callable, Deque, Dict, List, Optional, Set, Tuple
 from urllib.parse import urlsplit
 
 from relora_tpu.obs.flight import dump_on_fault
@@ -319,6 +319,8 @@ class GenerateServer:
         self._pending_reload: Optional[_ReloadRequest] = None
         self._last_step_t = time.monotonic()
         self._model_busy = False  # model thread writes; watchdog reads
+        # stream events posted inside the running scheduler step (None outside one)
+        self._outbox: Optional[List[Tuple[Any, Any, Tuple[str, Any, Any]]]] = None
         self._stuck = False  # watchdog writes; healthz reads
         self._watchdog: Optional[threading.Thread] = None
         # -- router-aware warmup ----------------------------------------------
@@ -425,6 +427,39 @@ class GenerateServer:
 
     # -- model thread --------------------------------------------------------
 
+    def _post(self, loop, events: "asyncio.Queue", item: Tuple[str, Any, Any]) -> None:
+        """Hand a stream event (a token, a finish) to the request's handler on
+        the event loop.  Inside a scheduler step the model thread only notes
+        it: a round's events — a token a decoding row — go over in one
+        ``call_soon_threadsafe`` when the step ends (:meth:`_flush_outbox`).
+        One wake-up a row made the loop thread take the GIL row by row, so
+        that the step's commit waited for every stream's write (and, in a
+        process that also holds the clients, for every client's read) while
+        the device stood idle: 17 ms of a 42 ms round at 64 rows (PERF.md
+        section 6, PR 34)."""
+        outbox = self._outbox
+        if outbox is not None and threading.current_thread() is self._worker:
+            outbox.append((loop, events, item))
+            return
+        try:
+            loop.call_soon_threadsafe(events.put_nowait, item)
+        except RuntimeError:
+            pass  # loop closed mid-drain; the record still lands in metrics
+
+    def _flush_outbox(self) -> None:
+        outbox, self._outbox = self._outbox, None
+        if not outbox:
+            return
+
+        def deliver(batch) -> None:
+            for _, events, item in batch:
+                events.put_nowait(item)
+
+        try:
+            outbox[0][0].call_soon_threadsafe(deliver, outbox)
+        except RuntimeError:
+            pass  # loop closed mid-drain
+
     def _model_loop(self) -> None:
         """The scheduler's single driving thread: claim tickets while slots
         are free, apply cancellations, run one decode round, repeat.  Exits
@@ -478,7 +513,11 @@ class GenerateServer:
                 )
                 if sched.has_work():
                     self._model_busy = True
-                    sched.step()
+                    self._outbox = []  # what the step's callbacks post rides out together
+                    try:
+                        sched.step()
+                    finally:
+                        self._flush_outbox()
                     self._last_step_t = time.monotonic()
                     continue
                 self._model_busy = False
@@ -1283,10 +1322,7 @@ class GenerateServer:
         events: "asyncio.Queue[Tuple[str, Any, Any]]" = asyncio.Queue()
 
         def post(kind: str, a: Any = None, b: Any = None) -> None:
-            try:
-                loop.call_soon_threadsafe(events.put_nowait, (kind, a, b))
-            except RuntimeError:
-                pass
+            self._post(loop, events, (kind, a, b))
 
         deadline_s = record.get("deadline_s")
         ticket = Ticket(
@@ -1395,10 +1431,7 @@ class GenerateServer:
         events: "asyncio.Queue[Tuple[str, Any, Any]]" = asyncio.Queue()
 
         def post(kind: str, a: Any = None, b: Any = None) -> None:
-            try:
-                loop.call_soon_threadsafe(events.put_nowait, (kind, a, b))
-            except RuntimeError:
-                pass  # loop closed mid-drain; the record still lands in metrics
+            self._post(loop, events, (kind, a, b))
 
         deadline = (
             time.monotonic() + fields["deadline_s"]

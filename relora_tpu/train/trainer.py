@@ -123,6 +123,11 @@ def _fence_metrics(metric_dicts) -> float:
 
 
 def build_model(model_cfg: ModelConfig, lora: Optional[LoraSpec], cfg: TrainingConfig):
+    if model_cfg.family not in ("llama", "neox"):
+        raise ValueError(
+            f"the {model_cfg.family} family is served, not trained: neither the paper nor the "
+            "model defines ReLoRA over routed experts (ROADMAP.md R4)"
+        )
     compute_dtype = _DTYPES[cfg.dtype]
     if cfg.sp_size > 1:
         # context parallelism: sequence sharded; ring streams K/V blocks

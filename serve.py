@@ -340,11 +340,21 @@ def main(argv=None) -> int:
 
         logger.info(f"random-init weights for {args.model_config} (drill/bench mode)")
         model = build_decode_model(
-            model_cfg, cache_size=args.cache_size or model_cfg.max_sequence_length
+            model_cfg,
+            cache_size=args.cache_size or model_cfg.max_sequence_length,
+            dtype=jnp.bfloat16 if args.dtype == "bf16" else jnp.float32,
         )
-        params = init_params(
-            model, jax.random.PRNGKey(args.seed), jnp.zeros((1, 8), jnp.int32)
-        )
+
+        def init(key):
+            tree = init_params(model, key, jnp.zeros((1, 8), jnp.int32))
+            # a model that states the type it holds its weights in (mimo)
+            # gets every matrix in it; one program, so no f32 tree exists
+            held = getattr(model, "param_dtype", None)
+            return jax.tree_util.tree_map(
+                lambda x: x.astype(held) if held is not None and x.ndim > 1 else x, tree
+            )
+
+        params = jax.jit(init)(jax.random.PRNGKey(args.seed))
     elif args.checkpoint is None:
         raise SystemExit("pass --checkpoint (or --random-init for drills)")
     else:
@@ -493,7 +503,8 @@ def main(argv=None) -> int:
         if args.paged:
             return PagedContinuousBatchingScheduler(
                 engine,
-                prefix_cache=not args.no_prefix_cache,
+                # a family that cannot reuse prefixes yet serves without
+                prefix_cache=not args.no_prefix_cache and "prefix reuse" not in engine.refuses,
                 spec=args.spec,
                 packed=args.packed,
                 role=args.role,

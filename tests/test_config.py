@@ -1,5 +1,6 @@
 """Tests for the training/model config system (args_utils.py parity)."""
 
+import json
 import os
 
 import pytest
@@ -182,3 +183,74 @@ def test_package_import_does_not_initialize_jax():
     )
     r = subprocess.run([sys.executable, "-c", code], capture_output=True, text=True, cwd="/root/repo")
     assert r.returncode == 0, r.stderr
+
+
+MIMO_JSON = {
+    "model_type": "mimo_v2", "hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 3,
+    "hybrid_layer_pattern": [0, 1, 0], "moe_layer_freq": [0, 1, 1], "num_attention_heads": 4,
+    "num_key_value_heads": 1, "swa_num_key_value_heads": 2, "head_dim": 24, "v_head_dim": 16,
+    "partial_rotary_factor": 0.334, "rope_theta": 10000000, "swa_rope_theta": 10000, "sliding_window": 8,
+    "add_swa_attention_sink_bias": True, "add_full_attention_sink_bias": False, "attention_value_scale": 0.707,
+    "moe_intermediate_size": 32, "n_routed_experts": 16, "num_experts_per_tok": 4, "norm_topk_prob": True,
+    "routed_scaling_factor": None, "layernorm_epsilon": 1e-5, "vocab_size": 100,
+}
+
+
+def _mimo(tmp_path, **over):
+    p = tmp_path / "config.json"
+    p.write_text(json.dumps({**MIMO_JSON, **over}))
+    return ModelConfig.from_hf_json(str(p))
+
+
+def test_mimo_v2_json_gives_the_third_family(tmp_path):
+    c = _mimo(tmp_path, experts_held=4, expert_offset=8)
+    assert c.family == "mimo" and c.layer_window == (0, 1, 0) and c.layer_moe == (0, 1, 1)
+    assert (c.head_dim, c.qk_head_dim, c.v_head_dim, c.rotary_dim) == (24, 24, 16, 8)
+    assert (c.kv_heads, c.window_kv_heads, c.sliding_window) == (1, 2, 8)
+    assert (c.rotary_emb_base, c.window_rotary_base) == (1e7, 1e4)
+    assert c.window_sink and not c.global_sink and c.value_scale == 0.707
+    assert (c.n_routed_experts, c.experts_held, c.expert_offset, c.num_experts_per_tok) == (16, 4, 8, 4)
+    assert c.routed_scaling_factor == 1.0 and c.rms_norm_eps == 1e-5
+    # without a share the chip holds every expert; a dict round trip keeps the per-layer kinds
+    assert _mimo(tmp_path).experts_held == 16
+    assert ModelConfig.from_dict(json.loads(json.dumps(c.to_dict()))) == c
+    # what this chip holds: one global dense layer, one window and one global expert layer of 4 experts
+    attn = lambda n_kv: 64 * (4 * 24 + n_kv * 40) + 4 * 16 * 64 + 2 * 64
+    want = 64 + attn(1) + 3 * 64 * 128 + (attn(2) + 4) + attn(1) + 2 * (65 * 16 + 4 * 3 * 64 * 32) + 2 * 100 * 64
+    assert c.num_params() == want
+
+
+@pytest.mark.parametrize(
+    "over, match",
+    [
+        ({"hybrid_layer_pattern": [0, 1]}, "must have num_hidden_layers"),
+        ({"experts_held": 4, "expert_offset": 14}, "not among the 16 routed"),
+        ({"n_group": 2}, "group-limited routing"),
+        ({"n_shared_experts": 1}, "shared experts"),
+        ({"scoring_func": "softmax"}, "sigmoid only"),
+        ({"swa_head_dim": 32}, "swa_head_dim"),
+    ],
+)
+def test_mimo_v2_json_refuses_what_the_model_does_not_compute(tmp_path, over, match):
+    with pytest.raises(ValueError, match=match):
+        _mimo(tmp_path, **over)
+
+
+def test_unknown_model_type_is_an_error(tmp_path):
+    """No silent reading of another family's config as Llama."""
+    p = tmp_path / "config.json"
+    p.write_text(json.dumps({**MIMO_JSON, "model_type": "lfm2"}))
+    with pytest.raises(ValueError, match="model_type 'lfm2' is none of"):
+        load_model_config(str(p))
+    p.write_text('{"hidden_size": 8, "intermediate_size": 8, "num_hidden_layers": 1, "num_attention_heads": 1, "vocab_size": 8}')
+    assert load_model_config(str(p)).family == "llama"  # the reference's own configs carry no model_type
+
+
+def test_every_config_file_in_the_tree_parses():
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    found = []
+    for sub in ("configs", os.path.join("benchmark", "configs"), os.path.join("benchmark", "tests", "data", "configs")):
+        for name in sorted(os.listdir(os.path.join(root, sub))):
+            if name.endswith(".json"):
+                found.append(load_model_config(os.path.join(root, sub, name)).family)
+    assert sorted(set(found)) == ["mimo", "neox"] and len(found) >= 5

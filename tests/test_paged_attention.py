@@ -434,3 +434,75 @@ def test_training_auto_on_cpu_is_xla_bitwise():
     auto = dot_product_attention(q, k, v, impl="auto")
     forced = dot_product_attention(q, k, v, impl="xla")
     np.testing.assert_array_equal(np.asarray(auto), np.asarray(forced))
+
+
+# ---------------------------------------------------------------------------
+# K heads wider than V heads, a window through a ring table, a sink score
+# ---------------------------------------------------------------------------
+
+
+def _ring_case(seed, positions, *, ring, window, heads, kv_heads, dk, dv, sink):
+    """Rows at ``positions`` over ring tables of ``ring`` pages (``None``: a
+    table as wide as the row needs): logical page ``p`` of a row lives in
+    entry ``p % ring``, older pages overwritten, so the pool holds only what a
+    window of ``window`` can still see.  Returns the kernel's arguments and an
+    oracle computed in numpy float64 from the row's own token list."""
+    rng = np.random.default_rng(seed)
+    B = len(positions)
+    W = ring or max(p // PAGE + 1 for p in positions)
+    num_pages = 1 + B * W
+    bt = 1 + np.arange(B * W, dtype=np.int32).reshape(B, W)
+    q = rng.standard_normal((B, 1, heads, dk)).astype(np.float32)
+    pool_k = rng.standard_normal((num_pages, PAGE, kv_heads, dk)).astype(np.float32)  # garbage
+    pool_v = rng.standard_normal((num_pages, PAGE, kv_heads, dv)).astype(np.float32)
+    s = rng.standard_normal(heads).astype(np.float32) if sink else None
+    want = np.zeros((B, 1, heads, dv))
+    g = heads // kv_heads
+    for b, p in enumerate(positions):
+        k = rng.standard_normal((p + 1, kv_heads, dk))
+        v = rng.standard_normal((p + 1, kv_heads, dv))
+        for t in range(p + 1):  # written in order: a ring keeps the newest
+            pool_k[bt[b, (t // PAGE) % W], t % PAGE] = k[t]
+            pool_v[bt[b, (t // PAGE) % W], t % PAGE] = v[t]
+        lo = max(0, p - window + 1) if window else 0
+        for n in range(heads):
+            scores = k[lo:, n // g] @ q[b, 0, n].astype(np.float64) * dk**-0.5
+            top = max(scores.max(), s[n]) if sink else scores.max()
+            e = np.exp(scores - top)
+            denom = e.sum() + (np.exp(s[n] - top) if sink else 0.0)
+            want[b, 0, n] = (e / denom) @ v[lo:, n // g]
+    args = (jnp.asarray(q), jnp.asarray(pool_k), jnp.asarray(pool_v), jnp.asarray(bt), jnp.asarray(positions, jnp.int32)[:, None])
+    kw = dict(window=window, sink=None if s is None else jnp.asarray(s))
+    return args, kw, want
+
+
+@pytest.mark.parametrize(
+    "ring, window, sink, heads, kv_heads",
+    [
+        (None, None, False, 8, 2),  # a global layer: K 24 wide, V 16, grouped queries
+        (None, None, True, 8, 2),  # ... with a sink
+        (5, 40, True, 8, 2),  # a window layer through a 5-page ring, wrapped many times over
+        (5, 40, False, 4, 4),  # every head at once (g*S == 1), no sink
+        (9, 128, True, 4, 4),  # the published window: 9 pages seen, two steps of 8
+    ],
+)
+def test_fused_walk_unlike_head_sizes_window_ring_and_sink(ring, window, sink, heads, kv_heads):
+    """Against an f64 oracle that never saw a page: the first live page is
+    where the window starts, found through the ring, and the sink joins the
+    denominator only.  Rows before the window fills, mid-page, and far past
+    several wraps of the ring."""
+    positions = [0, 7, 38, 131, 16 * 23 + 5]
+    args, kw, want = _ring_case(3, positions, ring=ring, window=window, heads=heads, kv_heads=kv_heads, dk=24, dv=16, sink=sink)
+    got = paged_decode_attention(*args, **kw, interpret=True)
+    assert got.shape == want.shape
+    assert np.abs(np.asarray(got, np.float64) - want).max() < 2e-5
+    # the gather arm computes the same through ring_key_positions
+    naive = paged_cached_attention(*args, **kw)
+    assert np.abs(np.asarray(naive, np.float64) - want).max() < 2e-5
+
+
+def test_sink_and_window_change_the_result():
+    args, kw, want = _ring_case(4, [50, 90], ring=5, window=40, heads=4, kv_heads=2, dk=24, dv=16, sink=True)
+    for drop in ({"sink": None}, {"window": 39}):
+        got = paged_decode_attention(*args, **{**kw, **drop}, interpret=True)
+        assert np.abs(np.asarray(got, np.float64) - want).max() > 1e-3

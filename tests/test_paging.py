@@ -1005,3 +1005,80 @@ def test_sample_draws_total_counts_each_draw_under_its_batchs_path():
     assert step(temperature=0.8, top_p=0.9) == ({"greedy": 0, "categorical": 0, "nucleus": 2}, ["nucleus"])
     assert step() == ({"greedy": 0, "categorical": 0, "nucleus": 1}, ["nucleus"])
     assert "sample_draws_total.greedy" in sched.obs_registry.snapshot()
+
+
+# -- one cache spec per layer kind (models/step.py) -----------------------------
+
+TINY_MIMO = ModelConfig(
+    family="mimo", vocab_size=256, hidden_size=32, intermediate_size=64, num_hidden_layers=4,
+    num_attention_heads=4, num_key_value_heads=1, max_sequence_length=256, rotary_pct=0.334,
+    layer_window=(0, 1, 1, 0), layer_moe=(0, 1, 1, 1), qk_head_dim=12, v_head_dim=8, window_kv_heads=2,
+    sliding_window=8, window_sink=True, value_scale=0.707, moe_intermediate_size=16,
+    n_routed_experts=8, experts_held=2, expert_offset=0, num_experts_per_tok=2,
+)
+
+
+def _spec_engine(model_cfg, kv_dtype="bf16"):
+    model = build_decode_model(model_cfg, cache_size=64)
+    params = init_params(model, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    return InferenceEngine(
+        model_cfg, params, cache_size=64, page_size=4, num_pages=40, chunk_size=8, kv_dtype=kv_dtype
+    )
+
+
+@pytest.mark.parametrize("model_cfg", [TINY_LLAMA, TINY_NEOX, TINY_MIMO], ids=["llama", "neox", "mimo"])
+def test_cache_specs_say_what_the_pool_holds(model_cfg):
+    """The engine reads one thing for all three families: a spec per cache
+    kind whose bytes are the pool's leaves', and whose per-token bytes are
+    what the scheduler prices a decode's reads with."""
+    from relora_tpu.models.step import PAGED, RING
+
+    eng = _spec_engine(model_cfg)
+    specs = eng.cache_specs(3)
+    leaves = jax.tree_util.tree_leaves(eng.pool_shapes(3))
+    assert sum(c.pool_bytes for c in specs) == eng.pool_bytes(3) == sum(
+        int(np.prod(x.shape)) * x.dtype.itemsize for x in leaves
+    )
+    paged = specs[0]
+    assert paged.kind == PAGED and paged.num_pages == 40 and paged.table_width == 16 and paged.window == 0
+    assert eng.pool_bytes(3, PAGED) == paged.pool_bytes
+    if model_cfg.family != "mimo":
+        assert len(specs) == 1 and eng.pool_bytes(3, RING) == 0
+        assert paged.bytes_per_token == eng.kv_bytes_per_token() == 2 * 2 * 4 * 16 * 4
+        assert set(eng.tables_by_kind(np.zeros((3, 16), np.int32))) == {PAGED}
+        return
+    ring = specs[1]
+    assert ring.kind == RING and ring.layers == 2 and ring.window == 8
+    assert ring.table_width == (8 + 8) // 4 + 1 and ring.num_pages == 1 + 3 * 5
+    assert (paged.k_dim, paged.v_dim, paged.k_pad, paged.kv_heads, ring.kv_heads) == (12, 8, 116, 1, 2)
+    assert paged.bytes_per_token == 2 * 1 * 20 * 4 and ring.bytes_per_token == 2 * 2 * 20 * 4
+    assert paged.read_bytes(99) == 100 * paged.bytes_per_token and ring.read_bytes(99) == 8 * ring.bytes_per_token
+    assert ring.read_bytes(2) == 3 * ring.bytes_per_token
+    # the pool's leaves: a K head stored in whole 128-lane tiles, V as it is
+    shapes = eng.pool_shapes(3)
+    assert shapes["layers_0"]["attn"]["k"].shape == (40, 4, 1, 128) and shapes["layers_0"]["attn"]["v"].shape == (40, 4, 1, 8)
+    assert shapes["layers_1"]["attn"]["k"].shape == (16, 4, 2, 128)
+
+
+def test_ring_tables_are_the_slots_own_pages_and_null_for_idle_rows():
+    from relora_tpu.models.step import PAGED, RING
+
+    eng = _spec_engine(TINY_MIMO)
+    paged = np.zeros((3, 16), np.int32)
+    paged[1, :3] = [7, 9, 2]
+    tables = eng.tables_by_kind(paged)
+    assert np.array_equal(np.asarray(tables[PAGED]), paged)
+    assert tables[RING].tolist() == [[0] * 5, [6, 7, 8, 9, 10], [0] * 5]
+    # a prefill chunk of the request that will decode in slot 2
+    assert eng.tables_by_kind(paged[1:2], slot=2)[RING].tolist() == [[11, 12, 13, 14, 15]]
+
+
+def test_allocator_counts_global_pages_only():
+    """Admission allocates the paged kind's pages; a window layer's ring costs
+    a request nothing, however long it is."""
+    eng = _spec_engine(TINY_MIMO)
+    sched = PagedContinuousBatchingScheduler(eng, max_batch=3, eos_id=-1, prefix_cache=False)
+    assert sched.allocator.page_bytes == eng.pool_bytes(3, "paged") // 40
+    sched.submit(Request(uid=1, prompt=list(range(30)), max_new_tokens=10))
+    sched.step()
+    assert sched.allocator.used_pages == pages_needed(40, 4)

@@ -202,3 +202,61 @@ def test_grouped_lora_matmul_compiles(one_chip):
         return lora_matmul_grouped(x, w, a, b, s, idx, arm="grouped", interpret=False)
 
     assert "tpu_custom_call" in compiled_text(fn, *args)
+
+
+def test_mimo_decode_program_compiles_at_the_cells_shapes(one_chip, monkeypatch):
+    """``serve.mimo_v2.5.reason_c64``'s decode: 64 rows, published widths,
+    seven layers of three kinds, both cache kinds at the cell's sizes.  The
+    paged kernel takes K pages of 192 features stored in 256 lanes beside V
+    pages of 128, walks a 25-page ring in the window layers and starts their
+    softmax from the sink; the experts' grouped product is Mosaic's; and the
+    plan holds the bf16 weights once (an f32 copy would be 13.7 GB)."""
+    import json
+    import os
+
+    from benchmark import weights_mimo
+    from relora_tpu.config.model import load_model_config
+    from relora_tpu.models import step as model_step
+    from relora_tpu.models.mimo import MimoForCausalLM
+    from relora_tpu.models.step import StepContext
+    from relora_tpu.serve.engine import _forward
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the dispatchers take their TPU branch
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    path = os.path.join(root, "benchmark", "configs", "mimo_v2.5.json")
+    with open(path) as f:
+        raw = json.load(f)
+    with open(os.path.join(root, "benchmark", "workloads", "serve.mimo_v2.5.reason_c64.json")) as f:
+        w = json.load(f)
+    cfg = load_model_config(path)
+    B, ps = w["max_batch"], w["page_size"]
+    model = MimoForCausalLM(cfg, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, decode=True, page_size=ps)
+    specs = model_step.cache_specs(
+        cfg, page_size=ps, num_pages=w["num_pages"], cache_size=w["cache_size"], chunk_size=w["chunk_size"],
+        max_batch=B, itemsize=2,
+    )
+    pool = jax.tree_util.tree_map(lambda s: one_chip(s.shape, s.dtype), model.pool_shapes(specs, jnp.bfloat16))
+
+    def leaves(shapes, name=""):
+        if isinstance(shapes, dict):
+            return {k: leaves(v, k) for k, v in shapes.items()}
+        return one_chip(shapes, jnp.float32 if name in ("scale", "sink", "select_bias") else jnp.bfloat16)
+
+    params = leaves(weights_mimo.param_shapes(raw))
+    tables = {c.kind: one_chip((B, c.table_width), jnp.int32) for c in specs}
+
+    def decode(p, pool, tok, pos, tables):
+        logits, pool, counts = _forward(model, p, pool, tok, StepContext(positions=pos, tables=tables))
+        return logits[:, -1, :], pool, counts
+
+    compiled = jax.jit(decode, donate_argnums=(1,)).lower(
+        params, pool, one_chip((B, 1), jnp.int32), one_chip((B, 1), jnp.int32), tables
+    ).compile()
+    text = compiled.as_text()
+    assert text.count("paged_decode_attention") >= 7 and "%ragged-dot-none" in text
+    plan = compiled.memory_analysis()
+    held = sum(int(jnp.prod(jnp.asarray(x.shape))) * x.dtype.itemsize for x in jax.tree_util.tree_leaves(params))
+    assert 6.8e9 < held < 6.9e9
+    assert plan.temp_size_in_bytes < 1e9  # no second copy of the weights, no copy of a pool
+    live = plan.argument_size_in_bytes + plan.output_size_in_bytes - plan.alias_size_in_bytes + plan.temp_size_in_bytes
+    assert live < 11e9
