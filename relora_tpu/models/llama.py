@@ -69,6 +69,10 @@ def attend_with_cache(
     return cached_attention(q, ck.value, cv.value, positions)
 
 
+def pool_must_be_given(*_):
+    raise ValueError("the paged forward needs its page pool in the 'cache' collection (engine.init_pool)")
+
+
 def attend_with_paged_cache(
     module: nn.Module,
     q: jax.Array,
@@ -77,6 +81,7 @@ def attend_with_paged_cache(
     positions: jax.Array,
     block_tables: jax.Array,
     row_map: Optional[jax.Array] = None,
+    layer: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Paged twin of :func:`attend_with_cache`: K/V pages live in one shared
     pool ("cache" collection, shape (num_pages, page_size, n_kv, head_dim) —
@@ -88,8 +93,18 @@ def attend_with_paged_cache(
     A logical page index beyond the row's table width clips to the last
     column, and padded table entries hold the null page (serve/paging.py) —
     so garbage writes from idle decode rows and chunk padding land where
-    nothing ever reads unmasked.  Under ``nn.scan`` the pool stacks on the
-    leading "layers" axis, exactly like the contiguous cache.
+    nothing ever reads unmasked.
+
+    The pool is the caller's to give (:func:`paged_pool_shapes`,
+    serve/engine.init_pool); an init makes parameters and no pool.  Under
+    ``nn.scan`` the leaves carry a leading layers axis, ``(L, num_pages,
+    ...)``, and ride the layer loop *whole*, as a carry: no layer's pages are
+    ever sliced out of the stack or written back into it.  ``layer`` (the
+    loop's index) says which pages are this layer's: the leaf is viewed as
+    ``(L * num_pages, ...)`` — a reshape that moves nothing — and ``layer *
+    num_pages`` is added to every table entry, so the write, the gather and
+    the kernels below address the stack in place by page id.  Page 0 of each
+    layer stays that layer's null page.
 
     ``module.kv_dtype == "int8"`` stores the pool as int8 codes plus f32
     per-``(page, kv_head)`` absmax scales (ops/quant.quantize_kv_page
@@ -117,11 +132,18 @@ def attend_with_paged_cache(
         raise ValueError("paged decode requires block_tables (got None)")
     if row_map is not None and B != 1:
         raise ValueError(f"packed (row_map) forward is token-major: B must be 1, got {B}")
-    n_kv, hd = k_new.shape[2], k_new.shape[3]
+    if module.is_initializing():  # parameters only: the pool is never an init's to make
+        return dot_product_attention(q, k_new, v_new, causal=True, impl="xla")
+    n_kv = k_new.shape[2]
     quantized = getattr(module, "kv_dtype", "bf16") == "int8"
-    pool_dtype = jnp.int8 if quantized else k_new.dtype
-    ck = module.variable("cache", "k", jnp.zeros, (num_pages, ps, n_kv, hd), pool_dtype)
-    cv = module.variable("cache", "v", jnp.zeros, (num_pages, ps, n_kv, hd), pool_dtype)
+    names = ("k", "v", "k_scale", "v_scale") if quantized else ("k", "v")
+    leaves = {name: module.variable("cache", name, pool_must_be_given) for name in names}
+    if layer is None:
+        pool = {name: leaf.value for name, leaf in leaves.items()}
+    else:
+        # the stacked leaves as one run of pages, this layer's at its offset
+        pool = {name: leaf.value.reshape((-1,) + leaf.value.shape[2:]) for name, leaf in leaves.items()}
+        block_tables = block_tables + layer * leaves["k"].value.shape[1]
     positions = jnp.broadcast_to(positions, (B, T)).astype(jnp.int32)
     W = block_tables.shape[1]
     logical = jnp.clip(positions // ps, 0, W - 1)
@@ -137,17 +159,6 @@ def attend_with_paged_cache(
         ).reshape(B, T)
     offs = positions % ps
 
-    if not quantized:
-        ck.value = ck.value.at[rows, offs].set(k_new.astype(ck.value.dtype))
-        cv.value = cv.value.at[rows, offs].set(v_new.astype(cv.value.dtype))
-        if row_map is not None:
-            return packed_attention(
-                q, ck.value, cv.value, block_tables, row_map, positions
-            )
-        return paged_attention(q, ck.value, cv.value, block_tables, positions)
-
-    cks = module.variable("cache", "k_scale", jnp.zeros, (num_pages, n_kv), jnp.float32)
-    cvs = module.variable("cache", "v_scale", jnp.zeros, (num_pages, n_kv), jnp.float32)
     flat_rows = rows.reshape(-1)  # (B*T,)
     # a tenant always enters a page at offset 0, so an offset-0 write starts
     # that page's life: clear the previous tenant's scale (and, via ratio=0,
@@ -180,17 +191,41 @@ def attend_with_paged_cache(
         ).astype(jnp.int8)
         return codes.at[rows, offs].set(q_new), new_scale
 
-    ck.value, cks.value = write_quantized(ck.value, cks.value, k_new)
-    cv.value, cvs.value = write_quantized(cv.value, cvs.value, v_new)
+    if quantized:
+        pool["k"], pool["k_scale"] = write_quantized(pool["k"], pool["k_scale"], k_new)
+        pool["v"], pool["v_scale"] = write_quantized(pool["v"], pool["v_scale"], v_new)
+    else:
+        pool["k"] = pool["k"].at[rows, offs].set(k_new.astype(pool["k"].dtype))
+        pool["v"] = pool["v"].at[rows, offs].set(v_new.astype(pool["v"].dtype))
+    for name, leaf in leaves.items():
+        leaf.value = pool[name].reshape(leaf.value.shape)
+    k, v = pool.pop("k"), pool.pop("v")  # what is left are the scales, if any
     if row_map is not None:
-        return packed_attention(
-            q, ck.value, cv.value, block_tables, row_map, positions,
-            k_scale=cks.value, v_scale=cvs.value,
-        )
-    return paged_attention(
-        q, ck.value, cv.value, block_tables, positions,
-        k_scale=cks.value, v_scale=cvs.value,
-    )
+        return packed_attention(q, k, v, block_tables, row_map, positions, **pool)
+    return paged_attention(q, k, v, block_tables, positions, **pool)
+
+
+def paged_pool_shapes(module: nn.Module, attention: str, specs, dtype) -> dict:
+    """The ``cache`` collection the paged forward of a Llama or GPT-NeoX
+    model wants: under its attention module (``attention`` is its name) a K
+    and a V leaf ``(num_pages, page_size, kv_heads, head_dim)`` of the one
+    cache kind's spec (models/step.CacheSpec), and for an int8 pool the f32
+    scales ``(num_pages, kv_heads)``; stacked on a leading layers axis under
+    ``layers`` when the model scans, a ``layers_{i}`` each when it does not."""
+    (s,) = specs
+    leaves = {
+        "k": jax.ShapeDtypeStruct((s.num_pages, s.page_size, s.kv_heads, s.k_dim), dtype),
+        "v": jax.ShapeDtypeStruct((s.num_pages, s.page_size, s.kv_heads, s.v_dim), dtype),
+    }
+    if jnp.dtype(dtype) == jnp.int8:
+        scale = jax.ShapeDtypeStruct((s.num_pages, s.kv_heads), jnp.float32)
+        leaves.update(k_scale=scale, v_scale=scale)
+    if not module.scan_layers:
+        return {f"layers_{i}": {attention: dict(leaves)} for i in range(s.layers)}
+    stacked = {
+        name: jax.ShapeDtypeStruct((s.layers,) + leaf.shape, leaf.dtype) for name, leaf in leaves.items()
+    }
+    return {"layers": {attention: stacked}}
 
 
 class RMSNorm(nn.Module):
@@ -292,6 +327,7 @@ class LlamaAttention(nn.Module):
         block_tables: Optional[jax.Array] = None,
         adapter_idx: Optional[jax.Array] = None,
         row_map: Optional[jax.Array] = None,
+        layer: Optional[jax.Array] = None,
     ) -> jax.Array:
         cfg = self.config
         h, n, hd = cfg.hidden_size, cfg.num_attention_heads, cfg.head_dim
@@ -314,7 +350,7 @@ class LlamaAttention(nn.Module):
         # n/n_kv× the K/V bytes in HBM and ride the ring at full width)
         if self.decode and self.page_size > 0:
             out = attend_with_paged_cache(
-                self, q, k, v, positions, block_tables, row_map
+                self, q, k, v, positions, block_tables, row_map, layer
             )
         elif self.decode:
             out = attend_with_cache(self, q, k, v, positions)
@@ -349,8 +385,10 @@ class LlamaMLP(nn.Module):
 class LlamaDecoderLayer(nn.Module):
     """Pre-norm block (parity: modeling_llama.py:243-308).
 
-    Signature is scan-compatible:
-    ``(x, cos, sin, positions, det, block_tables, adapter_idx) -> (x, None)``.
+    Signature is scan-compatible: ``(x, cos, sin, positions, det,
+    block_tables, adapter_idx, row_map[, layer]) -> (x, None)``; ``layer`` is
+    the loop's index, given where the stacked page pool rides the loop whole
+    (:func:`attend_with_paged_cache`).
     """
 
     config: ModelConfig
@@ -364,7 +402,7 @@ class LlamaDecoderLayer(nn.Module):
     kv_dtype: str = "bf16"
 
     @nn.compact
-    def __call__(self, x, cos, sin, positions=None, deterministic: bool = True, block_tables=None, adapter_idx=None, row_map=None):
+    def __call__(self, x, cos, sin, positions=None, deterministic: bool = True, block_tables=None, adapter_idx=None, row_map=None, layer=None):
         cfg = self.config
         a = RMSNorm(eps=cfg.rms_norm_eps, dtype=self.dtype, name="input_layernorm")(x)
         a = LlamaAttention(
@@ -372,11 +410,38 @@ class LlamaDecoderLayer(nn.Module):
             self.decode, self.cache_size, self.page_size, self.num_pages,
             self.kv_dtype,
             name="self_attn"
-        )(a, cos, sin, positions, deterministic, block_tables, adapter_idx, row_map)
+        )(a, cos, sin, positions, deterministic, block_tables, adapter_idx, row_map, layer)
         x = x + a
         m = RMSNorm(eps=cfg.rms_norm_eps, dtype=self.dtype, name="post_attention_layernorm")(x)
         m = LlamaMLP(cfg, self.lora, self.dtype, name="mlp")(m, deterministic, adapter_idx)
         return x + m, None
+
+
+def scan_layers(block, layer_kwargs: dict, length: int, x: jax.Array, *broadcast) -> jax.Array:
+    """``length`` layers of ``block`` as one ``nn.scan`` named ``layers``:
+    parameters stacked on a leading layers axis, ``x`` the carry, ``broadcast``
+    the same for every layer.  The contiguous decode cache stacks like the
+    parameters.  The page pool does not: it is a *carry* of the loop, so each
+    layer sees the stacked leaves whole and is told its index (a scanned
+    ``arange``) to find its pages in them (:func:`attend_with_paged_cache`) —
+    stacked as a scanned input and output, every iteration would slice the
+    layer's pages out of the pool and write all of them back."""
+    paged = layer_kwargs["decode"] and layer_kwargs["page_size"] > 0
+    variable_axes = {"params": 0}
+    if layer_kwargs["decode"] and not paged:
+        variable_axes["cache"] = 0
+    scanned = nn.scan(
+        block,
+        variable_axes=variable_axes,
+        variable_carry="cache" if paged else False,
+        split_rngs={"params": True, "dropout": True},
+        in_axes=(nn.broadcast,) * len(broadcast) + ((0,) if paged else ()),
+        length=length,
+        metadata_params={nn.PARTITION_NAME: "layers"},
+    )
+    index = (jnp.arange(length, dtype=jnp.int32),) if paged else ()
+    x, _ = scanned(**layer_kwargs, name="layers")(x, *broadcast, *index)
+    return x
 
 
 def decoder_stack(
@@ -432,22 +497,9 @@ def decoder_stack(
         kv_dtype=getattr(module, "kv_dtype", "bf16"),
     )
     if module.scan_layers:
-        variable_axes = {"params": 0}
-        if decode:
-            # per-layer KV cache stacks on the same leading "layers" axis
-            # (contiguous per-slot buffers or the shared paged pool alike)
-            variable_axes["cache"] = 0
-        scanned = nn.scan(
-            block,
-            variable_axes=variable_axes,
-            split_rngs={"params": True, "dropout": True},
-            in_axes=(nn.broadcast,) * 7,
-            length=cfg.num_hidden_layers,
-            metadata_params={nn.PARTITION_NAME: "layers"},
-        )
-        x, _ = scanned(**layer_kwargs, name="layers")(
-            x, cos, sin, positions, deterministic, block_tables, adapter_idx,
-            row_map,
+        x = scan_layers(
+            block, layer_kwargs, cfg.num_hidden_layers,
+            x, cos, sin, positions, deterministic, block_tables, adapter_idx, row_map,
         )
     else:
         for i in range(cfg.num_hidden_layers):
@@ -531,6 +583,10 @@ class LlamaForCausalLM(nn.Module):
             name="lm_head",
         )(x)
         return logits.astype(self.logits_dtype)
+
+    def pool_shapes(self, specs, dtype) -> dict:
+        """The page pool the paged forward wants in its ``cache`` collection."""
+        return paged_pool_shapes(self, "self_attn", specs, dtype)
 
 
 class LlamaBackbone(nn.Module):

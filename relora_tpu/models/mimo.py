@@ -33,7 +33,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from relora_tpu.config.model import ModelConfig
-from relora_tpu.models.llama import LlamaMLP, RMSNorm, apply_rotary, rotary_tables
+from relora_tpu.models.llama import LlamaMLP, RMSNorm, apply_rotary, pool_must_be_given, rotary_tables
 from relora_tpu.models.step import PAGED, RING, CacheSpec, StepContext
 from relora_tpu.ops import moe
 from relora_tpu.ops.attention import cached_attention
@@ -42,10 +42,6 @@ from relora_tpu.ops.attention_dispatch import paged_attention
 
 def _normal(std: float):
     return nn.initializers.normal(stddev=std)
-
-
-def _pool_must_be_given(*_):
-    raise ValueError("the paged forward needs its page pool in the 'cache' collection (engine.init_pool)")
 
 
 class MimoAttention(nn.Module):
@@ -93,8 +89,8 @@ class MimoAttention(nn.Module):
             raise ValueError("this family is served from the paged engine only (page_size set)")
         else:
             table = ctx.tables[RING if self.window else PAGED]
-            ck = self.variable("cache", "k", _pool_must_be_given)
-            cv = self.variable("cache", "v", _pool_must_be_given)
+            ck = self.variable("cache", "k", pool_must_be_given)
+            cv = self.variable("cache", "v", pool_must_be_given)
             positions = jnp.broadcast_to(ctx.positions, (B, S)).astype(jnp.int32)
             # a K head is stored with zero features after it up to whole
             # 128-lane tiles (CacheSpec.k_pad); the queries get the same zeros

@@ -30,7 +30,9 @@ from relora_tpu.models.llama import (
     apply_rotary,
     attend_with_cache,
     attend_with_paged_cache,
+    paged_pool_shapes,
     rotary_tables,
+    scan_layers,
 )
 from relora_tpu.models.lora import LoRALinear
 from relora_tpu.ops.attention import dot_product_attention
@@ -77,7 +79,7 @@ class NeoXAttention(nn.Module):
     kv_dtype: str = "bf16"
 
     @nn.compact
-    def __call__(self, x, cos, sin, positions=None, deterministic: bool = True, block_tables=None, adapter_idx=None, row_map=None):
+    def __call__(self, x, cos, sin, positions=None, deterministic: bool = True, block_tables=None, adapter_idx=None, row_map=None, layer=None):
         cfg = self.config
         h, n, hd = cfg.hidden_size, cfg.num_attention_heads, cfg.head_dim
         rot = cfg.rotary_dim
@@ -101,7 +103,7 @@ class NeoXAttention(nn.Module):
         k = jnp.concatenate([apply_rotary(k[..., :rot], cos, sin), k[..., rot:]], axis=-1)
 
         if self.decode and self.page_size > 0:
-            out = attend_with_paged_cache(self, q, k, v, positions, block_tables, row_map)
+            out = attend_with_paged_cache(self, q, k, v, positions, block_tables, row_map, layer)
         elif self.decode:
             out = attend_with_cache(self, q, k, v, positions)
         else:
@@ -152,7 +154,7 @@ class NeoXLayer(nn.Module):
     kv_dtype: str = "bf16"
 
     @nn.compact
-    def __call__(self, x, cos, sin, positions=None, deterministic: bool = True, block_tables=None, adapter_idx=None, row_map=None):
+    def __call__(self, x, cos, sin, positions=None, deterministic: bool = True, block_tables=None, adapter_idx=None, row_map=None, layer=None):
         cfg = self.config
         attn_in = LayerNorm(eps=cfg.layer_norm_eps, dtype=self.dtype, name="input_layernorm")(x)
         attn_out = NeoXAttention(
@@ -160,7 +162,7 @@ class NeoXLayer(nn.Module):
             self.decode, self.cache_size, self.page_size, self.num_pages,
             self.kv_dtype,
             name="attention"
-        )(attn_in, cos, sin, positions, deterministic, block_tables, adapter_idx, row_map)
+        )(attn_in, cos, sin, positions, deterministic, block_tables, adapter_idx, row_map, layer)
         mlp_in = LayerNorm(
             eps=cfg.layer_norm_eps, dtype=self.dtype, name="post_attention_layernorm"
         )(x if cfg.use_parallel_residual else x + attn_out)
@@ -247,20 +249,9 @@ class GPTNeoXForCausalLM(nn.Module):
             num_pages=self.num_pages, kv_dtype=self.kv_dtype,
         )
         if self.scan_layers:
-            variable_axes = {"params": 0}
-            if self.decode:
-                # per-layer KV cache stacks on the same leading "layers" axis
-                variable_axes["cache"] = 0
-            scanned = nn.scan(
-                block,
-                variable_axes=variable_axes,
-                split_rngs={"params": True, "dropout": True},
-                in_axes=(nn.broadcast,) * 7,
-                length=cfg.num_hidden_layers,
-                metadata_params={nn.PARTITION_NAME: "layers"},
-            )
-            x, _ = scanned(**layer_kwargs, name="layers")(
-                x, cos, sin, positions, deterministic, block_tables, adapter_idx, row_map
+            x = scan_layers(
+                block, layer_kwargs, cfg.num_hidden_layers,
+                x, cos, sin, positions, deterministic, block_tables, adapter_idx, row_map,
             )
         else:
             for i in range(cfg.num_hidden_layers):
@@ -279,3 +270,7 @@ class GPTNeoXForCausalLM(nn.Module):
             name="embed_out",
         )(x)
         return logits.astype(self.logits_dtype)
+
+    def pool_shapes(self, specs, dtype) -> dict:
+        """The page pool the paged forward wants in its ``cache`` collection."""
+        return paged_pool_shapes(self, "attention", specs, dtype)

@@ -28,7 +28,13 @@ chunk straight into the pool through the request's block table (no insert
 copy), and ``decode_paged(pool, token, pos, block_tables)`` decodes every slot
 through its table.  Both compile exactly once: prompt length appears in no
 compiled shape, and cache HBM scales with ``num_pages``, not
-``max_batch × cache_size``.
+``max_batch × cache_size``.  The pool is made here (``init_pool``, from the
+model family's ``pool_shapes``), never by the model.  Under ``nn.scan`` the
+contiguous cache stacks on the leading layers axis as a scanned input and
+output of the layer loop; the pool's leaves have the same leading axis but
+ride the loop whole, as a carry, each layer addressing its own pages in
+place (models/llama.attend_with_paged_cache) — so a step aliases the donated
+pool to its result and holds neither a copy of it nor a layer's slice.
 
 Shardings: with a mesh, params shard per the model's logical annotations
 (parallel/mesh.py LOGICAL_RULES), cache buffers shard their batch axis over
@@ -901,18 +907,11 @@ class InferenceEngine:
         shape (num_pages, page_size, kv_heads, head_dim) (a leading layers
         axis when scanned).  Its byte size scales with ``num_pages``, not
         ``max_batch × cache_size`` — the paged memory win, visible in
-        ``memory_plans()``'s pytree breakdown.  A model with more than one
-        cache kind lays its leaves out from :meth:`cache_specs`; its window
-        layers' rings are sized for ``batch`` decode slots."""
+        ``memory_plans()``'s pytree breakdown.  The model family lays the
+        leaves out from :meth:`cache_specs` (its ``pool_shapes``); a window
+        layer's rings are sized for ``batch`` decode slots."""
         self._require_paged()
-        if hasattr(self.paged_model, "pool_shapes"):
-            return self.paged_model.pool_shapes(self.cache_specs(batch), self._pool_dtype)
-        ids = jnp.zeros((1, 1), jnp.int32)
-        bt = jnp.zeros((1, self.block_table_width), jnp.int32)
-        variables = jax.eval_shape(
-            lambda: self.paged_model.init(jax.random.PRNGKey(0), ids, block_tables=bt)
-        )
-        return variables["cache"]
+        return self.paged_model.pool_shapes(self.cache_specs(batch), self._pool_dtype)
 
     def pool_bytes(self, batch: int = 0, kind: Optional[str] = None) -> int:
         """Resident bytes of the shared K/V page pool — codes plus (int8)
@@ -1386,9 +1385,53 @@ class InferenceEngine:
             ],
         }
 
+    def paged_programs(self, batch: int) -> dict:
+        """Every jitted paged entry point this engine serves with, by name,
+        with the abstract arguments of its one compiled shape at ``batch``
+        rows: ``{name: (jitted, args)}``.  ``jitted.lower(*args).compile()``
+        is the program the serve loop runs — what :meth:`memory_plans` plans
+        and what the tests read (aliasing, what is copied) — with no device
+        work."""
+        self._require_paged()
+        i32 = jnp.int32
+        pool = self.pool_shapes(batch)
+
+        def ints(*shape):
+            return jax.ShapeDtypeStruct(shape, i32)
+
+        def tables(rows: int, width: int) -> dict:
+            kinds = {PAGED: ints(rows, width)}
+            if self._ring is not None:
+                kinds[RING] = ints(rows, self._ring.table_width)
+            return kinds
+
+        W, C = self.block_table_width, self.chunk_size
+        programs = {
+            "prefill_chunk": (
+                self._prefill_chunk, (self.params, ints(1, C), ints(1, C), pool, tables(1, W), ints(1))
+            ),
+            "decode_paged": (
+                self._decode_paged,
+                (self.params, pool, ints(batch, 1), ints(batch, 1), tables(batch, W), ints(batch)),
+            ),
+        }
+        if self.spec_k > 0:
+            S = self.spec_k + 1
+            programs["verify_paged"] = (
+                self._verify_paged,
+                (self.params, ints(batch, S), ints(batch, S), pool, tables(batch, W + 1), ints(batch)),
+            )
+        if self.token_budget:
+            Tb = self.token_budget
+            programs["step_paged"] = (
+                self._step_paged,
+                (self.params, ints(1, Tb), ints(1, Tb), pool, tables(batch + 1, W + 1), ints(Tb), ints(Tb)),
+            )
+        return programs
+
     def memory_plans(self, batch: int, *, prompt_buckets: Optional[Sequence[int]] = None) -> dict:
         """Static HBM plans for every jitted serving entry point (per-bucket
-        prefill, insert, decode at ``batch`` rows — or the chunk/decode pair
+        prefill, insert, decode at ``batch`` rows — or :meth:`paged_programs`
         when paged) plus the per-pytree breakdown of what stays resident
         (params, KV cache).  On a paged engine the ``kv_cache`` entry is the
         shared page pool, whose bytes scale with ``num_pages`` rather than
@@ -1400,60 +1443,13 @@ class InferenceEngine:
         numbers describe host buffers, but the relative breakdown holds."""
         i32 = jnp.int32
         if self.paged:
-            pool = self.pool_shapes(batch)
-
-            def tables(rows: int, width: int) -> dict:
-                kinds = {PAGED: jax.ShapeDtypeStruct((rows, width), i32)}
-                if self._ring is not None:
-                    kinds[RING] = jax.ShapeDtypeStruct((rows, self._ring.table_width), i32)
-                return kinds
-
             plans: dict = {
                 "pytree": obs_memory.pytree_breakdown(
-                    {"params": self.params, "kv_cache": pool}
+                    {"params": self.params, "kv_cache": self.pool_shapes(batch)}
                 )
             }
-            plans["prefill_chunk"] = obs_memory.plan_for(
-                self._prefill_chunk,
-                self.params,
-                jax.ShapeDtypeStruct((1, self.chunk_size), i32),
-                jax.ShapeDtypeStruct((1, self.chunk_size), i32),
-                pool,
-                tables(1, self.block_table_width),
-                jax.ShapeDtypeStruct((1,), i32),
-            )
-            plans["decode_paged"] = obs_memory.plan_for(
-                self._decode_paged,
-                self.params,
-                pool,
-                jax.ShapeDtypeStruct((batch, 1), i32),
-                jax.ShapeDtypeStruct((batch, 1), i32),
-                tables(batch, self.block_table_width),
-                jax.ShapeDtypeStruct((batch,), i32),
-            )
-            if self.spec_k > 0:
-                S = self.spec_k + 1
-                plans["verify_paged"] = obs_memory.plan_for(
-                    self._verify_paged,
-                    self.params,
-                    jax.ShapeDtypeStruct((batch, S), i32),
-                    jax.ShapeDtypeStruct((batch, S), i32),
-                    pool,
-                    tables(batch, self.block_table_width + 1),
-                    jax.ShapeDtypeStruct((batch,), i32),
-                )
-            if self.token_budget:
-                Tb = self.token_budget
-                plans["step_paged"] = obs_memory.plan_for(
-                    self._step_paged,
-                    self.params,
-                    jax.ShapeDtypeStruct((1, Tb), i32),
-                    jax.ShapeDtypeStruct((1, Tb), i32),
-                    pool,
-                    tables(batch + 1, self.block_table_width + 1),
-                    jax.ShapeDtypeStruct((Tb,), i32),
-                    jax.ShapeDtypeStruct((Tb,), i32),
-                )
+            for name, (jitted, args) in self.paged_programs(batch).items():
+                plans[name] = obs_memory.plan_for(jitted, *args)
             return plans
         if prompt_buckets is None:
             prompt_buckets = self.default_prompt_buckets()

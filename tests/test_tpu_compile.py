@@ -260,3 +260,65 @@ def test_mimo_decode_program_compiles_at_the_cells_shapes(one_chip, monkeypatch)
     assert plan.temp_size_in_bytes < 1e9  # no second copy of the weights, no copy of a pool
     live = plan.argument_size_in_bytes + plan.output_size_in_bytes - plan.alias_size_in_bytes + plan.temp_size_in_bytes
     assert live < 11e9
+
+
+def test_serving_cell_programs_hold_no_copy_of_the_pool(one_chip, monkeypatch):
+    """``serve.pythia_1.4b.chat_c32``'s two programs at the cell's shapes (32
+    rows, a 64-token chunk, 1,025 pages): the 3.22 GB pool rides the layer
+    loop as a carry, so each program aliases it to its result, slices no
+    layer's 134 MB out of it, writes none back and copies none of it — its
+    temporaries are the bf16 copy of the f32 kernels (2.42 GB) and little
+    else.  The parent of PR 35 planned 5.78 GB for each (PERF.md §6)."""
+    import json
+    import os
+
+    from test_paging import pool_sized_moves
+
+    from relora_tpu.config.model import load_model_config
+    from relora_tpu.models.step import PAGED, StepContext, cache_specs
+    from relora_tpu.serve.engine import _forward, build_decode_model
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the dispatchers take their TPU branch
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cfg = load_model_config(os.path.join(root, "benchmark", "configs", "pythia_1.4b.json"))
+    with open(os.path.join(root, "benchmark", "workloads", "serve.pythia_1.4b.chat_c32.json")) as f:
+        w = json.load(f)
+    B, ps, chunk, width = w["max_batch"], w["page_size"], w["chunk_size"], w["cache_size"] // w["page_size"]
+    model = build_decode_model(
+        cfg, cache_size=w["cache_size"], dtype=jnp.bfloat16, page_size=ps, num_pages=w["num_pages"]
+    )
+    specs = cache_specs(
+        cfg, page_size=ps, num_pages=w["num_pages"], cache_size=w["cache_size"], chunk_size=chunk,
+        max_batch=B, itemsize=2,
+    )
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(lambda s: one_chip(s.shape, s.dtype), tree)
+
+    pool = on_chip(model.pool_shapes(specs, jnp.bfloat16))
+    pool_bytes = sum(c.pool_bytes for c in specs)
+    assert 3.2e9 < pool_bytes < 3.3e9
+    ids = jnp.zeros((1, 8), jnp.int32)
+    params = on_chip(jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), ids, block_tables=ids)["params"]))
+    kernels_bf16 = sum(
+        x.size * 2 for path, x in jax.tree_util.tree_leaves_with_path(params) if "kernel" in jax.tree_util.keystr(path)
+    )
+
+    def step(p, pool, tokens, pos, table):
+        ctx = StepContext(positions=pos, tables={PAGED: table})
+        logits, pool, _ = _forward(model, p, pool, tokens, ctx)
+        return logits, pool
+
+    def ints(*shape):
+        return one_chip(shape, jnp.int32)
+
+    for rows, tokens in ((B, 1), (1, chunk)):  # decode_paged, prefill_chunk
+        compiled = jax.jit(step, donate_argnums=(1,)).lower(
+            params, pool, ints(rows, tokens), ints(rows, tokens), ints(rows, width)
+        ).compile()
+        text = compiled.as_text()
+        assert ("paged_decode_attention" in text) == (tokens == 1)
+        assert not pool_sized_moves(text, pool["layers"]["attention"]["k"].shape)
+        plan = compiled.memory_analysis()
+        assert plan.alias_size_in_bytes == pool_bytes
+        assert plan.temp_size_in_bytes < kernels_bf16 + 0.1e9 < pool_bytes
