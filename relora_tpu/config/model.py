@@ -20,7 +20,7 @@ from typing import Optional, Tuple
 
 @dataclass(frozen=True)
 class ModelConfig:
-    """Architecture hyperparameters for the three model families.
+    """Architecture hyperparameters for the four model families.
 
     ``family`` is "llama" (RMSNorm, SwiGLU, no biases, separate q/k/v) or
     "neox" (LayerNorm, GELU MLP, biases, fused QKV, parallel residual,
@@ -28,7 +28,10 @@ class ModelConfig:
     (modeling_llama.py, modeling_pythia.py) — or "mimo" (HF ``mimo_v2``:
     sliding-window layers with a sink bias beside global layers, K heads
     wider than V heads, a dense FFN in the leading layers and sigmoid-routed
-    experts after them; served only, see models/mimo.py).
+    experts after them; served only, see models/mimo.py) or "afmoe" (HF
+    ``afmoe``, Arcee Trinity: gated attention with per-head q/k norms, rotary
+    window layers beside position-free global ones, four norms a layer, a
+    shared expert beside the routed ones; served only, see models/afmoe.py).
     """
 
     family: str = "llama"
@@ -53,7 +56,7 @@ class ModelConfig:
     tie_word_embeddings: bool = False
     bos_token_id: int = 0
     eos_token_id: int = 1
-    # -- mimo: per-layer kinds, two head geometries, the expert fields --------
+    # -- mimo, afmoe: per-layer kinds, two head geometries, the expert fields --
     # 1 = sliding-window attention, 0 = global (HF hybrid_layer_pattern)
     layer_window: Tuple[int, ...] = ()
     # 1 = routed experts, 0 = dense FFN (HF moe_layer_freq)
@@ -76,6 +79,13 @@ class ModelConfig:
     scoring_func: str = "sigmoid"
     norm_topk_prob: bool = True
     routed_scaling_factor: float = 1.0
+    # -- afmoe: what no other family has ---------------------------------------
+    attn_gate: bool = False  # o *= sigmoid(x Wg) before the output projection
+    qk_norm: bool = False  # RMSNorm over each q and k head's features
+    global_rotary: bool = True  # False: global layers take no positional encoding
+    sandwich_norm: bool = False  # a norm after attention and after the FFN too
+    embed_scale: float = 1.0  # multiplies the embedding (mup_enabled: sqrt(hidden))
+    n_shared_experts: int = 0  # SwiGLU experts every token takes, unrouted
 
     @property
     def head_dim(self) -> int:
@@ -92,16 +102,20 @@ class ModelConfig:
     def num_params(self, include_embeddings: bool = True) -> int:
         """Approximate parameter count (dense, untied)."""
         h, i, L, v = self.hidden_size, self.intermediate_size, self.num_hidden_layers, self.vocab_size
-        if self.family == "mimo":
+        if self.family in ("mimo", "afmoe"):
             # what this chip holds: its share of the experts, every other leaf whole
             q, o = self.num_attention_heads * self.qk_head_dim, self.num_attention_heads * self.v_head_dim
             n = h
             for window, moe in zip(self.layer_window, self.layer_moe):
                 n_kv = self.window_kv_heads if window else self.kv_heads
-                n += h * (q + n_kv * (self.qk_head_dim + self.v_head_dim)) + o * h + 2 * h
+                n += h * (q + n_kv * (self.qk_head_dim + self.v_head_dim)) + o * h
+                n += (4 if self.sandwich_norm else 2) * h
+                n += h * o if self.attn_gate else 0
+                n += 2 * self.qk_head_dim if self.qk_norm else 0
                 n += self.num_attention_heads if (self.window_sink if window else self.global_sink) else 0
                 if moe:
-                    n += (h + 1) * self.n_routed_experts + self.experts_held * 3 * h * self.moe_intermediate_size
+                    n += (h + 1) * self.n_routed_experts
+                    n += (self.experts_held + self.n_shared_experts) * 3 * h * self.moe_intermediate_size
                 else:
                     n += 3 * h * i
             return n + (2 * v * h if include_embeddings else 0)
@@ -138,6 +152,8 @@ class ModelConfig:
             )
         if model_type == "mimo_v2":
             return cls._from_mimo_json(d)
+        if model_type == "afmoe":
+            return cls._from_afmoe_json(d)
         return cls(
             family=MODEL_TYPES[model_type],
             vocab_size=d["vocab_size"],
@@ -181,12 +197,7 @@ class ModelConfig:
         if d.get("scoring_func", "sigmoid") != "sigmoid":
             raise ValueError(f"scoring_func {d['scoring_func']!r} is not supported (sigmoid only)")
         n_experts = d["n_routed_experts"]
-        held = d.get("experts_held", n_experts)
-        offset = d.get("expert_offset", 0)
-        if not 0 < held <= n_experts or not 0 <= offset <= n_experts - held:
-            raise ValueError(
-                f"experts {offset} .. {offset + held - 1} are not among the {n_experts} routed"
-            )
+        held, offset = _experts_share(d, n_experts)
         return cls(
             family="mimo",
             vocab_size=d["vocab_size"],
@@ -222,10 +233,82 @@ class ModelConfig:
             routed_scaling_factor=d.get("routed_scaling_factor") or 1.0,
         )
 
+    @classmethod
+    def _from_afmoe_json(cls, d: dict) -> "ModelConfig":
+        """HF ``afmoe`` keys (Arcee Trinity), and this repo's ``experts_held``
+        / ``expert_offset``.  ``layer_types`` says which layers slide,
+        ``num_dense_layers`` how many leading ones keep a dense FFN."""
+        L = d["num_hidden_layers"]
+        kinds = {"sliding_attention": 1, "full_attention": 0}
+        odd = sorted(set(d["layer_types"]) - set(kinds))
+        if odd or len(d["layer_types"]) != L:
+            raise ValueError(
+                f"layer_types must name num_hidden_layers = {L} layers, each one of {sorted(kinds)}"
+                + (f" (got {odd})" if odd else f" (got {len(d['layer_types'])})")
+            )
+        if d.get("n_group", 1) != 1 or d.get("topk_group", 1) != 1:
+            raise ValueError("group-limited routing (n_group / topk_group > 1) is not supported")
+        if d.get("score_func", "sigmoid") != "sigmoid":
+            raise ValueError(f"score_func {d['score_func']!r} is not supported (sigmoid only)")
+        if d.get("rope_scaling"):
+            raise ValueError("rope_scaling is not supported for afmoe")
+        dense = d["num_dense_layers"]
+        if not 0 <= dense <= L:
+            raise ValueError(f"num_dense_layers = {dense} is not within num_hidden_layers = {L}")
+        n_experts = d["num_experts"]
+        held, offset = _experts_share(d, n_experts)
+        head = d.get("head_dim") or d["hidden_size"] // d["num_attention_heads"]
+        return cls(
+            family="afmoe",
+            vocab_size=d["vocab_size"],
+            hidden_size=d["hidden_size"],
+            intermediate_size=d["intermediate_size"],
+            num_hidden_layers=L,
+            num_attention_heads=d["num_attention_heads"],
+            num_key_value_heads=d["num_key_value_heads"],
+            max_sequence_length=d.get("max_position_embeddings", 2048),
+            rms_norm_eps=d.get("rms_norm_eps", 1e-5),
+            initializer_range=d.get("initializer_range", 0.02),
+            rotary_emb_base=d.get("rope_theta", 10000.0),
+            window_rotary_base=d.get("rope_theta", 10000.0),
+            tie_word_embeddings=d.get("tie_word_embeddings", False),
+            bos_token_id=d.get("bos_token_id", 0),
+            eos_token_id=d.get("eos_token_id", 1),
+            layer_window=tuple(kinds[k] for k in d["layer_types"]),
+            layer_moe=tuple(int(i >= dense) for i in range(L)),
+            qk_head_dim=head,
+            v_head_dim=head,
+            window_kv_heads=d["num_key_value_heads"],
+            sliding_window=d["sliding_window"],
+            moe_intermediate_size=d["moe_intermediate_size"],
+            n_routed_experts=n_experts,
+            experts_held=held,
+            expert_offset=offset,
+            num_experts_per_tok=d["num_experts_per_tok"],
+            norm_topk_prob=d.get("route_norm", True),
+            routed_scaling_factor=d.get("route_scale") or 1.0,
+            attn_gate=True,
+            qk_norm=True,
+            global_rotary=False,
+            sandwich_norm=True,
+            embed_scale=float(d["hidden_size"]) ** 0.5 if d.get("mup_enabled") else 1.0,
+            n_shared_experts=d.get("num_shared_experts", 0),
+        )
 
-#: HF ``model_type`` -> family; any other is an error (an absent key is Llama,
-#: the reference's own configs/*.json carry none)
-MODEL_TYPES = {"llama": "llama", "gpt_neox": "neox", "mimo_v2": "mimo"}
+
+def _experts_share(d: dict, n_experts: int) -> Tuple[int, int]:
+    """``experts_held`` and ``expert_offset`` of a configuration file: the
+    chip's share of the ``n_experts`` the router scores (absent = all)."""
+    held = d.get("experts_held", n_experts)
+    offset = d.get("expert_offset", 0)
+    if not 0 < held <= n_experts or not 0 <= offset <= n_experts - held:
+        raise ValueError(f"experts {offset} .. {offset + held - 1} are not among the {n_experts} routed")
+    return held, offset
+
+
+#: HF ``model_type`` -> family, four of them; any other is an error (an absent
+#: key is Llama, the reference's own configs/*.json carry none)
+MODEL_TYPES = {"llama": "llama", "gpt_neox": "neox", "mimo_v2": "mimo", "afmoe": "afmoe"}
 
 
 def _llama(h: int, i: int, L: int, heads: int, seq: int = 1024, vocab: int = 32100) -> ModelConfig:

@@ -97,25 +97,25 @@ def cache_specs(
     cfg: ModelConfig, *, page_size: int, num_pages: int, cache_size: int,
     chunk_size: int, max_batch: int, itemsize: int,
 ) -> Tuple[CacheSpec, ...]:
-    """The cache kinds of a configuration's layers.  ``max_batch`` sizes the
-    rings (0: the family has none to size yet)."""
+    """The cache kinds of a configuration's layers, read off its layer kinds
+    (``layer_window``) and head sizes.  ``max_batch`` sizes the rings (0:
+    there are no slots to size them by yet)."""
     common = dict(itemsize=itemsize, page_size=page_size)
     width = cache_size // page_size
-    if cfg.family != "mimo":
-        return (
-            CacheSpec(PAGED, cfg.num_hidden_layers, cfg.kv_heads, cfg.head_dim, cfg.head_dim,
-                      num_pages=num_pages, table_width=width, **common),
-        )
     n_ring = sum(cfg.layer_window)
-    common["k_pad"] = -cfg.qk_head_dim % 128
+    d_k, d_v = cfg.head_dim, cfg.v_head_dim or cfg.head_dim
+    if cfg.qk_head_dim:
+        # a configuration that states its K and V head sizes keeps each layer's
+        # pools apart, K rows padded to whole lane tiles (CacheSpec.k_pad)
+        common["k_pad"] = -d_k % 128
     specs = [
-        CacheSpec(PAGED, cfg.num_hidden_layers - n_ring, cfg.kv_heads, cfg.qk_head_dim, cfg.v_head_dim,
+        CacheSpec(PAGED, cfg.num_hidden_layers - n_ring, cfg.kv_heads, d_k, d_v,
                   num_pages=num_pages, table_width=width, **common)
     ]
     if n_ring:
         r = ring_pages(cfg.sliding_window, chunk_size, page_size)
         specs.append(
-            CacheSpec(RING, n_ring, cfg.window_kv_heads, cfg.qk_head_dim, cfg.v_head_dim,
+            CacheSpec(RING, n_ring, cfg.window_kv_heads, d_k, d_v,
                       num_pages=1 + max_batch * r, table_width=r, window=cfg.sliding_window, **common)
         )
     return tuple(specs)

@@ -739,7 +739,9 @@ def paged_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, N * T, Hv), q.dtype),
         interpret=interpret,
-        name="paged_decode_attention",
+        # a window layer's launches carry a name of their own, so that a trace
+        # tells the two cache kinds' launches apart (the prefix is shared)
+        name="paged_decode_attention" if window is None else "paged_decode_attention_window",
     )(bt, pos, low, span, *operands)
     return out.reshape(B, N, T, Hv).transpose(0, 2, 1, 3)
 

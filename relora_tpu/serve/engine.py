@@ -228,12 +228,13 @@ def build_decode_model(
         from relora_tpu.models.pythia import GPTNeoXForCausalLM
 
         return GPTNeoXForCausalLM(**kwargs)
-    if model_cfg.family == "mimo":
-        from relora_tpu.models.mimo import MimoForCausalLM
-
-        return MimoForCausalLM(
-            model_cfg, dtype=dtype, param_dtype=dtype, decode=True, page_size=page_size
-        )
+    if model_cfg.family in ("mimo", "afmoe"):
+        # the families with layers of unlike kinds: paged only, weights held as handed
+        if model_cfg.family == "mimo":
+            from relora_tpu.models.mimo import MimoForCausalLM as Model
+        else:
+            from relora_tpu.models.afmoe import AfmoeForCausalLM as Model
+        return Model(model_cfg, dtype=dtype, param_dtype=dtype, decode=True, page_size=page_size)
     raise ValueError(f"Unknown model family {model_cfg.family!r}")
 
 

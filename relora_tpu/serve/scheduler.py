@@ -893,6 +893,9 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
         # table entries the last decode had to walk (the decode_step span's
         # live_pages; the serve/decode_live_page_share gauge)
         self._decode_live_pages = 0
+        # decoding rows at or past the window in the last decode (the
+        # decode_step span's rows_past_window; the serve/ring_wrapped_rows gauge)
+        self._ring_wrapped_rows = 0
         # disaggregated serving (docs/serving.md): a prefill-role scheduler
         # hands each finished prompt's page run to ``migration_sink`` (set by
         # the server; runs on the model thread, must not block) and parks the
@@ -1686,10 +1689,14 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
         # two cache kinds: a window layer reads its last ``window`` tokens,
         # however long the request
         by_kind = [sum(c.read_bytes(p) for p in positions) for c in (self._paged_spec, self._ring_spec)]
+        # rows whose ring has wrapped: their window layers' walk is the whole
+        # window, modulo the ring, whatever the position
+        self._ring_wrapped_rows = sum(p >= self._ring_spec.window for p in positions)
         return {
             "kv_bytes": sum(by_kind),
             "kv_bytes_global": by_kind[0],
             "kv_bytes_window": by_kind[1],
+            "rows_past_window": self._ring_wrapped_rows,
             "live_pages": self._decode_live_pages,
         }
 
@@ -1788,6 +1795,7 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
                 for kind, nbytes in self._kv_cache_bytes_by_kind.items():
                     self.obs_registry.set_gauge(f"kv_cache_bytes_{kind}", nbytes)
                 self.obs_registry.set_gauge("window_ring_pages", self._ring_spec.table_width)
+                self.obs_registry.set_gauge("ring_wrapped_rows", self._ring_wrapped_rows)
             if self._moe_fanout:
                 for name in ("moe_assignments_total", "moe_assignments_local_total", "moe_experts_hit_total"):
                     self.obs_registry.inc(name, by=0)
@@ -1838,6 +1846,7 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
                         "serve/kv_cache_bytes_paged": self._kv_cache_bytes_by_kind[PAGED],
                         "serve/kv_cache_bytes_ring": self._kv_cache_bytes_by_kind[RING],
                         "serve/window_ring_pages": self._ring_spec.table_width,
+                        "serve/ring_wrapped_rows": self._ring_wrapped_rows,
                     }
                     if self._ring_spec is not None
                     else {}

@@ -253,4 +253,4 @@ def test_every_config_file_in_the_tree_parses():
         for name in sorted(os.listdir(os.path.join(root, sub))):
             if name.endswith(".json"):
                 found.append(load_model_config(os.path.join(root, sub, name)).family)
-    assert sorted(set(found)) == ["mimo", "neox"] and len(found) >= 5
+    assert sorted(set(found)) == ["afmoe", "mimo", "neox"] and len(found) >= 6

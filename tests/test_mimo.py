@@ -21,7 +21,8 @@ from benchmark import weights_mimo
 from benchmark.reference import mimo as reference
 from relora_tpu.config.model import ModelConfig
 from relora_tpu.core.relora import LoraSpec
-from relora_tpu.models.mimo import MimoExperts, MimoForCausalLM
+from relora_tpu.models.hybrid import RoutedExperts
+from relora_tpu.models.mimo import MimoForCausalLM
 from relora_tpu.models.step import PAGED, RING
 from relora_tpu.obs.metrics import MetricsRegistry
 from relora_tpu.serve.engine import InferenceEngine
@@ -118,7 +119,7 @@ def test_chunked_prefill_then_decode_is_the_reference(cfg, params):
 def _experts_layer(cfg, p, x, offset, held):
     share = dataclasses.replace(cfg, experts_held=held, expert_offset=offset)
     mine = {**p, "gate_up": p["gate_up"][offset : offset + held], "down": p["down"][offset : offset + held]}
-    module = MimoExperts(share, dtype=jnp.float32, param_dtype=jnp.float32)
+    module = RoutedExperts(share, dtype=jnp.float32, param_dtype=jnp.float32)
     y, state = module.apply({"params": mine}, x[None], mutable=["stats"])
     return y[0], np.asarray(state["stats"]["moe"])
 

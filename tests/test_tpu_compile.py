@@ -322,3 +322,74 @@ def test_serving_cell_programs_hold_no_copy_of_the_pool(one_chip, monkeypatch):
         plan = compiled.memory_analysis()
         assert plan.alias_size_in_bytes == pool_bytes
         assert plan.temp_size_in_bytes < kernels_bf16 + 0.1e9 < pool_bytes
+
+
+@pytest.mark.parametrize("program", ["decode_paged", "prefill_chunk"])
+def test_trinity_programs_compile_at_the_cells_shapes(one_chip, monkeypatch, program):
+    """``serve.trinity_large_preview.agent_c32``'s two programs: 32 rows or a
+    256-token chunk, published widths, five layers (a dense sliding one,
+    three routed sliding ones, a routed full one), a 273-page ring a slot in
+    the window layers beside a 22,529-page pool in the full one.  The decode's
+    window launches carry their own name; the chunk's arm gathers a row's
+    whole table — 11,264 keys in the full layer, a ``(1, 48, 256, 11264)`` f32
+    score matrix of 553 MB — and must fit beside 8.64 GB of bf16 weights held
+    once and 3.77 GB of cache: the plan (arguments + temporaries) stays under
+    the chip's 15.75 GB."""
+    import json
+    import os
+
+    from benchmark import weights_afmoe
+    from relora_tpu.config.model import load_model_config
+    from relora_tpu.models import step as model_step
+    from relora_tpu.models.afmoe import AfmoeForCausalLM
+    from relora_tpu.models.step import StepContext
+    from relora_tpu.serve.engine import _forward
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the dispatchers take their TPU branch
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    path = os.path.join(root, "benchmark", "configs", "trinity_large_preview.json")
+    with open(path) as f:
+        raw = json.load(f)
+    with open(os.path.join(root, "benchmark", "workloads", "serve.trinity_large_preview.agent_c32.json")) as f:
+        w = json.load(f)
+    cfg = load_model_config(path)
+    B, ps, chunk = w["max_batch"], w["page_size"], w["chunk_size"]
+    model = AfmoeForCausalLM(cfg, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, decode=True, page_size=ps)
+    specs = model_step.cache_specs(
+        cfg, page_size=ps, num_pages=w["num_pages"], cache_size=w["cache_size"], chunk_size=chunk,
+        max_batch=B, itemsize=2,
+    )
+    assert [(c.kind, c.layers, c.table_width) for c in specs] == [("paged", 1, 704), ("ring", 4, 273)]
+    pool = jax.tree_util.tree_map(lambda s: one_chip(s.shape, s.dtype), model.pool_shapes(specs, jnp.bfloat16))
+
+    def leaves(shapes, name=""):
+        if isinstance(shapes, dict):
+            return {k: leaves(v, k) for k, v in shapes.items()}
+        return one_chip(shapes, jnp.float32 if name in ("scale", "select_bias") else jnp.bfloat16)
+
+    params = leaves(weights_afmoe.param_shapes(raw))
+    rows, tokens = (B, 1) if program == "decode_paged" else (1, chunk)
+    tables = {c.kind: one_chip((rows, c.table_width), jnp.int32) for c in specs}
+
+    def step(p, pool, tok, pos, tables):
+        logits, pool, counts = _forward(model, p, pool, tok, StepContext(positions=pos, tables=tables))
+        return (logits[:, -1, :] if tokens == 1 else logits), pool, counts
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, pool, one_chip((rows, tokens), jnp.int32), one_chip((rows, tokens), jnp.int32), tables
+    ).compile()
+    text = compiled.as_text()
+    assert "%ragged-dot-none" in text
+    if tokens == 1:
+        assert text.count("paged_decode_attention_window") >= 4
+        assert len(text.split("paged_decode_attention")) > len(text.split("paged_decode_attention_window"))
+    else:
+        assert "paged_decode_attention" not in text
+    plan = compiled.memory_analysis()
+    held = sum(int(jnp.prod(jnp.asarray(x.shape))) * x.dtype.itemsize for x in jax.tree_util.tree_leaves(params))
+    assert 8.6e9 < held < 8.7e9
+    assert plan.alias_size_in_bytes >= sum(c.pool_bytes for c in specs)  # the pools are written in place
+    live = plan.argument_size_in_bytes + plan.output_size_in_bytes - plan.alias_size_in_bytes + plan.temp_size_in_bytes
+    assert live < 15.75e9  # 12.42 GB (decode) and 13.01 GB (chunk) at PR 36
+    # no second copy of the weights or of a pool; the chunk's are its score matrices (0.58 GB)
+    assert plan.temp_size_in_bytes < (0.1e9 if tokens == 1 else 1e9)
