@@ -98,7 +98,7 @@ class AfmoeLayer(nn.Module):
         x = x + norm("post_attention_layernorm")(a)
         m = norm("pre_mlp_layernorm")(x)
         if not self.routed:
-            f = LlamaMLP(cfg, None, self.dtype, name="mlp")(m)
+            f = LlamaMLP(cfg, None, self.dtype, self.param_dtype, name="mlp")(m)
         else:
             f = RoutedExperts(cfg, self.dtype, self.param_dtype, name="experts")(m)
             if cfg.n_shared_experts:
@@ -108,7 +108,7 @@ class AfmoeLayer(nn.Module):
                     cfg, intermediate_size=cfg.n_shared_experts * cfg.moe_intermediate_size
                 )
                 with jax.named_scope("shared_expert"):
-                    f = f + LlamaMLP(shared, None, self.dtype, name="shared_expert")(m)
+                    f = f + LlamaMLP(shared, None, self.dtype, self.param_dtype, name="shared_expert")(m)
         return x + norm("post_mlp_layernorm")(f)
 
 

@@ -315,6 +315,7 @@ class LlamaAttention(nn.Module):
     # "bf16" stores pool pages at the compute dtype (unquantized); "int8"
     # stores codes + per-(page, kv_head) scales — see attend_with_paged_cache
     kv_dtype: str = "bf16"
+    param_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
     def __call__(
@@ -333,7 +334,7 @@ class LlamaAttention(nn.Module):
         h, n, hd = cfg.hidden_size, cfg.num_attention_heads, cfg.head_dim
         n_kv = cfg.kv_heads
         dense = functools.partial(
-            LoRALinear, lora=self.lora, dtype=self.dtype, use_bias=False
+            LoRALinear, lora=self.lora, dtype=self.dtype, param_dtype=self.param_dtype, use_bias=False
         )
         q = dense(h, kernel_axes=("embed", "qkv"), name="q_proj")(x, deterministic, adapter_idx)
         k = dense(n_kv * hd, kernel_axes=("embed", "kv"), name="k_proj")(x, deterministic, adapter_idx)
@@ -366,6 +367,7 @@ class LlamaMLP(nn.Module):
     config: ModelConfig
     lora: Optional[LoraSpec] = None
     dtype: jnp.dtype = jnp.bfloat16
+    param_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
     def __call__(
@@ -374,7 +376,7 @@ class LlamaMLP(nn.Module):
     ) -> jax.Array:
         cfg = self.config
         dense = functools.partial(
-            LoRALinear, lora=self.lora, dtype=self.dtype, use_bias=False
+            LoRALinear, lora=self.lora, dtype=self.dtype, param_dtype=self.param_dtype, use_bias=False
         )
         gate = dense(cfg.intermediate_size, kernel_axes=("embed", "mlp"), name="gate_proj")(x, deterministic, adapter_idx)
         up = dense(cfg.intermediate_size, kernel_axes=("embed", "mlp"), name="up_proj")(x, deterministic, adapter_idx)
@@ -400,6 +402,7 @@ class LlamaDecoderLayer(nn.Module):
     page_size: int = 0
     num_pages: int = 0
     kv_dtype: str = "bf16"
+    param_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
     def __call__(self, x, cos, sin, positions=None, deterministic: bool = True, block_tables=None, adapter_idx=None, row_map=None, layer=None):
@@ -408,12 +411,12 @@ class LlamaDecoderLayer(nn.Module):
         a = LlamaAttention(
             cfg, self.lora, self.dtype, self.attention_impl,
             self.decode, self.cache_size, self.page_size, self.num_pages,
-            self.kv_dtype,
+            self.kv_dtype, self.param_dtype,
             name="self_attn"
         )(a, cos, sin, positions, deterministic, block_tables, adapter_idx, row_map, layer)
         x = x + a
         m = RMSNorm(eps=cfg.rms_norm_eps, dtype=self.dtype, name="post_attention_layernorm")(x)
-        m = LlamaMLP(cfg, self.lora, self.dtype, name="mlp")(m, deterministic, adapter_idx)
+        m = LlamaMLP(cfg, self.lora, self.dtype, self.param_dtype, name="mlp")(m, deterministic, adapter_idx)
         return x + m, None
 
 
@@ -495,6 +498,7 @@ def decoder_stack(
         page_size=getattr(module, "page_size", 0),
         num_pages=getattr(module, "num_pages", 0),
         kv_dtype=getattr(module, "kv_dtype", "bf16"),
+        param_dtype=getattr(module, "param_dtype", jnp.float32),
     )
     if module.scan_layers:
         x = scan_layers(
@@ -518,7 +522,7 @@ def token_embed(module: nn.Module, input_ids: jax.Array) -> jax.Array:
         embedding_init=nn.with_logical_partitioning(
             nn.initializers.normal(stddev=cfg.initializer_range), ("vocab", "embed")
         ),
-        param_dtype=jnp.float32,
+        param_dtype=getattr(module, "param_dtype", jnp.float32),
         dtype=module.dtype,
         name="embed_tokens",
     )(input_ids)
@@ -553,6 +557,10 @@ class LlamaForCausalLM(nn.Module):
     page_size: int = 0
     num_pages: int = 0
     kv_dtype: str = "bf16"
+    # the type the matrices, embedding and LoRA factors are declared in: f32
+    # for training; the serving engine gives the compute dtype, and holds the
+    # tree so.  RMSNorm scales are f32 either way.
+    param_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
     def __call__(
@@ -579,6 +587,7 @@ class LlamaForCausalLM(nn.Module):
             self.config.vocab_size,
             lora=None,  # lm_head is never LoRA-wrapped (target-module policy)
             dtype=self.dtype,
+            param_dtype=self.param_dtype,
             kernel_axes=("embed", "vocab"),
             name="lm_head",
         )(x)

@@ -409,7 +409,7 @@ class LoRALinear(nn.Module):
         )
         if spec.trainable_scaling:
             lora_s = self.param(
-                "lora_s", nn.initializers.ones_init(), (1,), self.param_dtype
+                "lora_s", nn.initializers.ones_init(), (1,), jnp.float32
             )
             # parity: trainable scaling passes through tanh (relora.py:263-267)
             scale = jnp.tanh(lora_s.astype(self.dtype))

@@ -99,7 +99,7 @@ class MimoLayer(nn.Module):
         m = RMSNorm(eps=cfg.rms_norm_eps, dtype=self.dtype, name="post_attention_layernorm")(x)
         if self.routed:
             return x + RoutedExperts(cfg, self.dtype, self.param_dtype, name="experts")(m)
-        return x + LlamaMLP(cfg, None, self.dtype, name="mlp")(m)
+        return x + LlamaMLP(cfg, None, self.dtype, self.param_dtype, name="mlp")(m)
 
 
 class MimoForCausalLM(nn.Module):

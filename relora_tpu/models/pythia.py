@@ -77,6 +77,7 @@ class NeoXAttention(nn.Module):
     page_size: int = 0
     num_pages: int = 0
     kv_dtype: str = "bf16"
+    param_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
     def __call__(self, x, cos, sin, positions=None, deterministic: bool = True, block_tables=None, adapter_idx=None, row_map=None, layer=None):
@@ -89,6 +90,7 @@ class NeoXAttention(nn.Module):
             use_bias=True,
             lora=self.lora,
             dtype=self.dtype,
+            param_dtype=self.param_dtype,
             kernel_axes=("embed", "qkv"),
             name="query_key_value",
         )(x, deterministic, adapter_idx)
@@ -114,6 +116,7 @@ class NeoXAttention(nn.Module):
             use_bias=True,
             lora=self.lora,
             dtype=self.dtype,
+            param_dtype=self.param_dtype,
             kernel_axes=("qkv", "embed"),
             name="dense",
         )(out, deterministic, adapter_idx)
@@ -123,12 +126,13 @@ class NeoXMLP(nn.Module):
     config: ModelConfig
     lora: Optional[LoraSpec] = None
     dtype: jnp.dtype = jnp.bfloat16
+    param_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
     def __call__(self, x, deterministic: bool = True, adapter_idx=None):
         cfg = self.config
         dense = functools.partial(
-            LoRALinear, use_bias=True, lora=self.lora, dtype=self.dtype
+            LoRALinear, use_bias=True, lora=self.lora, dtype=self.dtype, param_dtype=self.param_dtype
         )
         y = dense(cfg.intermediate_size, kernel_axes=("embed", "mlp"), name="dense_h_to_4h")(
             x, deterministic, adapter_idx
@@ -152,6 +156,7 @@ class NeoXLayer(nn.Module):
     page_size: int = 0
     num_pages: int = 0
     kv_dtype: str = "bf16"
+    param_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
     def __call__(self, x, cos, sin, positions=None, deterministic: bool = True, block_tables=None, adapter_idx=None, row_map=None, layer=None):
@@ -160,13 +165,13 @@ class NeoXLayer(nn.Module):
         attn_out = NeoXAttention(
             cfg, self.lora, self.dtype, self.attention_impl,
             self.decode, self.cache_size, self.page_size, self.num_pages,
-            self.kv_dtype,
+            self.kv_dtype, self.param_dtype,
             name="attention"
         )(attn_in, cos, sin, positions, deterministic, block_tables, adapter_idx, row_map, layer)
         mlp_in = LayerNorm(
             eps=cfg.layer_norm_eps, dtype=self.dtype, name="post_attention_layernorm"
         )(x if cfg.use_parallel_residual else x + attn_out)
-        mlp_out = NeoXMLP(cfg, self.lora, self.dtype, name="mlp")(mlp_in, deterministic, adapter_idx)
+        mlp_out = NeoXMLP(cfg, self.lora, self.dtype, self.param_dtype, name="mlp")(mlp_in, deterministic, adapter_idx)
         if cfg.use_parallel_residual:
             # x + attn(ln1(x)) + mlp(ln2(x))
             return x + attn_out + mlp_out, None
@@ -194,6 +199,10 @@ class GPTNeoXForCausalLM(nn.Module):
     page_size: int = 0
     num_pages: int = 0
     kv_dtype: str = "bf16"
+    # the type the matrices, linear biases, embedding and LoRA factors are
+    # declared in: f32 for training; the serving engine gives the compute
+    # dtype, and holds the tree so.  LayerNorm leaves are f32 either way.
+    param_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
     def __call__(
@@ -213,7 +222,7 @@ class GPTNeoXForCausalLM(nn.Module):
             embedding_init=nn.with_logical_partitioning(
                 nn.initializers.normal(stddev=cfg.initializer_range), ("vocab", "embed")
             ),
-            param_dtype=jnp.float32,
+            param_dtype=self.param_dtype,
             dtype=self.dtype,
             name="embed_in",
         )(input_ids)
@@ -246,7 +255,7 @@ class GPTNeoXForCausalLM(nn.Module):
             config=cfg, lora=self.lora, dtype=self.dtype,
             attention_impl=self.attention_impl, decode=self.decode,
             cache_size=self.cache_size, page_size=self.page_size,
-            num_pages=self.num_pages, kv_dtype=self.kv_dtype,
+            num_pages=self.num_pages, kv_dtype=self.kv_dtype, param_dtype=self.param_dtype,
         )
         if self.scan_layers:
             x = scan_layers(
@@ -266,6 +275,7 @@ class GPTNeoXForCausalLM(nn.Module):
             cfg.vocab_size,
             lora=None,
             dtype=self.dtype,
+            param_dtype=self.param_dtype,
             kernel_axes=("embed", "vocab"),
             name="embed_out",
         )(x)

@@ -163,6 +163,11 @@ def _reload_params_tree(params, fresh):
     return out
 
 
+def _dict_path(path) -> Tuple[str, ...]:
+    """The dict keys along a pytree path (a boxed leaf's own keys left out)."""
+    return tuple(k.key for k in path if isinstance(k, jax.tree_util.DictKey))
+
+
 def _pages_axis(ndim: int) -> int:
     """Pages axis of a pool leaf: code leaves are ``(..., num_pages,
     page_size, kv_heads, head_dim)`` (axis ndim-4), int8 scale leaves are
@@ -198,7 +203,12 @@ def build_decode_model(
     multi-tenant layout (models/lora.py ``num_slots``): factors become
     ``(adapter_slots, …)`` HBM slabs and every forward takes a per-row
     ``adapter_idx`` routed through the grouped kernel.  Slot 0 is the
-    zero-initialized identity adapter."""
+    zero-initialized identity adapter.
+
+    Every family declares its weights in ``dtype`` (``param_dtype=dtype``):
+    what a forward multiplies in the compute dtype, the engine holds in it.
+    What is used in f32 — norm scales and offsets, ``lora_s``, quantization
+    scales — is declared f32 whatever ``dtype`` is."""
     if lora is not None:
         lora = dataclasses.replace(
             lora,
@@ -210,6 +220,7 @@ def build_decode_model(
         config=model_cfg,
         lora=lora,
         dtype=dtype,
+        param_dtype=dtype,
         scan_layers=scan_layers,
         remat=False,
         attention_impl=attention_impl,
@@ -229,7 +240,7 @@ def build_decode_model(
 
         return GPTNeoXForCausalLM(**kwargs)
     if model_cfg.family in ("mimo", "afmoe"):
-        # the families with layers of unlike kinds: paged only, weights held as handed
+        # the families with layers of unlike kinds: paged only
         if model_cfg.family == "mimo":
             from relora_tpu.models.mimo import MimoForCausalLM as Model
         else:
@@ -389,14 +400,17 @@ class InferenceEngine:
         #: [local assignments, distinct experts hit] of the last forward, on
         #: the device (None: the model has no routed experts)
         self.moe_counts = None
+        declared = self._declared_params()
         if adapter_slots:
             # the checkpoint carries unstacked (in, r) factors; the slotted
             # model wants (num_slots, in, r) slabs.  Rebuild: non-LoRA leaves
             # from the checkpoint, LoRA leaves fresh (zeros / spec scale) so
             # slot 0 is the identity adapter — the base checkpoint's own A/B
             # are deliberately dropped (tenants load theirs via the registry)
-            params = self._stack_adapter_params(params, lora)
-        params = jax.tree_util.tree_map(jnp.asarray, params)
+            params = self._stack_adapter_params(params, lora, declared)
+        # every leaf in the dtype the decode model declares for it, rounded
+        # here once and not by every dispatch; then placed
+        params = self._as_declared(params, declared)
         if mesh is not None:
             from relora_tpu.models.params_util import logical_partition_specs
 
@@ -404,6 +418,8 @@ class InferenceEngine:
             specs = logical_partition_specs(self.model, sample_ids)
             shardings = param_shardings(mesh, specs)
             params = jax.tree_util.tree_map(jax.device_put, params, shardings)
+        else:
+            params = jax.tree_util.tree_map(jnp.asarray, params)
         self.params = params
         # optional second tree for model-drafted speculation (--spec model):
         # same shapes/dtypes/shardings as params, installed via
@@ -594,20 +610,51 @@ class InferenceEngine:
             shardings,
         )
 
+    # -- the held parameter tree ---------------------------------------------
+
+    def _declared_params(self) -> PyTree:
+        """Abstract (shape, dtype) tree of the parameters the decode model
+        declares — eval_shape over model.init, so no FLOPs or memory."""
+        from flax import linen as nn
+
+        ids = jnp.zeros((1, 1), jnp.int32)
+        return nn.meta.unbox(jax.eval_shape(lambda: self.model.init(jax.random.PRNGKey(0), ids))["params"])
+
+    def _as_declared(self, params: PyTree, declared: PyTree) -> PyTree:
+        """``params`` with every leaf in the dtype the decode model declares
+        for it (``declared``: :meth:`_declared_params`): the compute dtype for what the
+        forward multiplies in it, f32 for what it uses in f32.  A leaf that
+        has that dtype already is the leaf handed; another is rounded once, a
+        leaf at a time (no second whole tree): one that arrives on the host is
+        cast there, so that what is transferred is what is held, and a device
+        leaf is replaced by its cast.  The forward casts each weight to the
+        compute dtype at its use, so the rounding is the one every dispatch
+        made: same bits into the same operations."""
+        dtypes = {_dict_path(path): leaf.dtype for path, leaf in jax.tree_util.tree_leaves_with_path(declared)}
+
+        def held(path, leaf):
+            if not isinstance(leaf, jax.Array):
+                leaf = np.asarray(leaf)
+            dtype = dtypes.get(_dict_path(path), leaf.dtype)
+            return leaf if leaf.dtype == dtype else leaf.astype(dtype)
+
+        return jax.tree_util.tree_map_with_path(held, params)
+
+    def param_bytes(self) -> int:
+        """Resident bytes of the parameter tree as held (per replica: a
+        sharded leaf counts whole).  The ``serve/param_bytes`` gauge."""
+        return sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(self.params))
+
     # -- multi-tenant adapter slots (adapter_slots set at construction) ------
 
-    def _stack_adapter_params(self, params: PyTree, lora: LoraSpec) -> PyTree:
+    def _stack_adapter_params(self, params: PyTree, lora: LoraSpec, shapes: PyTree) -> PyTree:
         """Rebuild the checkpoint tree for the slotted model: every non-LoRA
         leaf comes from the checkpoint, every lora_a/lora_b leaf becomes its
         zero stacked ``(num_slots, …)`` twin and lora_s fills with the spec
-        scale — so every slot starts as the identity adapter."""
+        scale — so every slot starts as the identity adapter.  ``shapes`` is
+        the slotted model's own tree (:meth:`_declared_params`)."""
         from flax import linen as nn
 
-        shapes = nn.meta.unbox(
-            jax.eval_shape(
-                lambda: self.model.init(jax.random.PRNGKey(0), jnp.zeros((1, 1), jnp.int32))
-            )["params"]
-        )
         params = nn.meta.unbox(params)
 
         def merge(ckpt, init):
@@ -664,8 +711,10 @@ class InferenceEngine:
         traced dynamic_update_slice over the donated param tree — pure data
         movement, zero steady-state retraces).  ``factors`` is the
         lora_a/lora_b subtree an AdapterRegistry loader returns; leaves are
-        cast onto the engine's template so dtype drift between checkpoints
-        cannot change the compiled signature."""
+        cast onto the engine's template — the dtype the slabs are held in,
+        the compute dtype — so an f32 adapter checkpoint is rounded once
+        here and dtype drift between checkpoints cannot change the compiled
+        signature."""
         self._require_slots()
         if not (0 < slot < self.adapter_slots):
             raise ValueError(
@@ -753,8 +802,11 @@ class InferenceEngine:
         against the live tree before any device write, the live tree is
         donated (no transient 2x params in HBM), and the jitted swap keeps
         one signature across reloads, so the CompileWatcher pins zero
-        steady-state retraces under reload churn.  On any validation error
-        the live tree is untouched — the server's fail-closed contract."""
+        steady-state retraces under reload churn.  Leaves are cast on the
+        host onto the live tree's dtypes — the ones the decode model declares
+        (:meth:`_as_declared`) — so an f32 checkpoint is rounded to the held
+        dtype once, here, as at construction.  On any validation error the
+        live tree is untouched — the server's fail-closed contract."""
         fresh = self._prepare_reload_tree(self.params, new_params)
         self.params = self._reload(self.params, fresh)
         # surface transfer/execution errors here, not on the next decode
@@ -767,8 +819,9 @@ class InferenceEngine:
         pruned+merged checkpoint ``--spec model`` proposes from.
 
         Same validation and placement as ``reload_params`` (every live leaf
-        needs a same-shape twin, dtypes cast host-side, shards placed on the
-        live leaf's sharding) but with NO donation: base and draft stay
+        needs a same-shape twin, dtypes cast host-side onto the held ones — an
+        f32 draft checkpoint lands in the compute dtype like the base —
+        shards placed on the live leaf's sharding) but with NO donation: base and draft stay
         resident together, sharing the one page pool, tokenizer, and — the
         point — the already-compiled paged programs.  The params argument of
         every paged jit is traced, and the draft tree presents the identical

@@ -147,6 +147,7 @@ class ContinuousBatchingScheduler:
         # server injects its Tracer + ServeMetrics (per-phase histograms)
         self.tracer = tracer if tracer is not None else NoopTracer()
         self.obs_registry = obs_registry
+        self.publish_param_bytes()
         self.key = key if key is not None else jax.random.PRNGKey(0)
         self._step_count = 0
         self._pending: Deque[Request] = deque()
@@ -228,6 +229,13 @@ class ContinuousBatchingScheduler:
     def _observe(self, name: str, value: float) -> None:
         if self.obs_registry is not None:
             self.obs_registry.observe(name, value)
+
+    def publish_param_bytes(self) -> None:
+        """Set the ``serve/param_bytes`` gauge: the bytes of the weights as
+        the engine holds them.  They change only with the tree, so this is
+        called when a registry is attached and after a reload, never a round."""
+        if self.obs_registry is not None:
+            self.obs_registry.set_gauge("param_bytes", self.engine.param_bytes())
 
     def cancel(
         self, uid: int, reason: str = "cancelled", detail: Optional[str] = None

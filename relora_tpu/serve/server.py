@@ -259,6 +259,7 @@ class GenerateServer:
             scheduler.tracer = self.tracer
         if scheduler.obs_registry is None:
             scheduler.obs_registry = self.stats
+            scheduler.publish_param_bytes()
         # multi-tenant: materialize the per-adapter series at zero so a
         # scrape taken before any tenant traffic still shows every adapter
         # the server can route to (absent-vs-zero is a real distinction for
@@ -635,6 +636,7 @@ class GenerateServer:
             self.weights_checkpoint = req.checkpoint
             self.stats.inc("weights_reloads_total")
             self.stats.set_gauge("weights_version", req.version)
+            self.scheduler.publish_param_bytes()
             logger.info(
                 f"weights hot-swapped to version {req.version} ({req.checkpoint})"
             )

@@ -65,7 +65,12 @@ def parse_args(argv=None) -> argparse.Namespace:
     p.add_argument("--eos-id", type=int, default=None, help="default: model config eos_token_id")
     p.add_argument("--cache-size", type=int, default=None, help="default: max_sequence_length")
     p.add_argument("--max-batch", type=int, default=4, help="decode slots (request-loop mode)")
-    p.add_argument("--dtype", choices=["f32", "bf16"], default="f32")
+    p.add_argument(
+        "--dtype", choices=["f32", "bf16"], default="f32",
+        help="the type the forward computes in and the engine holds its weights in: a "
+        "checkpoint is rounded to it once, at load and at every reload (norm scales, "
+        "lora_s and quantization scales stay f32)",
+    )
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--run-dir", default=None, help="metrics.jsonl destination (request-loop/server mode)")
     p.add_argument("--port", type=int, default=None, help="launch the HTTP server on this port (0 = ephemeral)")
@@ -345,16 +350,11 @@ def main(argv=None) -> int:
             dtype=jnp.bfloat16 if args.dtype == "bf16" else jnp.float32,
         )
 
-        def init(key):
-            tree = init_params(model, key, jnp.zeros((1, 8), jnp.int32))
-            # a model that states the type it holds its weights in (mimo)
-            # gets every matrix in it; one program, so no f32 tree exists
-            held = getattr(model, "param_dtype", None)
-            return jax.tree_util.tree_map(
-                lambda x: x.astype(held) if held is not None and x.ndim > 1 else x, tree
-            )
-
-        params = jax.jit(init)(jax.random.PRNGKey(args.seed))
+        # the decode model declares every leaf in the type the engine holds
+        # it in, so the draw is the held tree: no f32 copy exists
+        params = jax.jit(lambda key: init_params(model, key, jnp.zeros((1, 8), jnp.int32)))(
+            jax.random.PRNGKey(args.seed)
+        )
     elif args.checkpoint is None:
         raise SystemExit("pass --checkpoint (or --random-init for drills)")
     else:

@@ -426,6 +426,9 @@ class _IdleScheduler:
         self.tracer = NoopTracer()
         self.obs_registry = None
 
+    def publish_param_bytes(self):
+        pass
+
     def has_work(self):
         return False
 

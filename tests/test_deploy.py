@@ -292,6 +292,10 @@ def test_server_reload_between_decode_rounds(engine, disarm_faults):
         payload = json.loads(_http(port, "GET", "/healthz")[2])
         assert payload["weights_version"] == 1
         assert payload["weights_checkpoint"] == "/ckpt/model_1"
+        # the bytes of the weights as held: on /metrics before any round runs
+        assert server.stats.gauge_value("param_bytes") == engine.param_bytes() > 0
+        assert "param_bytes" in _http(port, "GET", "/metrics")[2].decode()
+        server.stats.set_gauge("param_bytes", 0)
 
         # concurrent load across the swap: nothing may drop
         results = []
@@ -314,6 +318,7 @@ def test_server_reload_between_decode_rounds(engine, disarm_faults):
         assert status == 200, body
         reply = json.loads(body)
         assert reply["ok"] is True and reply["weights_version"] == 2
+        assert server.stats.gauge_value("param_bytes") == engine.param_bytes()  # set again by the reload alone
         assert len(results) == 8
         assert all(r in ("length", "eos") for r in results)  # zero dropped
 

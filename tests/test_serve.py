@@ -388,3 +388,166 @@ def test_engine_on_mesh():
     out_ref = engine.generate([[1, 2, 3], [4, 5, 6]], max_new_tokens=4)
     out_mesh = sharded.generate([[1, 2, 3], [4, 5, 6]], max_new_tokens=4)
     assert out_ref == out_mesh
+
+
+# -- the held tree: every leaf in the dtype the decode model declares ---------
+
+HELD_FAMILIES = [pytest.param(TINY_NEOX, id="neox"), pytest.param(TINY_LLAMA, id="llama")]
+PAGED_BF16 = dict(cache_size=32, dtype=jnp.bfloat16, page_size=8, num_pages=9, chunk_size=8)
+
+
+def f32_tree(cfg, seed=0, lora=None):
+    """What a checkpoint restores to: the training layout, every float leaf f32."""
+    model = type(build_decode_model(cfg, cache_size=32))(cfg, lora=lora, dtype=jnp.float32)
+    return init_params(model, jax.random.PRNGKey(seed), jnp.zeros((1, 8), jnp.int32))
+
+
+def declared_dtype(path, compute):
+    """The dtype a leaf is used in, by its name: norm leaves, ``lora_s`` and
+    int8 scales in f32, int8 codes as they are, the rest in the compute dtype."""
+    *modules, name = (k.key for k in path)
+    if name == "kernel_q":
+        return jnp.int8
+    if name in ("lora_s", "kernel_scale") or any("norm" in m for m in modules):
+        return jnp.float32
+    return compute
+
+
+def dtypes_of(tree):
+    return jax.tree_util.tree_map(lambda x: x.dtype, tree)
+
+
+@pytest.mark.parametrize("cfg", HELD_FAMILIES)
+def test_engine_holds_every_leaf_in_its_declared_dtype(cfg):
+    """An engine that computes in bf16 over an f32 tree holds kernels, linear
+    biases, embeddings and LoRA factors in bf16, and what the forward uses in
+    f32 in f32; one that computes in f32 holds the very leaves it was handed."""
+    from relora_tpu.core.relora import LoraSpec
+
+    spec = LoraSpec(r=4, alpha=8, dropout=0.0, trainable_scaling=True, quantize="int8")
+    for lora, tree in ((None, f32_tree(cfg)), (spec, f32_tree(cfg, lora=spec))):
+        assert all(x.dtype in (jnp.float32, jnp.int8) for x in jax.tree_util.tree_leaves(tree))
+        held = InferenceEngine(cfg, tree, cache_size=32, dtype=jnp.bfloat16, lora=lora).params
+        names = set()
+        for path, leaf in jax.tree_util.tree_leaves_with_path(held):
+            assert leaf.dtype == declared_dtype(path, jnp.bfloat16), jax.tree_util.keystr(path)
+            names.add(path[-1].key)
+        assert {"kernel", "embedding", "scale"} <= names
+        if lora is not None:
+            assert {"lora_a", "lora_b", "lora_s", "kernel_q", "kernel_scale"} <= names
+        same = InferenceEngine(cfg, tree, cache_size=32, dtype=jnp.float32, lora=lora).params
+        assert all(a is b for a, b in zip(jax.tree_util.tree_leaves(same), jax.tree_util.tree_leaves(tree), strict=True))
+    assert ("bias" in names) == (cfg.family == "neox")  # GPT-NeoX's linears have biases, held in bf16
+
+
+@pytest.mark.parametrize("cfg", HELD_FAMILIES)
+def test_held_bf16_weights_give_the_logits_of_weights_cast_at_use(cfg):
+    """Rounding the f32 tree once, when the engine is built, gives the paged
+    programs the bits that casting every weight at its use gave them: the
+    logits of ``prefill_chunk`` and ``decode_paged`` equal, bit for bit, those
+    of the same model declared in f32 and applied to the f32 tree."""
+    from relora_tpu.models.step import StepContext
+    from relora_tpu.serve.engine import _chunk_positions, _forward
+
+    tree = f32_tree(cfg)
+    engine = InferenceEngine(cfg, tree, **PAGED_BF16)
+    assert engine.params["layers"]["mlp"]["down_proj" if cfg.family == "llama" else "dense_4h_to_h"]["kernel"].dtype == jnp.bfloat16
+    cast_at_use = engine.paged_model.clone(param_dtype=jnp.float32)
+
+    @jax.jit
+    def step(pool, ids, positions, tables):
+        return _forward(cast_at_use, tree, pool, ids, StepContext(positions=positions, tables=tables))[:2]
+
+    ids = jax.random.randint(jax.random.PRNGKey(3), (1, 8), 0, cfg.vocab_size)
+    table = np.arange(1, 5, dtype=np.int32)[None, :]
+    logits, pool = engine.prefill_chunk(ids, 0, engine.init_pool(), table)
+    want, want_pool = step(engine.init_pool(), ids, _chunk_positions(0, 1, 8), engine.tables_by_kind(table, 0))
+    assert logits.dtype == want.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(logits), np.asarray(want))
+
+    token, pos = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32), jnp.full((1, 1), 8, jnp.int32)
+    logits, _ = engine.decode_paged(pool, token, pos, table)
+    want, _ = step(want_pool, token, pos, engine.tables_by_kind(table))
+    np.testing.assert_array_equal(np.asarray(logits), np.asarray(want[:, -1, :]))
+
+
+def test_an_f32_checkpoint_reloads_into_the_held_dtypes():
+    """``reload_params`` and ``load_draft_params`` cast an f32 checkpoint tree
+    onto the live tree's dtypes: the leaves land in bf16 where the engine holds
+    bf16, the tokens are a fresh engine's over that checkpoint, and base and
+    draft replay the compiled programs (no steady-state retrace)."""
+    from relora_tpu.serve.scheduler import PagedContinuousBatchingScheduler
+
+    first, second = f32_tree(TINY_NEOX, seed=0), f32_tree(TINY_NEOX, seed=1)
+
+    def host(tree):
+        return jax.tree_util.tree_map(np.asarray, tree)
+
+    def drain(engine):
+        reqs = [Request(uid=i, prompt=[3 + i, 5, 7, 11, 13], max_new_tokens=6) for i in range(3)]
+        done = PagedContinuousBatchingScheduler(engine, max_batch=2, eos_id=-1).run(reqs)
+        return {uid: c.tokens for uid, c in done.items()}
+
+    engine = InferenceEngine(TINY_NEOX, first, **PAGED_BF16)
+    engine.warmup(2)
+    held = dtypes_of(engine.params)
+    assert held["embed_in"]["embedding"] == jnp.bfloat16 and held["final_layer_norm"]["scale"] == jnp.float32
+    before = drain(engine)
+    engine.reload_params(host(second))
+    engine.load_draft_params(host(first))
+    assert dtypes_of(engine.params) == held == dtypes_of(engine.draft_params)
+    after = drain(engine)
+    assert after != before
+    assert after == drain(InferenceEngine(TINY_NEOX, second, **PAGED_BF16))
+
+    ids = jnp.asarray([[2, 4, 6, 8, 10, 12, 14, 16]], jnp.int32)
+    table = np.arange(1, 5, dtype=np.int32)[None, :]
+    drafted, _ = engine.draft_prefill_chunk(ids, 0, engine.init_pool(2), table)
+    fresh, _ = InferenceEngine(TINY_NEOX, first, **PAGED_BF16).prefill_chunk(ids, 0, engine.init_pool(2), table)
+    np.testing.assert_array_equal(np.asarray(drafted), np.asarray(fresh))
+    assert engine.compile_watcher.steady_state_retraces == 0
+
+
+@pytest.mark.parametrize("family", ["neox", "llama"])
+def test_the_trainers_model_declares_what_it_declared(family):
+    """``build_decode_model`` alone gives a ``param_dtype``: the trainer's
+    model keeps every parameter in f32 (a bf16 frozen base only where the
+    spec asks for one, ``lora_s`` in f32 either way)."""
+    from relora_tpu.config.training import TrainingConfig
+    from relora_tpu.core.relora import LoraSpec
+    from relora_tpu.train.trainer import build_model
+
+    cfg = TINY_NEOX if family == "neox" else TINY_LLAMA
+    train_cfg = TrainingConfig(dataset_path="x", batch_size=1, total_batch_size=1)
+    ids = jnp.zeros((1, 8), jnp.int32)
+    for spec in (None, LoraSpec(r=4, trainable_scaling=True), LoraSpec(r=4, trainable_scaling=True, base_dtype="bf16")):
+        model = build_model(cfg, spec, train_cfg)
+        assert model.param_dtype == jnp.float32 and model.dtype == jnp.bfloat16
+        abstract = jax.eval_shape(lambda: init_params(model, jax.random.PRNGKey(0), ids))
+        for path, leaf in jax.tree_util.tree_leaves_with_path(abstract):
+            frozen_base = spec is not None and spec.base_dtype == "bf16" and path[-1].key == "kernel" and "lora_a" in _siblings(abstract, path)
+            assert leaf.dtype == (jnp.bfloat16 if frozen_base else jnp.float32), jax.tree_util.keystr(path)
+
+
+def _siblings(tree, path):
+    for k in path[:-1]:
+        tree = tree[k.key]
+    return tree
+
+
+@pytest.mark.parametrize("family", ["mimo", "afmoe"])
+def test_routed_engines_hold_the_bf16_tree_they_are_handed(family, tmp_path):
+    """The families that were always handed bf16 weights: the engine holds
+    the very leaves — matrices bf16, 1-D leaves f32 — and casts none."""
+    import importlib
+
+    tiny = importlib.import_module(f"test_{family}")  # the family's tiny configuration
+    weights = importlib.import_module(f"benchmark.weights_{family}")
+    tree = weights.make_weights(tiny.TINY, 7)
+    engine = InferenceEngine(
+        tiny._config(tmp_path), tree, cache_size=128, dtype=jnp.bfloat16, page_size=4, num_pages=70, chunk_size=8
+    )
+    handed, held = jax.tree_util.tree_leaves(tree), jax.tree_util.tree_leaves(engine.params)
+    assert all(a is b for a, b in zip(held, handed, strict=True))
+    assert all(x.dtype == (jnp.bfloat16 if x.ndim > 1 else jnp.float32) for x in held)
+    assert engine.param_bytes() == sum(x.nbytes for x in handed)
