@@ -11,7 +11,7 @@ buffer to disk as JSON that ``tools/trace_report.py`` renders.
 Beside the ring the recorder keeps **the capture**: the spans of the last
 ``jax.profiler`` session, which the tracer marks ``profiled`` (a session was
 live at both their ends).  The ring turns over in seconds under serving
-traffic (a span per streamed token); the capture is only cleared by the next
+traffic (some fourteen spans a round); the capture is only cleared by the next
 session's first span, so it is the host half of a captured profile — the
 trainer's ``--profile`` as much as a benchmark's traced run — and what
 :meth:`FlightRecorder.capture` hands a reader after the run.
@@ -38,13 +38,17 @@ __all__ = [
     "dump_on_fault",
 ]
 
-#: ring capacities — ~2k spans covers minutes of serving traffic or hundreds
-#: of train steps at <1 MB resident; sized for forensics, not archival
+#: ring capacities — ~2k spans are the last 140 serving rounds (a round
+#: leaves about fourteen spans, a request four of its own, its one
+#: ``sse_flush`` among them: five seconds at 28 rounds a second) or hundreds
+#: of train steps, at <1 MB resident; sized for forensics, not archival
 SPAN_CAPACITY = 2048
 EVENT_CAPACITY = 512
-#: the capture's bound: a profiler session is seconds long (a serving round
-#: leaves about ten context-managed spans, a train update four)
-CAPTURE_CAPACITY = 4096
+#: the capture's bound: a profiler session is seconds long.  A serving round
+#: leaves about fourteen context-managed spans (a train update four), so a
+#: 4 s session of 15 ms rounds is 3,700; a reader takes no mean over a
+#: capture that dropped any (``dropped_profiled``)
+CAPTURE_CAPACITY = 16384
 
 
 class FlightRecorder:

@@ -10,14 +10,13 @@ phases nest into a tree), and free-form attributes.
 
 Design constraints, in priority order:
 
-1. **Hot-loop safe.**  ``Tracer.span`` is called a dozen times per serving
+1. **Hot-loop safe.**  ``Tracer.span`` is called some fifteen times per serving
    round and four times per train update; its cost is two ``time.monotonic()``
    calls, a few dict stores, one lock-guarded deque append and, once jax is
    loaded, two ``is_enabled()`` checks (a profiler annotation only while a
-   session is live) — microseconds against rounds and updates
-   of hundreds of milliseconds (on the chip: PERF.md §6, "PR 26", gives what
-   the serving and the training cell read with a profiler session on and
-   off).  No I/O on the hot path unless a JSONL sink is explicitly configured.
+   session is live) — 7 µs on the chip's host, 27 inside a session, against
+   rounds of tens and updates of hundreds of milliseconds (PERF.md §6,
+   "PR 38").  No I/O on the hot path unless a JSONL sink is explicitly configured.
 2. **Stdlib-only and jax-free**, like serve/admission and analysis/: the
    tracer must import fast and run in the asyncio front-end, the model
    thread, and the signal handler that dumps the flight recorder.  It never

@@ -169,6 +169,10 @@ class PrefixCache:
         self._entries: "OrderedDict[bytes, _PrefixEntry]" = OrderedDict()
         self.lookups = 0
         self.hits = 0
+        # tokens fed to ``_digest`` by ``lookup`` and ``register``: every
+        # page-aligned prefix is hashed from token 0, so a prompt of n pages
+        # costs about n/2 times its length (what an incremental digest saves)
+        self.hashed_tokens = 0
 
     def __len__(self) -> int:
         return len(self._entries)
@@ -191,6 +195,7 @@ class PrefixCache:
         ps = self.allocator.page_size
         self.lookups += 1
         for k in range((len(prompt) - 1) // ps, 0, -1):
+            self.hashed_tokens += k * ps
             digest = self._digest(prompt[: k * ps])
             entry = self._entries.get(digest)
             if entry is None:
@@ -208,6 +213,7 @@ class PrefixCache:
         ps = self.allocator.page_size
         created = 0
         for k in range(1, len(prompt) // ps + 1):
+            self.hashed_tokens += k * ps
             digest = self._digest(prompt[: k * ps])
             if digest in self._entries:
                 self._entries.move_to_end(digest)

@@ -147,7 +147,7 @@ class ContinuousBatchingScheduler:
         # server injects its Tracer + ServeMetrics (per-phase histograms)
         self.tracer = tracer if tracer is not None else NoopTracer()
         self.obs_registry = obs_registry
-        self.publish_param_bytes()
+        ContinuousBatchingScheduler.publish_constants(self)  # a subclass adds its own once it is built
         self.key = key if key is not None else jax.random.PRNGKey(0)
         self._step_count = 0
         self._pending: Deque[Request] = deque()
@@ -230,12 +230,17 @@ class ContinuousBatchingScheduler:
         if self.obs_registry is not None:
             self.obs_registry.observe(name, value)
 
-    def publish_param_bytes(self) -> None:
-        """Set the ``serve/param_bytes`` gauge: the bytes of the weights as
-        the engine holds them.  They change only with the tree, so this is
-        called when a registry is attached and after a reload, never a round."""
+    def publish_constants(self) -> None:
+        """Publish what changes only with the tree or the pool — here the
+        ``serve/param_bytes`` gauge, the bytes of the weights as the engine
+        holds them.  Called when a registry is attached and after a reload,
+        never a round."""
         if self.obs_registry is not None:
             self.obs_registry.set_gauge("param_bytes", self.engine.param_bytes())
+
+    def drop_host_gap(self) -> None:
+        """The driving loop waited with nothing to run: that wait is no
+        host gap (the paged scheduler counts one; here there is none)."""
 
     def cancel(
         self, uid: int, reason: str = "cancelled", detail: Optional[str] = None
@@ -920,6 +925,12 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
         self._migrated_inserts = 0
         self._prefix_fetches = 0
         self._prefix_fetch_failures = 0
+        # the host gap (docs/observability.md): when the last blocking pull
+        # returned, on the tracer's clock (None: nothing to count from), and
+        # the seconds counted so far for the round in progress
+        self._pull_stamp: Optional[float] = None
+        self._host_gap_s = 0.0
+        self.publish_constants()
 
     # -- admission ------------------------------------------------------------
 
@@ -964,7 +975,7 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
             shared_pages: List[int] = []
             shared_tokens = 0
             if self.prefix_cache is not None:
-                shared_pages, shared_tokens = self.prefix_cache.lookup(req.prompt)
+                shared_pages, shared_tokens = self._prefix_lookup(req.prompt)
                 if (
                     not shared_pages
                     and self.prefix_fetch is not None
@@ -1055,6 +1066,7 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
         t0 = time.monotonic()
         # the request's trace, the round's child: one chunk of one request's
         # prompt, inside the batch-level round that ran it
+        self._enqueue()
         with self.tracer.span(
             "prefill_chunk", trace_id=tid, parent=self.tracer.current_span(),
             uid=req.uid, start=start, chunk=chunk, real=n_real,
@@ -1082,26 +1094,29 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
                     # counts go on its span (an earlier chunk's reach the
                     # counters with the next pull)
                     sp_chunk.set(**self._pull_moe_counts())
+                    self._pulled()
         self._observe("prefill_seconds", time.monotonic() - t0)
         if first_id is None:
             return  # more chunks to go; decode proceeds this round regardless
         if self.prefix_cache is not None:
             # only pages fully covered by prompt tokens register — the
             # donor's decode writes (positions >= L) never touch them
-            self.prefix_cache.register(list(req.prompt), slot.pages)
-        slot.decoding = True
-        slot.tokens = [first_id]
-        slot.pos = L
-        slot.t_first = time.monotonic()
-        slot.span = self.tracer.start_span("decode", trace_id=tid, uid=req.uid)
-        self._tokens[slot_idx] = first_id
-        self._positions[slot_idx] = L
-        self._tables[slot_idx, : len(slot.pages)] = slot.pages
-        if slot.draft_pages:
-            self._draft_tables[slot_idx, : len(slot.draft_pages)] = slot.draft_pages
-        self._emit_token(req.uid, first_id, 0)
-        self._finish_if_done(slot_idx, finished)
-        self._maybe_migrate(slot_idx)
+            self._prefix_register(req.prompt, slot.pages)
+        # the device has drained and waits for the decode's turn
+        with self.tracer.span("first_token", uid=req.uid):
+            slot.decoding = True
+            slot.tokens = [first_id]
+            slot.pos = L
+            slot.t_first = time.monotonic()
+            slot.span = self.tracer.start_span("decode", trace_id=tid, uid=req.uid)
+            self._tokens[slot_idx] = first_id
+            self._positions[slot_idx] = L
+            self._tables[slot_idx, : len(slot.pages)] = slot.pages
+            if slot.draft_pages:
+                self._draft_tables[slot_idx, : len(slot.draft_pages)] = slot.draft_pages
+            self._emit_token(req.uid, first_id, 0)
+            self._finish_if_done(slot_idx, finished)
+            self._maybe_migrate(slot_idx)
 
     # -- disaggregated handoff (prefill role -> decode peer) --------------------
 
@@ -1329,7 +1344,7 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
         if self.prefix_cache is not None:
             # the migrated prompt's pages are as shareable as a locally
             # prefilled one's — register them for later local hits
-            self.prefix_cache.register(list(req.prompt), pages)
+            self._prefix_register(req.prompt, pages)
         self._migrated_inserts += 1
         if self.obs_registry is not None:
             self.obs_registry.inc("migrated_inserts_total")
@@ -1371,7 +1386,7 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
             except Exception:
                 self.allocator.decref(pages)
                 raise
-            self.prefix_cache.register(list(req.prompt[:n_tokens]), pages)
+            self._prefix_register(req.prompt[:n_tokens], pages)
             # the cache's own refs keep the run alive; drop the alloc ref and
             # let the re-lookup incref for this request like any local hit
             self.allocator.decref(pages)
@@ -1380,13 +1395,31 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
             if self.obs_registry is not None:
                 self.obs_registry.inc("prefix_fetch_total")
                 self.obs_registry.inc("migration_bytes_total", by=int(nbytes))
-            return self.prefix_cache.lookup(req.prompt)
+            return self._prefix_lookup(req.prompt)
         except Exception as e:
             logger.warning(f"request {req.uid}: prefix fetch failed: {e!r}")
             self._prefix_fetch_failures += 1
             if self.obs_registry is not None:
                 self.obs_registry.inc("prefix_fetch_failures_total")
             return [], 0
+
+    def _prefix_lookup(self, prompt: Sequence[int]) -> tuple:
+        """``prefix_cache.lookup`` under the ``prefix_lookup`` span, with what
+        it hashed (the cache counts the tokens; the span takes the difference)."""
+        cache = self.prefix_cache
+        hashed = cache.hashed_tokens
+        with self.tracer.span("prefix_lookup", prompt_tokens=len(prompt)) as sp:
+            pages, n_tokens = cache.lookup(prompt)
+            sp.set(hashed_tokens=cache.hashed_tokens - hashed, hit_tokens=n_tokens)
+        return pages, n_tokens
+
+    def _prefix_register(self, prompt: Sequence[int], pages: Sequence[int]) -> None:
+        """``prefix_cache.register`` under the ``prefix_register`` span."""
+        cache = self.prefix_cache
+        hashed = cache.hashed_tokens
+        with self.tracer.span("prefix_register", prompt_tokens=len(prompt)) as sp:
+            created = cache.register(list(prompt), pages)
+            sp.set(hashed_tokens=cache.hashed_tokens - hashed, created=created)
 
     # -- speculative draft / verify --------------------------------------------
 
@@ -1451,6 +1484,7 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
         k_max = int(ks.max())
         cur = self._tokens[:, None]
         proposals = []
+        self._enqueue()
         for step in range(k_max):
             live = ks > step
             positions = np.where(live, self._positions + step, 0).astype(np.int32)
@@ -1462,6 +1496,7 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
             cur = jnp.argmax(logits, axis=-1).astype(jnp.int32).reshape(-1, 1)
             proposals.append(cur)
         stacked = np.asarray(jnp.concatenate(proposals, axis=1))  # one host pull
+        self._pulled()
         return {
             i: [int(t) for t in stacked[i, : int(ks[i])]] for i in eligible
         }
@@ -1582,10 +1617,13 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
 
         The round is split into spans where the device waits
         (docs/observability.md, "The serving round"): ``round`` holds
-        ``admit``, ``prefill_chunk``, ``decode_step`` (``dispatch`` up to
-        the enqueue, with the rows' draws as ``sample`` inside it, ``pull``
-        for the blocking read), ``commit`` and ``round_metrics``; a step
-        that dispatched nothing leaves none."""
+        ``admit`` (``prefix_lookup`` inside it), ``prefill_chunk``, after a
+        prompt's last chunk ``prefix_register`` and ``first_token``,
+        ``decode_prep``, ``decode_step`` (``dispatch`` up to the enqueue,
+        with the rows' draws as ``sample`` inside it, ``pull`` for the
+        blocking read), ``commit`` and ``round_metrics``; a step that
+        dispatched nothing leaves none.  What passes between a pull's return
+        and the next enqueue is the round's ``host_gap_ms``."""
         if self._packed:
             return self._step_packed()
         finished: List[Completion] = []
@@ -1603,28 +1641,32 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
                     self._close_round(sp_round, 0, d0)
                 else:
                     sp_round.drop()
+                    self.drop_host_gap()
                     if any(s is not None and s.migrating for s in self._slots):
                         time.sleep(0.001)  # only parked handoffs: don't hot-spin
                 return finished  # pure-prefill round (or idle)
 
             t_decode = time.monotonic()
-            if self._spec == "ngram":
-                drafts = self._draft_pass()
-            elif self._spec == "model":
-                drafts = self._model_draft_pass()
-            else:
-                drafts = {}
-            n_drafted = sum(len(d) for d in drafts.values())
-            rode = set(
-                i for i, s in enumerate(self._slots) if s is not None and s.decoding
-            )
+            with self.tracer.span("decode_prep"):
+                if self._spec == "ngram":
+                    drafts = self._draft_pass()
+                elif self._spec == "model":
+                    drafts = self._model_draft_pass()
+                else:
+                    drafts = {}
+                n_drafted = sum(len(d) for d in drafts.values())
+                rode = set(
+                    i for i, s in enumerate(self._slots) if s is not None and s.decoding
+                )
+                reads = self._decode_reads()
             with self.tracer.span(
                 "decode_step",
                 step=self._step_count,
                 active_slots=n_decoding,
                 spec_drafted=n_drafted,
-                **self._decode_reads(),
+                **reads,
             ) as sp_decode:
+                self._enqueue()
                 with self.tracer.span("dispatch"):
                     if drafts:
                         # draft→verify→accept: one verify window per row
@@ -1652,6 +1694,7 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
                     else:
                         next_tokens = np.asarray(drawn).tolist()
                     sp_decode.set(**self._pull_moe_counts())
+                    self._pulled()
             decode_s = time.monotonic() - t_decode
             self._observe("decode_step_seconds", decode_s)
             self._count_round()
@@ -1745,6 +1788,8 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
         return committed
 
     def _close_round(self, sp_round, n_decoding: int, d0: int) -> None:
+        gap_s, self._host_gap_s = self._host_gap_s, 0.0
+        self._observe("host_gap_seconds", gap_s)
         sp_round.set(
             decoding=n_decoding,
             prefilling=sum(
@@ -1752,7 +1797,30 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
                 for s in self._slots
             ),
             dispatches=self._dispatch_total - d0,
+            host_gap_ms=1e3 * gap_s,
         )
+
+    # -- the host gap -----------------------------------------------------------
+    # In the sequential step a blocking pull returns only once the device has
+    # drained, so from there to the next enqueue the device has nothing
+    # queued and waits for the host.  The stamp outlives the round span: the
+    # gap before a round's first enqueue (the last round's commit and
+    # metrics, the server's loop, this round's admission) is this round's.
+
+    def _pulled(self) -> None:
+        self._pull_stamp = self.tracer.clock()
+
+    def _enqueue(self) -> None:
+        """Device work is about to be queued: count what has passed since
+        the last pull returned, if anything is counted from."""
+        if self._pull_stamp is not None:
+            self._host_gap_s += self.tracer.clock() - self._pull_stamp
+            self._pull_stamp = None
+
+    def drop_host_gap(self) -> None:
+        """A step that dispatched nothing, or the server's loop waiting for
+        a request: the wait is not the host's, and is not counted."""
+        self._pull_stamp = None
 
     # -- dispatch accounting ----------------------------------------------------
 
@@ -1777,10 +1845,45 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
         if self.obs_registry is not None:
             self.obs_registry.inc("sched_rounds_total")
 
+    def publish_constants(self) -> None:
+        """Beside the weights' bytes: the gauges that are constants of the
+        pool, and every counter of the round at 0, so that ``/metrics`` has
+        the series (and a scraper's deltas a base) before the first count.
+        A round publishes only what a round can change."""
+        super().publish_constants()
+        registry = self.obs_registry
+        if registry is None:
+            return
+        registry.set_gauge("kv_cache_bytes", self._kv_cache_bytes)
+        registry.set_gauge("kv_bytes_per_token", self._kv_bytes_per_token)
+        if self._ring_spec is not None:
+            for kind, nbytes in self._kv_cache_bytes_by_kind.items():
+                registry.set_gauge(f"kv_cache_bytes_{kind}", nbytes)
+            registry.set_gauge("window_ring_pages", self._ring_spec.table_width)
+        registry.materialize_histogram("host_gap_seconds")
+        registry.inc("model_dispatches_total", by=0)
+        registry.inc("sched_rounds_total", by=0)
+        registry.inc("dispatch_tokens_total", by=0)
+        registry.inc("dispatch_tokens_real_total", by=0)
+        registry.inc("pages_migrated_total", by=0)
+        registry.inc("migration_bytes_total", by=0)
+        registry.inc("migration_failures_total", by=0)
+        registry.inc("migrated_inserts_total", by=0)
+        registry.inc("prefix_fetch_total", by=0)
+        registry.inc("prefix_fetch_failures_total", by=0)
+        if self._moe_fanout:
+            registry.inc("moe_assignments_total", by=0)
+            registry.inc("moe_assignments_local_total", by=0)
+            registry.inc("moe_experts_hit_total", by=0)
+        if self._spec != "off":
+            registry.set_gauge("spec_mode_model", 1.0 if self._spec == "model" else 0.0)
+            registry.inc("spec_drafted_total", by=0)
+            registry.inc("spec_accepted_total", by=0)
+
     def _round_metrics(self, admit_s: float, decode_s: float, n_decoding: int) -> None:
         """Publish the round's gauges and metrics.jsonl record — shared by
         the sequential and packed step bodies so both expose an identical
-        telemetry surface."""
+        telemetry surface.  What no round changes, :meth:`publish_constants` has set."""
         batch_fill = n_decoding / self.max_batch
         stall_share = admit_s / max(admit_s + decode_s, 1e-9)
         self._admit_time_s += admit_s
@@ -1798,42 +1901,17 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
             self.obs_registry.set_gauge("kv_pages_free", self.allocator.free_pages)
             self.obs_registry.set_gauge("prefix_cache_hit_rate", hit_rate)
             self.obs_registry.set_gauge("prefill_pad_share", pad_share)
-            self.obs_registry.set_gauge("kv_cache_bytes", self._kv_cache_bytes)
             if self._ring_spec is not None:
-                for kind, nbytes in self._kv_cache_bytes_by_kind.items():
-                    self.obs_registry.set_gauge(f"kv_cache_bytes_{kind}", nbytes)
-                self.obs_registry.set_gauge("window_ring_pages", self._ring_spec.table_width)
                 self.obs_registry.set_gauge("ring_wrapped_rows", self._ring_wrapped_rows)
-            if self._moe_fanout:
-                for name in ("moe_assignments_total", "moe_assignments_local_total", "moe_experts_hit_total"):
-                    self.obs_registry.inc(name, by=0)
-            self.obs_registry.set_gauge("kv_bytes_per_token", self._kv_bytes_per_token)
             self.obs_registry.set_gauge("decode_live_page_share", live_page_share)
             self.obs_registry.set_gauge("dispatches_per_round", dispatches_per_round)
             self.obs_registry.set_gauge("tokens_per_dispatch", tokens_per_dispatch)
             self.obs_registry.set_gauge("packed_token_utilization", token_utilization)
-            # by=0 materializes the counters at 0 so /metrics always exposes
-            # them (and scrapers' delta logic sees the series from the start)
-            self.obs_registry.inc("model_dispatches_total", by=0)
-            self.obs_registry.inc("sched_rounds_total", by=0)
-            self.obs_registry.inc("dispatch_tokens_total", by=0)
-            self.obs_registry.inc("dispatch_tokens_real_total", by=0)
-            self.obs_registry.inc("pages_migrated_total", by=0)
-            self.obs_registry.inc("migration_bytes_total", by=0)
-            self.obs_registry.inc("migration_failures_total", by=0)
-            self.obs_registry.inc("migrated_inserts_total", by=0)
-            self.obs_registry.inc("prefix_fetch_total", by=0)
-            self.obs_registry.inc("prefix_fetch_failures_total", by=0)
             if self._spec != "off":
                 self.obs_registry.set_gauge(
                     "spec_accept_rate",
                     self._spec_accepted / max(self._spec_drafted, 1),
                 )
-                self.obs_registry.set_gauge(
-                    "spec_mode_model", 1.0 if self._spec == "model" else 0.0
-                )
-                self.obs_registry.inc("spec_drafted_total", by=0)
-                self.obs_registry.inc("spec_accepted_total", by=0)
         record = None
         if self.metrics is not None:
             watcher = getattr(self.engine, "compile_watcher", None)
@@ -1898,6 +1976,7 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
         with self.tracer.span("round", round=self._round_total) as sp_round:
             if not self._packed_round(sp_round, finished):
                 sp_round.drop()
+                self.drop_host_gap()
         return finished
 
     def _packed_round(self, sp_round, finished: List[Completion]) -> bool:
@@ -1916,99 +1995,104 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
         null_pos = engine.cache_size
         spec_k = engine.spec_k
 
-        drafts = self._draft_pass() if self._spec == "ngram" else {}
-        spec_mode = bool(drafts)
-        S = spec_k + 1 if spec_mode else 1
+        # the draft pass and the window's assembly: host work before the enqueue
+        with self.tracer.span("decode_prep") as sp_prep:
+            drafts = self._draft_pass() if self._spec == "ngram" else {}
+            spec_mode = bool(drafts)
+            S = spec_k + 1 if spec_mode else 1
 
-        ids: List[int] = []
-        poss: List[int] = []
-        rows: List[int] = []
-        adap: List[int] = []
-        slot_off: Dict[int, int] = {}  # decoding slot -> its window's offset
+            ids: List[int] = []
+            poss: List[int] = []
+            rows: List[int] = []
+            adap: List[int] = []
+            slot_off: Dict[int, int] = {}  # decoding slot -> its window's offset
 
-        # decode/verify windows first — the budget never throttles decode
-        # (ctor floor check); k_eff=0 rows ride the full window in spec mode,
-        # mirroring _verify_dispatch
-        draft_mat = np.zeros((B, max(spec_k, 1)), np.int32)
-        k_eff = np.zeros(B, np.int32)
-        uids = np.zeros(B, np.int32)
-        starts = np.zeros(B, np.int32)
-        temps = np.zeros(B, np.float32)
-        top_ps = np.ones(B, np.float32)
-        for slot_idx, slot in enumerate(self._slots):
-            if slot is None or not slot.decoding:
-                continue
-            slot_off[slot_idx] = len(ids)
-            d = drafts.get(slot_idx, [])
-            window = [int(self._tokens[slot_idx])] + [int(t) for t in d]
-            window += [0] * (S - len(window))
-            ids.extend(window)
-            poss.extend(int(self._positions[slot_idx]) + j for j in range(S))
-            rows.extend([slot_idx] * S)
-            adap.extend([slot.adapter_slot] * S)
-            draft_mat[slot_idx, : len(d)] = d
-            k_eff[slot_idx] = len(d)
-            uids[slot_idx] = slot.request.uid
-            starts[slot_idx] = len(slot.tokens)
-            temps[slot_idx] = slot.request.temperature
-            top_ps[slot_idx] = slot.request.top_p
-        n_decoding = len(slot_off)
+            # decode/verify windows first — the budget never throttles decode
+            # (ctor floor check); k_eff=0 rows ride the full window in spec mode,
+            # mirroring _verify_dispatch
+            draft_mat = np.zeros((B, max(spec_k, 1)), np.int32)
+            k_eff = np.zeros(B, np.int32)
+            uids = np.zeros(B, np.int32)
+            starts = np.zeros(B, np.int32)
+            temps = np.zeros(B, np.float32)
+            top_ps = np.ones(B, np.float32)
+            for slot_idx, slot in enumerate(self._slots):
+                if slot is None or not slot.decoding:
+                    continue
+                slot_off[slot_idx] = len(ids)
+                d = drafts.get(slot_idx, [])
+                window = [int(self._tokens[slot_idx])] + [int(t) for t in d]
+                window += [0] * (S - len(window))
+                ids.extend(window)
+                poss.extend(int(self._positions[slot_idx]) + j for j in range(S))
+                rows.extend([slot_idx] * S)
+                adap.extend([slot.adapter_slot] * S)
+                draft_mat[slot_idx, : len(d)] = d
+                k_eff[slot_idx] = len(d)
+                uids[slot_idx] = slot.request.uid
+                starts[slot_idx] = len(slot.tokens)
+                temps[slot_idx] = slot.request.temperature
+                top_ps[slot_idx] = slot.request.top_p
+            n_decoding = len(slot_off)
 
-        # oldest-first prefill from MULTIPLE slots into the leftover budget;
-        # write-then-attend makes several chunks of one prompt inside one
-        # dispatch correct, so a slot may clear its whole backlog here
-        budget_left = engine.token_budget - len(ids)
-        prefill_spans: List[tuple] = []  # (slot_idx, start, n, packed offset)
-        for _, slot_idx in sorted(
-            (s.seq, i)
-            for i, s in enumerate(self._slots)
-            if s is not None and not s.decoding and not s.migrating
-        ):
-            if budget_left <= 0:
-                break
-            slot = self._slots[slot_idx]
-            req = slot.request
-            start = slot.prefill_progress
-            n = min(len(req.prompt) - start, budget_left)
-            if n <= 0:
-                continue
-            prefill_spans.append((slot_idx, start, n, len(ids)))
-            ids.extend(int(t) for t in req.prompt[start : start + n])
-            poss.extend(range(start, start + n))
-            rows.extend([slot_idx] * n)
-            adap.extend([slot.adapter_slot] * n)
-            budget_left -= n
+            # oldest-first prefill from MULTIPLE slots into the leftover budget;
+            # write-then-attend makes several chunks of one prompt inside one
+            # dispatch correct, so a slot may clear its whole backlog here
+            budget_left = engine.token_budget - len(ids)
+            prefill_spans: List[tuple] = []  # (slot_idx, start, n, packed offset)
+            for _, slot_idx in sorted(
+                (s.seq, i)
+                for i, s in enumerate(self._slots)
+                if s is not None and not s.decoding and not s.migrating
+            ):
+                if budget_left <= 0:
+                    break
+                slot = self._slots[slot_idx]
+                req = slot.request
+                start = slot.prefill_progress
+                n = min(len(req.prompt) - start, budget_left)
+                if n <= 0:
+                    continue
+                prefill_spans.append((slot_idx, start, n, len(ids)))
+                ids.extend(int(t) for t in req.prompt[start : start + n])
+                poss.extend(range(start, start + n))
+                rows.extend([slot_idx] * n)
+                adap.extend([slot.adapter_slot] * n)
+                budget_left -= n
 
-        n_real = len(ids)
-        if n_real == 0:
-            if any(s is not None and s.migrating for s in self._slots):
-                time.sleep(0.001)  # only parked handoffs: don't hot-spin
-            return False  # nothing decodable and nothing left to prefill
-        bucket = next(b for b in engine.packed_buckets() if b >= n_real)
-        pad = bucket - n_real
-        ids.extend([0] * pad)
-        poss.extend([null_pos] * pad)  # clips into the null page
-        rows.extend([B] * pad)  # the all-null pad row of _ptables
-        adap.extend([0] * pad)
-        self._pad_tokens += pad
-        self._prefill_tokens += sum(n for _, _, n, _ in prefill_spans)
+            n_real = len(ids)
+            if n_real == 0:
+                sp_prep.drop()
+                if any(s is not None and s.migrating for s in self._slots):
+                    time.sleep(0.001)  # only parked handoffs: don't hot-spin
+                return False  # nothing decodable and nothing left to prefill
+            bucket = next(b for b in engine.packed_buckets() if b >= n_real)
+            pad = bucket - n_real
+            ids.extend([0] * pad)
+            poss.extend([null_pos] * pad)  # clips into the null page
+            rows.extend([B] * pad)  # the all-null pad row of _ptables
+            adap.extend([0] * pad)
+            self._pad_tokens += pad
+            self._prefill_tokens += sum(n for _, _, n, _ in prefill_spans)
 
-        # slots whose prompt ends inside this dispatch: their first token is
-        # drawn with the same per-slot scalar call and (uid, 0) key as the
-        # sequential chunk path, so first tokens match exactly
-        ending = [
-            (slot_idx, off + n - 1)
-            for slot_idx, start, n, off in prefill_spans
-            if start + n >= len(self._slots[slot_idx].request.prompt)
-        ]
+            # slots whose prompt ends inside this dispatch: their first token is
+            # drawn with the same per-slot scalar call and (uid, 0) key as the
+            # sequential chunk path, so first tokens match exactly
+            ending = [
+                (slot_idx, off + n - 1)
+                for slot_idx, start, n, off in prefill_spans
+                if start + n >= len(self._slots[slot_idx].request.prompt)
+            ]
+            reads = self._decode_reads()
         with self.tracer.span(
             "decode_step",
             step=self._step_count,
             active_slots=n_decoding,
             spec_drafted=int(k_eff.sum()),
             packed_tokens=bucket,
-            **self._decode_reads(),
+            **reads,
         ):
+            self._enqueue()
             with self.tracer.span("dispatch"):
                 logits, self._pool = engine.step_paged(
                     self._ensure_pool(),
@@ -2060,6 +2144,7 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
                 elif drawn is not None:
                     next_tokens = np.asarray(drawn).tolist()
                 first_ids = [int(np.asarray(first)[0]) for first in firsts]
+                self._pulled()
         decode_s = time.monotonic() - t_decode
         self._observe("decode_step_seconds", decode_s)
         # dispatch and round tick together: a concurrent /healthz read
@@ -2088,7 +2173,7 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
                     continue
                 req = slot.request
                 if self.prefix_cache is not None:
-                    self.prefix_cache.register(list(req.prompt), slot.pages)
+                    self._prefix_register(req.prompt, slot.pages)
                 slot.decoding = True
                 slot.tokens = [first_id]
                 slot.pos = len(req.prompt)

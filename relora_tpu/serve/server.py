@@ -259,7 +259,7 @@ class GenerateServer:
             scheduler.tracer = self.tracer
         if scheduler.obs_registry is None:
             scheduler.obs_registry = self.stats
-            scheduler.publish_param_bytes()
+            scheduler.publish_constants()
         # multi-tenant: materialize the per-adapter series at zero so a
         # scrape taken before any tenant traffic still shows every adapter
         # the server can route to (absent-vs-zero is a real distinction for
@@ -489,39 +489,51 @@ class GenerateServer:
                     )
             while True:
                 faults.serve_tick(self._tokens_emitted)  # serving drills only
-                # a pending reload pauses *claiming* only: queued tickets wait
-                # in admission (nothing is dropped), in-flight requests finish
-                # entirely on the old weights (per-request version purity),
-                # and the swap happens at the idle boundary below
-                reload_req = self._pending_reload
-                while reload_req is None and (
-                    sched.active_slots + sched.queue_depth < sched.max_batch
-                ):
-                    ticket = self.admission.pop(timeout=None)
-                    if ticket is None:
-                        break
-                    self._claim(ticket)
-                for uid, ticket in list(self._active.items()):
-                    if ticket.cancelled.is_set():
-                        sched.cancel(uid)  # fires on_finish -> _active cleanup
-                self._drain_disagg_inbox()
-                self.stats.set_gauge(
-                    "queue_depth", self.admission.depth() + sched.queue_depth
-                )
-                self.stats.set_gauge("active_slots", sched.active_slots)
-                self.stats.set_gauge(
-                    "retry_after_s", round(self.admission.retry_after_s, 3)
-                )
-                if sched.has_work():
+                # the loop's own part of the scheduler's host gap, between two
+                # rounds: the device waits through it, so it has a name
+                with self.tracer.span("claim") as sp_claim:
+                    # a pending reload pauses *claiming* only: queued tickets
+                    # wait in admission (nothing is dropped), in-flight
+                    # requests finish entirely on the old weights (per-request
+                    # version purity), and the swap happens at the idle
+                    # boundary below
+                    reload_req = self._pending_reload
+                    claimed = 0
+                    while reload_req is None and (
+                        sched.active_slots + sched.queue_depth < sched.max_batch
+                    ):
+                        ticket = self.admission.pop(timeout=None)
+                        if ticket is None:
+                            break
+                        self._claim(ticket)
+                        claimed += 1
+                    for uid, ticket in list(self._active.items()):
+                        if ticket.cancelled.is_set():
+                            sched.cancel(uid)  # fires on_finish -> _active cleanup
+                    self._drain_disagg_inbox()
+                    self.stats.set_gauge(
+                        "queue_depth", self.admission.depth() + sched.queue_depth
+                    )
+                    self.stats.set_gauge("active_slots", sched.active_slots)
+                    self.stats.set_gauge(
+                        "retry_after_s", round(self.admission.retry_after_s, 3)
+                    )
+                    busy = sched.has_work()
+                    sp_claim.set(claimed=claimed)
+                    if not claimed and not busy:
+                        sp_claim.drop()  # an idle turn leaves nothing
+                if busy:
                     self._model_busy = True
                     self._outbox = []  # what the step's callbacks post rides out together
                     try:
                         sched.step()
                     finally:
-                        self._flush_outbox()
+                        with self.tracer.span("flush_outbox"):
+                            self._flush_outbox()
                     self._last_step_t = time.monotonic()
                     continue
                 self._model_busy = False
+                sched.drop_host_gap()  # waiting for a request is not the host's gap
                 self._last_step_t = time.monotonic()  # idle is not a stall
                 if reload_req is not None:
                     # the boundary: no active slots, no scheduler queue — swap
@@ -532,7 +544,8 @@ class GenerateServer:
                     break
                 ticket = self.admission.pop(timeout=_IDLE_POP_S)
                 if ticket is not None:
-                    self._claim(ticket)
+                    with self.tracer.span("claim", claimed=1):
+                        self._claim(ticket)
         except BaseException as e:
             self._worker_error = e
             logger.error(f"model thread died: {e!r}")
@@ -636,7 +649,7 @@ class GenerateServer:
             self.weights_checkpoint = req.checkpoint
             self.stats.inc("weights_reloads_total")
             self.stats.set_gauge("weights_version", req.version)
-            self.scheduler.publish_param_bytes()
+            self.scheduler.publish_constants()
             logger.info(
                 f"weights hot-swapped to version {req.version} ({req.checkpoint})"
             )
@@ -1526,23 +1539,31 @@ class GenerateServer:
                 kind, a, b = getter.result()
                 if kind == "token":
                     event = {"uid": ticket.uid, "index": b, "token": a}
-                    # manual span, explicit parent: handlers interleave on one
-                    # thread, so the tracer's ambient (thread-local) nesting
-                    # would cross-wire concurrent streams
-                    flush = self.tracer.start_span(
-                        "sse_flush",
-                        trace_id=ticket.trace_id,
-                        parent=ticket.span,
-                        index=b,
+                    # a span for the first token's write only, the last hop of
+                    # the request's TTFT: one a streamed token turned the
+                    # flight recorder's ring over in two seconds.  Manual,
+                    # explicit parent: handlers interleave on one thread, so
+                    # the tracer's ambient (thread-local) nesting would
+                    # cross-wire concurrent streams
+                    flush = (
+                        self.tracer.start_span(
+                            "sse_flush", trace_id=ticket.trace_id, parent=ticket.span, index=b
+                        )
+                        if b == 0
+                        else None
                     )
+                    t_write = self.tracer.clock()
                     writer.write(_sse(event))
                     try:
                         await writer.drain()
                     except (ConnectionError, OSError):
-                        flush.set(outcome="disconnect").end()
+                        if flush is not None:
+                            flush.set(outcome="disconnect").end()
                         self._client_gone(ticket)
                         return
-                    self.stats.observe("sse_flush_seconds", flush.end())
+                    if flush is not None:
+                        flush.end()
+                    self.stats.observe("sse_flush_seconds", self.tracer.clock() - t_write)
                 else:  # finish
                     writer.write(_sse(_completion_record(a)))
                     writer.write(b"data: [DONE]\n\n")

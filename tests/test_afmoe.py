@@ -204,6 +204,7 @@ def test_a_long_request_holds_a_constant_ring_and_frees_its_pages(cfg, params):
     registry = MetricsRegistry()
     sch = PagedContinuousBatchingScheduler(eng, max_batch=2, eos_id=-1, prefix_cache=False, key=jax.random.PRNGKey(0))
     sch.obs_registry = registry
+    sch.publish_constants()  # as the server does where it attaches its registry
     sch.tracer = Tracer(service="test")
     ring = next(c for c in eng.cache_specs(2) if c.kind == RING)
     assert ring.table_width == -(-(8 + 64) // 16) + 1 and ring.num_pages == 1 + 2 * ring.table_width

@@ -426,7 +426,10 @@ class _IdleScheduler:
         self.tracer = NoopTracer()
         self.obs_registry = None
 
-    def publish_param_bytes(self):
+    def publish_constants(self):
+        pass
+
+    def drop_host_gap(self):
         pass
 
     def has_work(self):

@@ -575,6 +575,13 @@ def test_trace_report_puts_idle_gaps_down_to_host_spans():
     # 53 ms of round less dispatch start (12) .. last pull end on its thread (45)
     assert rnd["name"] == "round" and host_ns == (53 - 33) * ms
     assert [s["name"] for s in inside] == ["admit", "decode_step", "dispatch", "pull", "commit", "round_metrics"]
+    # by overlap, the 20 ms gap at 10-30 is admit's 2, dispatch's 2 and pull's 16 (its midpoint is in
+    # pull, which the midpoint rule bills for all of it); the gap at 50-52 is commit's; a gap past the
+    # round's end has no span open
+    model = [s for s in spans if s["thread"] == "model"]
+    assert tr.idle_by_overlap([(10 * ms, 20 * ms), (50 * ms, 2 * ms), (57 * ms, 3 * ms)], model) == {
+        "admit": 2 * ms, "dispatch": 2 * ms, "pull": 16 * ms, "commit": 2 * ms, "round_metrics": 1 * ms, tr.NO_SPAN: 2 * ms,
+    }
 
 
 def test_trace_report_counts_programs_per_whole_round():

@@ -320,7 +320,7 @@ def test_packed_round_has_the_sequential_rounds_span_names():
     assert len(rounds) == len(decode_steps) == len(calls) == sched._round_total
     for r in rounds:
         names = [k["name"] for k in children[r["span_id"]]]
-        assert names == ["admit", "decode_step", "commit", "round_metrics"]
+        assert names == ["admit", "decode_prep", "decode_step", "commit", "round_metrics"]
         assert r["attrs"]["dispatches"] == 1
     per_token = engine.kv_bytes_per_token()
     for step, (_pool, _ids, poss, *_rest) in zip(decode_steps, calls):

@@ -106,6 +106,21 @@ class TestPageAllocator:
 
 
 class TestPrefixCache:
+    def test_hashed_tokens_counts_what_the_digest_is_fed(self):
+        """Every page-aligned prefix is hashed from token 0: a 1,024-token
+        prompt at page 16 feeds the digest 63 prefixes on a missed lookup
+        and 64 on a register, about 32 times its length each."""
+        alloc = PageAllocator(66, 16)
+        cache = PrefixCache(alloc)
+        prompt = list(range(1024))
+        assert cache.lookup(prompt) == ([], 0)
+        assert cache.hashed_tokens == 16 * sum(range(1, 64)) == 32_256
+        assert cache.register(prompt, alloc.alloc(64)) == 64
+        assert cache.hashed_tokens - 32_256 == 16 * sum(range(1, 65)) == 33_280
+        got, n = cache.lookup(prompt)  # a hit stops at the longest prefix: one digest
+        assert n == 63 * 16 and cache.hashed_tokens == 32_256 + 33_280 + 63 * 16
+        alloc.decref(got)
+
     def test_lookup_caps_below_full_prompt(self):
         """At least one prompt token must re-prefill: a prompt of exactly
         k pages only ever matches a (k-1)-page prefix."""
@@ -777,7 +792,9 @@ def test_paged_metrics_kv_bytes_gauges(tmp_path):
 
 # -- the round's spans (docs/observability.md, "The serving round") -----------
 
-ROUND_CHILDREN = {"admit", "prefill_chunk", "decode_step", "commit", "round_metrics"}
+ROUND_CHILDREN = {
+    "admit", "prefill_chunk", "prefix_register", "first_token", "decode_prep", "decode_step", "commit", "round_metrics",
+}
 
 
 def traced_drain(engine, reqs, *, spy: str, **kwargs):
@@ -830,7 +847,7 @@ def check_round_tree(rounds, children):
         assert set(names) <= ROUND_CHILDREN and names[0] == "admit", names
         assert r["attrs"]["dispatches"] == names.count("prefill_chunk") + names.count("decode_step") > 0
         if r["attrs"]["decoding"]:
-            assert names[-3:] == ["decode_step", "commit", "round_metrics"], names
+            assert names[-4:] == ["decode_prep", "decode_step", "commit", "round_metrics"], names
         for k in kids:
             assert r["t_start"] <= k["t_start"] <= k["t_end"] <= r["t_end"]
             grandkids = children.get(k["span_id"], [])
@@ -846,7 +863,11 @@ def check_round_tree(rounds, children):
                 assert k["trace_id"].startswith("request-")  # the request's trace, the round's child
                 assert 0 < k["attrs"]["real"] <= k["attrs"]["chunk"]
             else:
-                assert grandkids == []
+                # the prefix hash, where it runs: a lookup an admission, a
+                # register in the packed round's commit (the sequential
+                # round's is the round's own child, after the last chunk)
+                inside = {"admit": "prefix_lookup", "commit": "prefix_register"}.get(k["name"])
+                assert {g["name"] for g in grandkids} <= {inside}, (k["name"], grandkids)
             for g in grandkids:
                 assert k["t_start"] <= g["t_start"] <= g["t_end"] <= k["t_end"]
     return decode_steps

@@ -19,8 +19,10 @@ with the XLA timelines StepProfiler writes.
 device planes' operations and, from the ``/host:CPU`` plane of the same file
 and so on the same clock, the annotations ``Tracer.span`` left there.  It
 prints each device idle gap over a millisecond with the innermost host span
-open at its midpoint, the gap seconds per span name and per scheduler round,
-each round's host-only time (its duration less the interval from its
+open at its midpoint, the gap seconds per span name and per scheduler round
+(and, since a long gap covers several spans, all idle time inside the whole
+rounds by the innermost span it overlaps: the table that agrees with the
+scheduler's own ``round.host_gap_ms``), each round's host-only time (its duration less the interval from its
 first ``dispatch`` or ``prefill_chunk`` start to its last ``pull`` end), and
 the XLA programs the device executed, by name and per round.
 
@@ -315,6 +317,27 @@ def innermost_span(spans: List[Dict[str, Any]], t_ns: float) -> Optional[Dict[st
     return best
 
 
+def idle_by_overlap(
+    gaps: List[Tuple[float, float]], spans: List[Dict[str, Any]]
+) -> Dict[str, float]:
+    """Idle nanoseconds by the innermost span they overlap: each gap
+    (``(start_ns, duration_ns)``) is cut at every span boundary inside it and
+    each piece goes to the span open there that started last (``NO_SPAN``
+    where none is).  The midpoint rule of table (ii) bills a whole gap to one
+    span; a gap that runs from a pull's return over the host's work to the
+    next program's first operation is then named after whatever lies in its
+    middle.  ``spans`` sorted by start, of one thread."""
+    bounds = sorted({s["start_ns"] for s in spans} | {s["start_ns"] + s["dur_ns"] for s in spans})
+    out: Dict[str, float] = {}
+    for start, dur in gaps:
+        cuts = [start, *(b for b in bounds if start < b < start + dur), start + dur]
+        for lo, hi in zip(cuts, cuts[1:]):
+            sp = innermost_span(spans, (lo + hi) / 2)
+            name = sp["name"] if sp is not None else NO_SPAN
+            out[name] = out.get(name, 0.0) + hi - lo
+    return out
+
+
 def round_host_only(spans: List[Dict[str, Any]]) -> List[Tuple[Dict[str, Any], List[Dict[str, Any]], float]]:
     """Per ``round`` span: the spans of its thread that lie inside it, and its
     host-only nanoseconds — its duration less the interval from its first
@@ -381,6 +404,23 @@ def xplane_report(path: str, out=sys.stdout, max_gaps: int = 40) -> int:
             f"  put down to a named span: {100.0 * named / max(total, 1.0):.1f}%; "
             f"to {NO_SPAN}: {100.0 * (total - named) / max(total, 1.0):.1f}%\n"
         )
+        if rounds:
+            # within the whole rounds' extent, on their thread: what the
+            # scheduler's own count (round.host_gap_ms) can be held against
+            thread = rounds[0][0]["thread"]
+            lo = min(r["start_ns"] for r, _, _ in rounds)
+            hi = max(r["start_ns"] + r["dur_ns"] for r, _, _ in rounds)
+            clipped = [
+                (max(s, lo), min(s + d, hi) - max(s, lo))
+                for s, d in idle_gaps(ops, 0.0) if s + d > lo and s < hi
+            ]
+            overlap = idle_by_overlap(clipped, [s for s in spans if s["thread"] == thread])
+            out.write(
+                f"  (ii') all idle time inside the whole rounds, by the innermost span it overlaps: "
+                f"{sum(overlap.values()) / 1e6 / n_frames:.2f} ms/{frame}\n"
+            )
+            for name, ns in sorted(overlap.items(), key=lambda kv: -kv[1]):
+                out.write(f"  {name:<20} {ns / 1e6 / n_frames:>15.2f}\n")
     frames = [
         (s["start_ns"], s["start_ns"] + s["dur_ns"])
         for s in ([r for r, _, _ in rounds] or [s for s in spans if s["name"] == frame])
