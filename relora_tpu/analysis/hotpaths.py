@@ -83,6 +83,11 @@ HOT_FUNCTIONS: Dict[str, List[str]] = {
         "PagedContinuousBatchingScheduler.step",  # one budgeted round
         "PagedContinuousBatchingScheduler._admit_pass",  # per round
         "PagedContinuousBatchingScheduler._prefill_pass",  # per round
+        # its two halves, also run from step: a chunk sent behind the decode
+        # ahead of its pull, and landed at the next round's start
+        "PagedContinuousBatchingScheduler._send_chunk",
+        "PagedContinuousBatchingScheduler._land_chunk",
+        "PagedContinuousBatchingScheduler._pull_first",  # a first token's read
         # --spec model: K autoregressive draft forwards per decode round
         "PagedContinuousBatchingScheduler._model_draft_pass",
         "ContinuousBatchingScheduler._acquire_adapter",  # per admitted request

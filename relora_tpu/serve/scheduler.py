@@ -706,6 +706,19 @@ class _PagedSlot(_Slot):
     draft_pages: List[int] = dataclasses.field(default_factory=list)
 
 
+@dataclasses.dataclass
+class _SentChunk:
+    """A prefill chunk that has been enqueued: whose it is and, where it ends
+    the prompt, the first token's draw — on the device until ``first_id`` is
+    pulled (in the chunk's own span, or at the next round's start for a chunk
+    sent ahead)."""
+
+    slot_idx: int
+    slot: _PagedSlot
+    first: Optional[jax.Array] = None
+    first_id: Optional[int] = None
+
+
 class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
     """Continuous batching over the paged engine: budgeted rounds instead of
     prefill-on-admission.
@@ -717,6 +730,16 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
     never stalls in-flight streams for more than one ``chunk_size`` forward —
     the contiguous scheduler's ``serve/prefill_stall_share`` is exactly the
     cost this removes.
+
+    **The chunk goes ahead.**  Where a slot is still prefilling when a round's
+    decode has been enqueued, the chunk the next round would have run is
+    enqueued right behind it, *before* the decode is pulled: the device works
+    on it while the host reads the tokens, commits them and launches the next
+    decode.  The programs reach the device in the same order either way
+    (decode n, chunk n+1, decode n+1) and one chunk a decode stays the
+    policy, so every token is the one the serial order served; only the
+    host's enqueue moves.  The next round finds the chunk sent, runs no other
+    and, if it ended a prompt, pulls its first token just before its decode.
 
     Admission is all-or-nothing on pages (worst case
     ``ceil((prompt + max_new_tokens) / page_size)``): when the pool is
@@ -925,11 +948,19 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
         self._migrated_inserts = 0
         self._prefix_fetches = 0
         self._prefix_fetch_failures = 0
+        # the chunk a decode_step sent ahead of its pull, until the next round
+        # takes it up (cumulative count beside the dispatches')
+        self._ahead: Optional[_SentChunk] = None
+        self._chunks_ahead = 0
         # the host gap (docs/observability.md): when the last blocking pull
-        # returned, on the tracer's clock (None: nothing to count from), and
-        # the seconds counted so far for the round in progress
+        # returned, on the tracer's clock (None: nothing to count from),
+        # whether a chunk sent ahead was queued behind it (the device had
+        # work: what passes is covered, not gap), and the seconds of each
+        # kind counted so far for the round in progress
         self._pull_stamp: Optional[float] = None
+        self._pull_covered = False
         self._host_gap_s = 0.0
+        self._covered_gap_s = 0.0
         self.publish_constants()
 
     # -- admission ------------------------------------------------------------
@@ -1041,15 +1072,27 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
         """Run one prefill chunk for the oldest still-prefilling slot; when
         it completes the prompt, sample the first token (key (uid, 0) — the
         same stream as the contiguous path) and arm the slot for decode."""
+        sent = self._send_chunk()
+        if sent is not None:
+            self._land_chunk(sent, finished)
+
+    def _send_chunk(self, ahead: bool = False) -> Optional[_SentChunk]:
+        """Enqueue one chunk of the oldest still-prefilling slot's prompt
+        and, where it ends the prompt, the first token's draw behind it.
+        ``ahead``: from inside a ``decode_step``, behind a decode that has
+        not been pulled — nothing is read here, the next round lands it
+        (:meth:`_land_chunk`); otherwise a chunk that ends a prompt is pulled
+        inside its own span."""
         prefilling = [
             (s.seq, i)
             for i, s in enumerate(self._slots)
             if s is not None and not s.decoding and not s.migrating
         ]
         if not prefilling:
-            return
+            return None
         slot_idx = min(prefilling)[1]  # oldest admission first (FIFO)
         slot = self._slots[slot_idx]
+        sent = _SentChunk(slot_idx, slot)
         req = slot.request
         L = len(req.prompt)
         chunk = self.engine.chunk_size
@@ -1061,15 +1104,14 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
         table[0, : len(slot.pages)] = slot.pages
         self._pad_tokens += chunk - n_real
         self._prefill_tokens += n_real
-        tid = self._trace_ids.get(req.uid)
-        first_id = None
         t0 = time.monotonic()
-        # the request's trace, the round's child: one chunk of one request's
-        # prompt, inside the batch-level round that ran it
+        # the request's trace, the round's child (the decode_step's, sent
+        # ahead): one chunk of one request's prompt, inside the batch-level
+        # round that ran it
         self._enqueue()
         with self.tracer.span(
-            "prefill_chunk", trace_id=tid, parent=self.tracer.current_span(),
-            uid=req.uid, start=start, chunk=chunk, real=n_real,
+            "prefill_chunk", trace_id=self._trace_ids.get(req.uid), parent=self.tracer.current_span(),
+            uid=req.uid, start=start, chunk=chunk, real=n_real, **({"ahead": 1} if ahead else {}),
         ) as sp_chunk:
             logits, self._pool = self.engine.prefill_chunk(
                 jnp.asarray(ids), start, self._ensure_pool(), table,
@@ -1087,17 +1129,46 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
                 self._count_dispatch(chunk, n_real)
             slot.prefill_progress = start + n_real
             if slot.prefill_progress >= L:
-                first = self._sample_first(logits[:, L - 1 - start, :], req)
-                with self.tracer.span("pull"):
-                    first_id = int(np.asarray(first)[0])
+                sent.first = self._sample_first(logits[:, L - 1 - start, :], req)
+                if not ahead:
                     # the chunk that ends a prompt is pulled anyway: its own
                     # counts go on its span (an earlier chunk's reach the
                     # counters with the next pull)
-                    sp_chunk.set(**self._pull_moe_counts())
-                    self._pulled()
+                    with self.tracer.span("pull"):
+                        sent.first_id = self._pull_first(sent.first, sp_chunk)
         self._observe("prefill_seconds", time.monotonic() - t0)
-        if first_id is None:
-            return  # more chunks to go; decode proceeds this round regardless
+        if ahead:
+            self._chunks_ahead += 1
+            if self.obs_registry is not None:
+                self.obs_registry.inc("prefill_chunks_ahead_total")
+        return sent
+
+    def _pull_first(self, first: jax.Array, sp_counts) -> int:
+        """The blocking read of a first token's draw; the pending forwards'
+        counts (the chunk's own last) go on ``sp_counts``."""
+        first_id = int(np.asarray(first)[0])
+        sp_counts.set(**self._pull_moe_counts())
+        self._pulled()
+        return first_id
+
+    def _land_chunk(self, sent: _SentChunk, finished: List[Completion]) -> bool:
+        """What follows a chunk that ended its prompt: the first token read
+        (here, for a chunk sent ahead: as late as the round allows, after the
+        last round's commit and this one's admission), the prompt's pages
+        registered, the slot armed for this round's decode.  False where
+        nothing landed: the prompt has chunks to go, or its slot was
+        cancelled, expired or retired with the chunk in flight — the result
+        is dropped (the device's order keeps the chunk's page writes ahead of
+        any later owner's)."""
+        slot_idx, slot = sent.slot_idx, sent.slot
+        if sent.first is None or self._slots[slot_idx] is not slot:
+            return False
+        req = slot.request
+        if sent.first_id is None:
+            self._enqueue()  # the covered stretch ends where the host starts to wait
+            with self.tracer.span("pull", uid=req.uid) as sp_pull:
+                sent.first_id = self._pull_first(sent.first, sp_pull)
+        first_id, L = sent.first_id, len(req.prompt)
         if self.prefix_cache is not None:
             # only pages fully covered by prompt tokens register — the
             # donor's decode writes (positions >= L) never touch them
@@ -1108,7 +1179,9 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
             slot.tokens = [first_id]
             slot.pos = L
             slot.t_first = time.monotonic()
-            slot.span = self.tracer.start_span("decode", trace_id=tid, uid=req.uid)
+            slot.span = self.tracer.start_span(
+                "decode", trace_id=self._trace_ids.get(req.uid), uid=req.uid
+            )
             self._tokens[slot_idx] = first_id
             self._positions[slot_idx] = L
             self._tables[slot_idx, : len(slot.pages)] = slot.pages
@@ -1117,6 +1190,7 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
             self._emit_token(req.uid, first_id, 0)
             self._finish_if_done(slot_idx, finished)
             self._maybe_migrate(slot_idx)
+        return True
 
     # -- disaggregated handoff (prefill role -> decode peer) --------------------
 
@@ -1540,7 +1614,7 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
             starts[slot_idx] = len(slot.tokens)
             temps[slot_idx] = slot.request.temperature
             top_ps[slot_idx] = slot.request.top_p
-        n_dec = sum(1 for s in self._slots if s is not None and s.decoding)
+        n_dec = self._n_decoding()
         logits, self._pool = self.engine.verify_paged(
             self._ensure_pool(), tokens, positions, tables,
             adapter_idx=self._adapter_row,
@@ -1617,13 +1691,17 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
 
         The round is split into spans where the device waits
         (docs/observability.md, "The serving round"): ``round`` holds
-        ``admit`` (``prefix_lookup`` inside it), ``prefill_chunk``, after a
-        prompt's last chunk ``prefix_register`` and ``first_token``,
-        ``decode_prep``, ``decode_step`` (``dispatch`` up to the enqueue,
-        with the rows' draws as ``sample`` inside it, ``pull`` for the
-        blocking read), ``commit`` and ``round_metrics``; a step that
-        dispatched nothing leaves none.  What passes between a pull's return
-        and the next enqueue is the round's ``host_gap_ms``."""
+        ``admit`` (``prefix_lookup`` inside it), ``prefill_chunk`` (where the
+        last round's decode sent none ahead; ``pull`` alone where the one it
+        sent ended a prompt), after a prompt's last chunk
+        ``prefix_register`` and ``first_token``, ``decode_prep``,
+        ``decode_step`` (``dispatch`` up to the enqueue, with the rows' draws
+        as ``sample`` inside it, the next round's ``prefill_chunk`` where a
+        slot is prefilling, ``pull`` for the blocking read), ``commit`` and
+        ``round_metrics``; a step that dispatched nothing leaves none.  What
+        passes between a pull's return and the next enqueue is the round's
+        ``host_gap_ms``, or its ``covered_gap_ms`` where a chunk sent ahead
+        was queued behind the pull."""
         if self._packed:
             return self._step_packed()
         finished: List[Completion] = []
@@ -1631,13 +1709,23 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
         d0 = self._dispatch_total
         with self.tracer.span("round", round=self._round_total) as sp_round:
             self._admit_round(finished)
-            self._prefill_pass(finished)
+            # the chunk the last decode sent ahead is this round's: no other
+            # runs, unless no row decodes (a pure-prefill round has no decode
+            # to send its one chunk behind)
+            ahead, self._ahead = self._ahead, None
+            landed = ahead is not None and self._land_chunk(ahead, finished)
+            if ahead is None or not self._n_decoding():
+                self._prefill_pass(finished)
             admit_s = time.monotonic() - t_step
-            n_decoding = sum(s is not None and s.decoding for s in self._slots)
+            n_decoding = self._n_decoding()
             if n_decoding == 0:
                 if self._dispatch_total > d0:
                     self._count_round()  # pure-prefill round still dispatched
                     self._admit_time_s += admit_s  # a 100%-stall round
+                    self._close_round(sp_round, 0, d0)
+                elif landed:
+                    # only a first token landed, and ended its request: its
+                    # spans keep their parent, no reader counts the round
                     self._close_round(sp_round, 0, d0)
                 else:
                     sp_round.drop()
@@ -1688,13 +1776,18 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
                         ]
                         drawn = self._sample_rows(logits, masked)
                     self._step_count += 1
+                # the next round's chunk needs nothing this pull brings: it
+                # goes behind the decode now, and the pull reads (and
+                # converts the counts of) only what lies before it
+                behind = len(self._moe_pending)
+                self._ahead = self._send_chunk(ahead=True)
                 with self.tracer.span("pull"):
                     if drafts:
                         accept, alt = np.asarray(accept), np.asarray(alt)
                     else:
                         next_tokens = np.asarray(drawn).tolist()
-                    sp_decode.set(**self._pull_moe_counts())
-                    self._pulled()
+                    sp_decode.set(**self._pull_moe_counts(behind))
+                    self._pulled(covered=self._ahead is not None)
             decode_s = time.monotonic() - t_decode
             self._observe("decode_step_seconds", decode_s)
             self._count_round()
@@ -1751,12 +1844,15 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
             "live_pages": self._decode_live_pages,
         }
 
-    def _pull_moe_counts(self) -> Dict[str, int]:
+    def _pull_moe_counts(self, first: Optional[int] = None) -> Dict[str, int]:
         """Pull what the forwards since the last pull counted (the arrays are
         on the device behind results this round has already waited for),
         add them to the counters, and return the newest forward's as span
-        attributes; nothing for a model without routed experts."""
-        pending, self._moe_pending = self._moe_pending, []
+        attributes; nothing for a model without routed experts.  ``first``:
+        only the first so many pending forwards' — those of a chunk sent
+        ahead lie behind what was waited for, and wait for the next pull."""
+        n = len(self._moe_pending) if first is None else first
+        pending, self._moe_pending = self._moe_pending[:n], self._moe_pending[n:]
         if not pending:
             return {}
         pulled = [(routed, np.asarray(counts)) for routed, counts in pending]  # noqa: RTL204 - in the round's pull
@@ -1787,8 +1883,12 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
             self._finish_if_done(slot_idx, finished)
         return committed
 
+    def _n_decoding(self) -> int:
+        return sum(s is not None and s.decoding for s in self._slots)
+
     def _close_round(self, sp_round, n_decoding: int, d0: int) -> None:
         gap_s, self._host_gap_s = self._host_gap_s, 0.0
+        covered_s, self._covered_gap_s = self._covered_gap_s, 0.0
         self._observe("host_gap_seconds", gap_s)
         sp_round.set(
             decoding=n_decoding,
@@ -1798,23 +1898,35 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
             ),
             dispatches=self._dispatch_total - d0,
             host_gap_ms=1e3 * gap_s,
+            covered_gap_ms=1e3 * covered_s,
+            chunk_ahead=1 if self._ahead is not None else 0,
         )
 
     # -- the host gap -----------------------------------------------------------
-    # In the sequential step a blocking pull returns only once the device has
-    # drained, so from there to the next enqueue the device has nothing
-    # queued and waits for the host.  The stamp outlives the round span: the
-    # gap before a round's first enqueue (the last round's commit and
-    # metrics, the server's loop, this round's admission) is this round's.
+    # A blocking pull behind which nothing was queued returns only once the
+    # device has drained, so from there to the next enqueue the device has
+    # nothing queued and waits for the host.  A decode's pull with the next
+    # round's chunk queued behind it (``covered``) leaves the device at work:
+    # what passes from there to the next enqueue, or to the read of that
+    # chunk's first token, is counted apart, as covered.  The stamp outlives
+    # the round span: the gap before a round's first enqueue (the last
+    # round's commit and metrics, the server's loop, this round's admission)
+    # is this round's.
 
-    def _pulled(self) -> None:
+    def _pulled(self, covered: bool = False) -> None:
         self._pull_stamp = self.tracer.clock()
+        self._pull_covered = covered
 
     def _enqueue(self) -> None:
-        """Device work is about to be queued: count what has passed since
-        the last pull returned, if anything is counted from."""
+        """Device work is about to be queued (or a chunk sent ahead read):
+        count what has passed since the last pull returned, if anything is
+        counted from."""
         if self._pull_stamp is not None:
-            self._host_gap_s += self.tracer.clock() - self._pull_stamp
+            passed = self.tracer.clock() - self._pull_stamp
+            if self._pull_covered:
+                self._covered_gap_s += passed
+            else:
+                self._host_gap_s += passed
             self._pull_stamp = None
 
     def drop_host_gap(self) -> None:
@@ -1862,6 +1974,7 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
             registry.set_gauge("window_ring_pages", self._ring_spec.table_width)
         registry.materialize_histogram("host_gap_seconds")
         registry.inc("model_dispatches_total", by=0)
+        registry.inc("prefill_chunks_ahead_total", by=0)
         registry.inc("sched_rounds_total", by=0)
         registry.inc("dispatch_tokens_total", by=0)
         registry.inc("dispatch_tokens_real_total", by=0)
@@ -2260,6 +2373,7 @@ class PagedContinuousBatchingScheduler(ContinuousBatchingScheduler):
             "mode": "packed" if self._packed else "sequential",
             "rounds": self._round_total,
             "model_dispatches": self._dispatch_total,
+            "chunks_ahead": self._chunks_ahead,
             "dispatches_per_round": round(
                 self._dispatch_total / max(self._round_total, 1), 4
             ),

@@ -6,6 +6,7 @@ name every part of it, and what the tracing no longer records."""
 import asyncio
 import re
 import threading
+import time
 
 import jax
 import pytest
@@ -143,6 +144,131 @@ def test_a_step_that_dispatches_nothing_drops_the_gap(drive):
     assert [r["attrs"]["host_gap_ms"] for r in rounds] == pytest.approx([9.0, 9.0], abs=1e-9)
 
 
+# -- the chunk sent ahead (docs/observability.md, "The chunk goes ahead") --------
+
+
+def below(spans, span, name=None):
+    """The names (or, with ``name``, the spans so called) of ``span``'s children."""
+    kids = [s for s in spans if s["parent_id"] == span["span_id"]]
+    return [s for s in kids if s["name"] == name] if name else [s["name"] for s in kids]
+
+
+def test_the_next_rounds_chunk_goes_behind_the_decode_before_its_pull(drive):
+    sched, clock, rec, registry, on_token = drive
+    sched.submit(Request(uid=1, prompt=[5, 6, 7, 8, 9], max_new_tokens=6), on_token=on_token)
+    sched.step()  # round 0: uid 1's chunk, first token and first decode; nothing is prefilling behind it
+    assert sched._ahead is None
+    sched.submit(Request(uid=2, prompt=list(range(1, 21)), max_new_tokens=4), on_token=on_token)
+    sched.step()  # round 1: uid 2's chunk 1 (none was sent for it), the decode, chunk 2 behind it, the pull
+    assert sched._ahead.slot.request.uid == 2 and sched._ahead.first is None and sched._pull_covered
+    clock.advance(4 * MS)  # the server's loop
+    sched.step()  # round 2: chunk 2 is its chunk: the decode, chunk 3 (which ends the prompt) behind it
+    assert sched._ahead.first is not None and sched._slots[1].prefill_progress == 20 and not sched._slots[1].decoding
+    sched.step()  # round 3: the first token is read just before the decode uid 2 rides
+    assert sched._ahead is None and sched._slots[1].decoding and len(sched._slots[1].tokens) == 2
+
+    spans, by_id, rounds, children = round_spans(rec)
+    names = [children[r["span_id"]] for r in rounds]
+    tail = ["decode_prep", "decode_step", "commit", "round_metrics"]
+    assert names[1] == ["admit", "prefill_chunk", *tail]
+    assert names[2] == ["admit", *tail]  # no second chunk: the one sent ahead was this round's
+    assert names[3] == ["admit", "pull", "prefix_register", "first_token", *tail]
+    steps = [below(spans, r, "decode_step")[0] for r in rounds]
+    assert [below(spans, d) for d in steps] == [
+        ["dispatch", "pull"], ["dispatch", "prefill_chunk", "pull"], ["dispatch", "prefill_chunk", "pull"], ["dispatch", "pull"],
+    ]
+    sent = [c["attrs"] for d in steps for c in below(spans, d, "prefill_chunk")]
+    assert [(c["uid"], c["start"], c["real"], c["ahead"]) for c in sent] == [(2, 8, 8, 1), (2, 16, 4, 1)]
+    assert all(below(spans, c) == [] for d in steps for c in below(spans, d, "prefill_chunk"))  # nothing is pulled there
+    attrs = [r["attrs"] for r in rounds]
+    assert [a["chunk_ahead"] for a in attrs] == [0, 1, 1, 0]
+    assert [a["dispatches"] for a in attrs] == [2, 3, 2, 1]  # a chunk counts where it was sent
+    assert sum(s["name"] == "prefill_chunk" for s in spans) == 4  # one for uid 1, three for uid 2
+    assert registry.counter_value("prefill_chunks_ahead_total") == 2 == sched.dispatch_stats()["chunks_ahead"]
+    # round 1: round 0's commit 2 and metrics 1, the lookup 3: nothing was queued behind round 0's pull
+    # round 2: round 1's commit 2 and metrics 1 and the loop's 4, all behind a pull the chunk covered
+    # round 3: commit 2 + metrics 1 covered, up to the first token's read; from its return the register's 7
+    #          and the callback's 2 are the host's gap again, as they are after a chunk pulled in its own span
+    assert [a["host_gap_ms"] for a in attrs] == pytest.approx([9.0, 6.0, 0.0, 9.0], abs=1e-9)
+    assert [a["covered_gap_ms"] for a in attrs] == pytest.approx([0.0, 0.0, 7.0, 3.0], abs=1e-9)
+    hist = registry.histogram("host_gap_seconds")
+    assert hist.count == 4 and hist.total == pytest.approx(0.024, abs=1e-12)
+
+
+class SerialOrder(PagedContinuousBatchingScheduler):
+    """The round as it was: no chunk is sent ahead, so every round runs its own
+    chunk at its start (the order the tokens are compared with)."""
+
+    def _send_chunk(self, ahead=False):
+        return None if ahead else super()._send_chunk()
+
+
+def drained(cls, engine, reqs):
+    """Drain ``reqs`` through two slots (the third waits in the queue for one);
+    returns the scheduler, tokens by uid, by uid the round whose decode the
+    request first rode, and the number of rounds."""
+    rec = FlightRecorder(span_capacity=1 << 14)
+    sched = cls(engine, max_batch=2, eos_id=-1, key=jax.random.PRNGKey(7), tracer=Tracer(service="serve", recorder=rec))
+    done = {}
+    for req in reqs:
+        sched.submit(req)
+    while sched.has_work():
+        done.update({c.uid: c.tokens for c in sched.step()})
+    spans, by_id, rounds, _ = round_spans(rec)
+    first_round = {
+        s["attrs"]["uid"]: by_id[s["parent_id"]]["attrs"]["round"] for s in spans if s["name"] == "first_token"
+    }
+    return sched, done, first_round, len(rounds)
+
+
+def test_a_chunk_sent_ahead_gives_the_same_tokens_in_the_same_rounds(engine):
+    reqs = [
+        Request(uid=1, prompt=[3, 1, 4, 1, 5], max_new_tokens=12),
+        Request(uid=2, prompt=list(range(30, 51)), max_new_tokens=5, temperature=0.9, top_p=0.9),  # three chunks
+        Request(uid=3, prompt=list(range(60, 73)), max_new_tokens=6),  # two chunks, admitted when uid 2 or 1 retires
+    ]
+    serial, want, want_rounds, n_serial = drained(SerialOrder, engine, reqs)
+    sched, got, got_rounds, n_rounds = drained(PagedContinuousBatchingScheduler, engine, reqs)
+    assert serial.dispatch_stats()["chunks_ahead"] == 0 and sched.dispatch_stats()["chunks_ahead"] >= 3
+    assert got == want and all(len(got[r.uid]) == r.max_new_tokens for r in reqs)
+    assert got_rounds == want_rounds and n_rounds == n_serial  # each first token rides the round it rode
+    assert sched.dispatch_stats()["model_dispatches"] == serial.dispatch_stats()["model_dispatches"]
+    assert sched.allocator.free_pages == serial.allocator.free_pages
+
+
+@pytest.mark.parametrize("how", ["cancel", "deadline"])
+@pytest.mark.parametrize("chunk", ["more_to_go", "ends_the_prompt"])
+def test_a_request_gone_with_its_chunk_in_flight_returns_every_page(engine, how, chunk):
+    sched = PagedContinuousBatchingScheduler(engine, max_batch=2, eos_id=-1, key=jax.random.PRNGKey(0))
+    free = sched.allocator.free_pages
+    alone = PagedContinuousBatchingScheduler(engine, max_batch=2, eos_id=-1, key=jax.random.PRNGKey(0))
+    stays = Request(uid=1, prompt=[5, 6, 7, 8, 9], max_new_tokens=8)
+    want = alone.run([stays])[1].tokens
+    done = {}
+    sched.submit(stays)
+    sched.step()
+    sched.submit(
+        Request(uid=2, prompt=list(range(1, 21 if chunk == "more_to_go" else 13)), max_new_tokens=4),
+        deadline=time.monotonic() + 600.0,
+    )
+    sched.step()  # uid 2's first chunk, and its second behind the decode
+    assert sched._ahead.slot.request.uid == 2 and (sched._ahead.first is None) == (chunk == "more_to_go")
+    if how == "cancel":
+        assert sched.cancel(2).finish_reason == "cancelled"
+    else:
+        sched._slots[1].deadline = time.monotonic() - 1.0  # the next round's admit expires it
+    while sched.has_work():
+        done.update({c.uid: c for c in sched.step()})
+    if how == "deadline":
+        assert done[2].finish_reason == "timeout" and done[2].tokens == []
+    assert sched._ahead is None and done[1].finish_reason == "length" and done[1].tokens == want
+    assert sched.allocator.free_pages == free and sched.allocator.used_pages == 0
+    # the slot and its pages serve the next request as any freed ones do
+    again = sched.run([Request(uid=3, prompt=list(range(1, 21)), max_new_tokens=4)])[3]
+    assert again.tokens == alone.run([Request(uid=3, prompt=list(range(1, 21)), max_new_tokens=4)])[3].tokens
+    assert sched.allocator.used_pages == 20 // 8  # what the prefix cache keeps of uid 3's prompt
+
+
 def test_packed_rounds_count_the_same_gap():
     from tests.test_packed import make_engine
 
@@ -199,7 +325,7 @@ def test_the_loop_has_spans_a_stream_one_flush_span_and_metrics_one_more_series(
     assert not thread.is_alive() and server._worker_error is None
 
     series = set(re.findall(r"^# TYPE relora_serve_(\S+) ", metrics, re.M))
-    assert series == PARENT_SERIES | {"host_gap_seconds"}
+    assert series == PARENT_SERIES | {"host_gap_seconds", "prefill_chunks_ahead_total"}
     rounds = int(re.search(r"^relora_serve_sched_rounds_total (\d+)", metrics, re.M).group(1))
     assert int(re.search(r"^relora_serve_host_gap_seconds_count (\d+)", metrics, re.M).group(1)) == rounds
 

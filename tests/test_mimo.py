@@ -210,6 +210,47 @@ def test_decode_step_says_what_each_cache_kind_reads(cfg, params):
     assert "moe_assignments_local" in chunks[-1] and "moe_assignments_local" not in chunks[0]
 
 
+def test_the_decodes_pull_converts_no_count_of_the_chunk_sent_ahead(cfg, params):
+    """The next round's chunk lies behind the decode on the device: the decode's
+    pull reads the decode's own counts (``decode_step.expert_bytes`` is what
+    ``moe_experts_roofline.serve`` divides by) and leaves the chunk's pending."""
+    from relora_tpu.obs.tracer import Tracer
+
+    eng = _engine(cfg, params)
+    registry = MetricsRegistry()
+    sch = PagedContinuousBatchingScheduler(
+        eng, max_batch=2, eos_id=-1, prefix_cache=False, key=jax.random.PRNGKey(0), obs_registry=registry,
+    )
+    sch.tracer = Tracer(service="test")
+    forwards = []  # (program, tokens routed, the counts as the device holds them), in dispatch order
+    for name, tokens in (("decode_paged", 2), ("prefill_chunk", 8)):
+        def spied(*a, _real=getattr(eng, name), _name=name, _tokens=tokens, **kw):
+            out = _real(*a, **kw)
+            forwards.append((_name, _tokens, eng.moe_counts))
+            return out
+        setattr(eng, name, spied)
+    try:
+        sch.submit(Request(uid=1, prompt=_tokens(3, 5).tolist(), max_new_tokens=6))
+        sch.step()
+        sch.submit(Request(uid=2, prompt=_tokens(4, 20).tolist(), max_new_tokens=3))
+        sch.step()  # uid 2's first chunk, the decode, uid 2's second chunk behind it
+    finally:
+        del eng.decode_paged, eng.prefill_chunk
+    assert [f[0] for f in forwards] == ["prefill_chunk", "decode_paged", "prefill_chunk", "decode_paged", "prefill_chunk"]
+    fanout = TINY["num_experts_per_tok"] * sum(TINY["moe_layer_freq"])
+    *pulled, (_, _, ahead_counts) = forwards
+    assert sch._ahead is not None and [(r, c is ahead_counts) for r, c in sch._moe_pending] == [(8 * fanout, True)]
+    assert registry.counter_value("moe_assignments_total") == sum(t for _, t, _ in pulled) * fanout
+    assert registry.counter_value("moe_experts_hit_total") == sum(int(np.asarray(c)[1]) for _, _, c in pulled)
+    step = [s for s in sch.tracer.recorder.spans() if s["name"] == "decode_step"][-1]["attrs"]
+    local, hit = (int(v) for v in np.asarray(pulled[-1][2]))  # the decode's, the last forward before the chunk
+    assert (step["moe_assignments_local"], step["expert_bytes"]) == (local, hit * sch._expert_bytes)
+    assert int(np.asarray(ahead_counts)[1]) != hit  # a chunk of eight tokens hits other experts than a row does
+    sch.step()  # the next decode's pull takes the chunk's counts up, and leaves those of the chunk behind it
+    assert registry.counter_value("moe_assignments_total") == (sum(t for _, t, _ in pulled) + 8 + 2) * fanout
+    assert [r for r, _ in sch._moe_pending] == [8 * fanout]
+
+
 @pytest.mark.parametrize(
     "feature, kw",
     [

@@ -794,6 +794,7 @@ def test_paged_metrics_kv_bytes_gauges(tmp_path):
 
 ROUND_CHILDREN = {
     "admit", "prefill_chunk", "prefix_register", "first_token", "decode_prep", "decode_step", "commit", "round_metrics",
+    "pull",  # the first token of a chunk that the last round's decode_step sent ahead
 }
 
 
@@ -845,14 +846,18 @@ def check_round_tree(rounds, children):
         kids = children[r["span_id"]]
         names = [k["name"] for k in kids]
         assert set(names) <= ROUND_CHILDREN and names[0] == "admit", names
-        assert r["attrs"]["dispatches"] == names.count("prefill_chunk") + names.count("decode_step") > 0
+        # the next round's chunk, sent behind the decode before its pull, is this round's dispatch
+        ahead = [g for k in kids if k["name"] == "decode_step" for g in children[k["span_id"]] if g["name"] == "prefill_chunk"]
+        assert r["attrs"]["dispatches"] == names.count("prefill_chunk") + names.count("decode_step") + len(ahead) > 0
+        assert r["attrs"]["chunk_ahead"] == len(ahead) <= 1 and all(g["attrs"]["ahead"] == 1 for g in ahead)
         if r["attrs"]["decoding"]:
             assert names[-4:] == ["decode_prep", "decode_step", "commit", "round_metrics"], names
         for k in kids:
             assert r["t_start"] <= k["t_start"] <= k["t_end"] <= r["t_end"]
             grandkids = children.get(k["span_id"], [])
             if k["name"] == "decode_step":
-                assert [g["name"] for g in grandkids] == ["dispatch", "pull"]
+                assert [g["name"] for g in grandkids] in (["dispatch", "pull"], ["dispatch", "prefill_chunk", "pull"])
+                assert all(g["trace_id"].startswith("request-") and g["span_id"] not in children for g in grandkids[1:-1])
                 # the rows' draws, enqueued inside the dispatch (a packed
                 # round of prompt tokens only has no row to draw for)
                 samples = children.get(grandkids[0]["span_id"], [])
@@ -861,7 +866,9 @@ def check_round_tree(rounds, children):
             elif k["name"] == "prefill_chunk":
                 assert [g["name"] for g in grandkids] in ([], ["pull"])
                 assert k["trace_id"].startswith("request-")  # the request's trace, the round's child
-                assert 0 < k["attrs"]["real"] <= k["attrs"]["chunk"]
+                assert 0 < k["attrs"]["real"] <= k["attrs"]["chunk"] and "ahead" not in k["attrs"]
+            elif k["name"] == "pull":
+                assert grandkids == [] and names[names.index("pull") + 1] in ("prefix_register", "first_token")
             else:
                 # the prefix hash, where it runs: a lookup an admission, a
                 # register in the packed round's commit (the sequential
@@ -1072,8 +1079,9 @@ ROUND_MIXES = {
 @pytest.mark.parametrize("mix", list(ROUND_MIXES))
 def test_programs_a_round_do_not_grow_with_the_rows(tmp_path, mix):
     """A decode round dispatches two programs (``decode_paged`` and the
-    sampler) with 1 row decoding and with 4, and three with a prompt's chunk
-    in the round: none per row, whether the rows are greedy, sampled or mixed.
+    sampler) with 1 row decoding and with 4; a prompt's chunk is one more, and
+    so is the next round's chunk, sent behind the decode with its first token's
+    draw: none per row, whether the rows are greedy, sampled or mixed.
     The scheduler was warmed by greedy requests alone: the first sampled draw
     takes another branch of the program it has, and compiles nothing."""
     from relora_tpu.obs.metrics import MetricsRegistry
@@ -1104,11 +1112,15 @@ def test_programs_a_round_do_not_grow_with_the_rows(tmp_path, mix):
     assert one_row == four_rows == 2, (one_row, called_1, four_rows, called_4)
     assert {p: n - before[p] for p, n in draws().items() if n != before[p]} == {path: 1}
 
-    # a two-chunk prompt: its first chunk rides a round with the four decodes
+    # a two-chunk prompt: its first chunk rides a round with the four decodes, and its
+    # second (with the first token's draw) goes behind that decode, ahead of the pull
     sched.submit(Request(uid=5, prompt=rng.integers(1, 256, 13).tolist(), max_new_tokens=4))
     with_chunk, called_c = xla_programs(sched.step, tmp_path / "chunk")
-    assert decoding_rows(sched) == 4 and sched._slots[4].prefill_progress == 8
-    assert with_chunk == 3, (with_chunk, called_c)
+    assert decoding_rows(sched) == 4 and sched._slots[4].prefill_progress == 13
+    # chunk; decode, sampler; chunk, its last position's logits (a slice and a squeeze), the one-row sampler
+    assert with_chunk == 7, (with_chunk, called_c)
+    landed, called_l = xla_programs(sched.step, tmp_path / "landed")  # the round that chunk was sent for runs no other
+    assert decoding_rows(sched) == 5 and landed == 2, (landed, called_l)
     compiles.on = False
     assert compiles.count == 0, "a draw compiled: the sampler's path must be chosen inside its program"
 
