@@ -9,7 +9,9 @@ residual blocks (:443-456), LayerNorm with biases, GELU MLP, causal SDPA
 Used by the production 1B recipe (training_configs/1B_v1.0.yaml:
 EleutherAI/pythia-1b warm start).  Weight layout matches HF exactly — the
 fused QKV out-dim is interleaved per head as (heads, 3, head_dim) — so
-hf_compat transfers Pythia checkpoints without reshuffling.
+hf_compat transfers Pythia checkpoints without reshuffling.  The serving
+forward hands the QKV product to the head split across an optimization
+barrier, so the product reads its layer of the stacked kernel in place.
 
 Same TPU-first choices as models/llama.py: scan-over-layers, optional remat,
 bf16 matmuls with f32 norms/rotary/softmax.
@@ -95,6 +97,10 @@ class NeoXAttention(nn.Module):
             name="query_key_value",
         )(x, deterministic, adapter_idx)
         B, S = x.shape[:2]
+        if self.decode:
+            # were XLA to fold the head split into the product, each layer would slice its kernel into
+            # VMEM and copy it transposed; serving only: the training step makes no such copy
+            qkv = jax.lax.optimization_barrier(qkv)
         # HF NeoX fused layout: out dim is (heads, 3 * head_dim) interleaved
         qkv = qkv.reshape(B, S, n, 3 * hd)
         q, k, v = qkv[..., :hd], qkv[..., hd : 2 * hd], qkv[..., 2 * hd :]

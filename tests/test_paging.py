@@ -19,6 +19,7 @@ import pytest
 
 from relora_tpu.config.model import ModelConfig
 from relora_tpu.models.params_util import init_params
+from relora_tpu.models.pythia import GPTNeoXForCausalLM
 from relora_tpu.serve.engine import InferenceEngine, build_decode_model
 from relora_tpu.serve import sampling
 from relora_tpu.serve.paging import NULL_PAGE, PageAllocator, PrefixCache, pages_needed
@@ -224,6 +225,57 @@ def test_chunked_prefill_matches_whole(cfg, chunk):
             np.asarray(whole[:, start : start + n_real]),
             atol=1e-5,
         )
+
+
+def neox_paged_engine():
+    """A paged NeoX engine, the training-mode model it serves and the
+    parameters both are given."""
+    base = GPTNeoXForCausalLM(TINY_NEOX, dtype=jnp.float32)
+    params = init_params(base, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    paged = InferenceEngine(TINY_NEOX, params, cache_size=32, page_size=8, num_pages=13, chunk_size=8)
+    return paged, base, params
+
+
+def test_neox_paged_programs_match_the_training_forward():
+    """NeoX's serving forward hands the QKV product to its head split across
+    a barrier the training forward lacks; the logits stay the training
+    forward's: ``prefill_chunk`` at every real position of two chunks, the
+    ragged last included, then ``decode_paged`` at every later position."""
+    paged, base, params = neox_paged_engine()
+    L, S, chunk = 11, 16, paged.chunk_size
+    ids = np.asarray(jax.random.randint(jax.random.PRNGKey(7), (1, S), 0, TINY_NEOX.vocab_size))
+    full = np.asarray(base.apply({"params": params}, jnp.asarray(ids)))
+
+    pool = paged.init_pool()
+    table = np.zeros((1, paged.block_table_width), np.int32)
+    table[0, : pages_needed(S, paged.page_size)] = np.arange(1, pages_needed(S, paged.page_size) + 1)
+    for start in range(0, L, chunk):
+        n_real = min(chunk, L - start)
+        chunk_ids = np.zeros((1, chunk), np.int32)
+        chunk_ids[0, :n_real] = ids[0, start : start + n_real]
+        logits, pool = paged.prefill_chunk(jnp.asarray(chunk_ids), start, pool, table)
+        np.testing.assert_allclose(np.asarray(logits[:, :n_real]), full[:, start : start + n_real], atol=1e-5)
+    for t in range(L, S):
+        step, pool = paged.decode_paged(pool, ids[:, t : t + 1], np.full((1, 1), t, np.int32), table)
+        np.testing.assert_allclose(np.asarray(step), full[:, t], atol=1e-5)
+
+
+def test_only_the_serving_forward_carries_the_qkv_barrier():
+    """Every paged program of a NeoX engine lowers with the QKV barrier; the
+    training forward and its remat gradient, which the train step runs, lower
+    without one."""
+    paged, base, params = neox_paged_engine()
+    for name, (jitted, args) in paged.paged_programs(4).items():
+        assert "optimization_barrier" in jitted.lower(*args).as_text(), name
+
+    ids = jnp.zeros((2, 16), jnp.int32)
+    remat = base.clone(remat=True)
+
+    def loss(p):
+        return remat.apply({"params": p}, ids).mean()
+
+    assert "optimization_barrier" not in jax.jit(base.apply).lower({"params": params}, ids).as_text()
+    assert "optimization_barrier" not in jax.jit(jax.grad(loss)).lower(params).as_text()
 
 
 def test_memory_plans_pool_scales_with_pages():

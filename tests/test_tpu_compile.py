@@ -15,6 +15,7 @@ the worker that is given this file ever does.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -262,7 +263,7 @@ def test_mimo_decode_program_compiles_at_the_cells_shapes(one_chip, monkeypatch)
     assert live < 11e9
 
 
-def test_serving_cell_programs_hold_no_copy_of_the_pool(one_chip, monkeypatch):
+def test_serving_cell_programs_hold_no_copy_of_the_pool(serving_cell):
     """``serve.pythia_1.4b.chat_c32``'s two programs at the cell's shapes (32
     rows, a 64-token chunk, 1,025 pages): the 3.22 GB pool rides the layer
     loop as a carry, so each program aliases it to its result, slices no
@@ -272,18 +273,44 @@ def test_serving_cell_programs_hold_no_copy_of_the_pool(one_chip, monkeypatch):
     and its temporaries are activations, under 0.2 GB.  The parent of PR 35
     planned 5.78 GB for each, the parent of PR 37 2.42 GB: a bf16 copy of the
     f32 kernels, made anew by every dispatch (PERF.md §6)."""
-    import json
     import math
-    import os
-    import re
 
     from test_paging import pool_sized_moves
+
+    cfg, pool, params = serving_cell.cfg, serving_cell.pool, serving_cell.params
+    pool_bytes = sum(c.pool_bytes for c in serving_cell.specs)
+    assert 3.2e9 < pool_bytes < 3.3e9
+    for path, x in jax.tree_util.tree_leaves_with_path(params):
+        used_in_f32 = "norm" in jax.tree_util.keystr(path)  # LayerNorm scales and offsets
+        assert x.dtype == (jnp.float32 if used_in_f32 else jnp.bfloat16), jax.tree_util.keystr(path)
+    held = sum(x.size * x.dtype.itemsize for x in jax.tree_util.tree_leaves(params))
+    assert 2.8e9 < held < 2.9e9
+    a_stacked_kernel = cfg.num_hidden_layers * cfg.hidden_size**2  # the smallest: the attention output's
+
+    for name, compiled in serving_cell.programs.items():
+        text = compiled.as_text()
+        assert ("paged_decode_attention" in text) == (name == "decode_paged")
+        assert not pool_sized_moves(text, pool["layers"]["attention"]["k"].shape)
+        plan = compiled.memory_analysis()
+        assert plan.alias_size_in_bytes == pool_bytes
+        assert plan.temp_size_in_bytes < 0.2e9
+        converted = [math.prod(map(int, dims.split(","))) for dims in re.findall(r"= \w+\[([\d,]+)\]\S* convert\(", text)]
+        assert converted and max(converted) < a_stacked_kernel  # activations change type, no weight does
+
+
+@pytest.fixture(scope="module")
+def serving_cell(one_chip):
+    """``serve.pythia_1.4b.chat_c32``'s decode model, parameters and pool at
+    the cell's shapes (32 rows, a 64-token chunk, 1,025 pages), and its two
+    programs compiled once for the tests that read them."""
+    import json
+    import os
+    from types import SimpleNamespace
 
     from relora_tpu.config.model import load_model_config
     from relora_tpu.models.step import PAGED, StepContext, cache_specs
     from relora_tpu.serve.engine import _forward, build_decode_model
 
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the dispatchers take their TPU branch
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     cfg = load_model_config(os.path.join(root, "benchmark", "configs", "pythia_1.4b.json"))
     with open(os.path.join(root, "benchmark", "workloads", "serve.pythia_1.4b.chat_c32.json")) as f:
@@ -301,16 +328,8 @@ def test_serving_cell_programs_hold_no_copy_of_the_pool(one_chip, monkeypatch):
         return jax.tree_util.tree_map(lambda s: one_chip(s.shape, s.dtype), tree)
 
     pool = on_chip(model.pool_shapes(specs, jnp.bfloat16))
-    pool_bytes = sum(c.pool_bytes for c in specs)
-    assert 3.2e9 < pool_bytes < 3.3e9
     ids = jnp.zeros((1, 8), jnp.int32)
     params = on_chip(jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), ids, block_tables=ids)["params"]))
-    for path, x in jax.tree_util.tree_leaves_with_path(params):
-        used_in_f32 = "norm" in jax.tree_util.keystr(path)  # LayerNorm scales and offsets
-        assert x.dtype == (jnp.float32 if used_in_f32 else jnp.bfloat16), jax.tree_util.keystr(path)
-    held = sum(x.size * x.dtype.itemsize for x in jax.tree_util.tree_leaves(params))
-    assert 2.8e9 < held < 2.9e9
-    a_stacked_kernel = cfg.num_hidden_layers * cfg.hidden_size**2  # the smallest: the attention output's
 
     def step(p, pool, tokens, pos, table):
         ctx = StepContext(positions=pos, tables={PAGED: table})
@@ -320,18 +339,47 @@ def test_serving_cell_programs_hold_no_copy_of_the_pool(one_chip, monkeypatch):
     def ints(*shape):
         return one_chip(shape, jnp.int32)
 
-    for rows, tokens in ((B, 1), (1, chunk)):  # decode_paged, prefill_chunk
-        compiled = jax.jit(step, donate_argnums=(1,)).lower(
-            params, pool, ints(rows, tokens), ints(rows, tokens), ints(rows, width)
-        ).compile()
-        text = compiled.as_text()
-        assert ("paged_decode_attention" in text) == (tokens == 1)
-        assert not pool_sized_moves(text, pool["layers"]["attention"]["k"].shape)
-        plan = compiled.memory_analysis()
-        assert plan.alias_size_in_bytes == pool_bytes
-        assert plan.temp_size_in_bytes < 0.2e9
-        converted = [math.prod(map(int, dims.split(","))) for dims in re.findall(r"= \w+\[([\d,]+)\]\S* convert\(", text)]
-        assert converted and max(converted) < a_stacked_kernel  # activations change type, no weight does
+    programs = {}
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")  # the dispatchers take their TPU branch
+        for name, rows, tokens in (("decode_paged", B, 1), ("prefill_chunk", 1, chunk)):
+            programs[name] = jax.jit(step, donate_argnums=(1,)).lower(
+                params, pool, ints(rows, tokens), ints(rows, tokens), ints(rows, width)
+            ).compile()
+    return SimpleNamespace(cfg=cfg, specs=specs, pool=pool, params=params, programs=programs)
+
+
+def layer_sized_results(text, shapes):
+    """``(name, op)`` of every ``fusion`` or ``copy`` of a compiled program
+    that is not itself inside a fused computation and makes an array of one
+    of ``shapes``: what the program materialises, where a slice fused into
+    its consumer makes nothing."""
+    fused = set(re.findall(r"calls=(%[\w.\-]+)", text))
+    made, inside = [], False
+    for line in text.splitlines():
+        if not line.startswith(" "):  # a computation's header (or the module's)
+            head = re.match(r"(?:ENTRY )?(%[\w.\-]+) ", line)
+            inside = bool(head) and head.group(1) in fused
+            continue
+        m = re.match(r"\s+(?:ROOT )?(%[\w.\-]+) = \w+\[([\d,]*)\]\S* (fusion|copy)\(", line)
+        if m and not inside and tuple(int(d) for d in m.group(2).split(",") if d) in shapes:
+            made.append((m.group(1), m.group(3)))
+    return made
+
+
+@pytest.mark.parametrize("program", ["decode_paged", "prefill_chunk"])
+def test_serving_cell_programs_read_each_layers_kernels_in_place(serving_cell, program):
+    """Every kernel of a layer is read from its stack where it lies: no
+    program of ``serve.pythia_1.4b.chat_c32`` makes one layer of a stacked
+    kernel (``[2048, 6144]`` QKV, ``[2048, 2048]`` attention output,
+    ``[2048, 8192]`` and ``[8192, 2048]`` FFN), with or without the leading
+    layer dimension.  Were NeoX's head split folded into the QKV product,
+    each layer of both programs would slice its 25 MB QKV kernel into VMEM and
+    copy it transposed before multiplying."""
+    stacked = [x.shape[1:] for x in jax.tree_util.tree_leaves(serving_cell.params["layers"]) if x.ndim == 3]
+    shapes = {s for shape in stacked for s in (shape, (1, *shape))}
+    assert {(2048, 6144), (2048, 2048), (2048, 8192), (8192, 2048)} <= shapes
+    assert layer_sized_results(serving_cell.programs[program].as_text(), shapes) == []
 
 
 @pytest.mark.parametrize("program", ["decode_paged", "prefill_chunk"])
